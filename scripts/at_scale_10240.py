@@ -35,9 +35,9 @@ import numpy as np
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
+from wukong_tpu.utils.paths import LUBM_BASIC as BASIC  # noqa: E402
 
 SCALE = int(os.environ.get("WUKONG_10240_SCALE", "10240"))  # override = smoke
-BASIC = "/root/reference/scripts/sparql_query/lubm/basic"
 BATCH = 1024
 
 

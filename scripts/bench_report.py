@@ -18,7 +18,7 @@ artifact:
   reported but never fail the check.
 
 Artifact shapes handled: headline files ({metric, value, unit, ...}),
-bench_loop wrapper files ({parsed: {…headline…}, tail, rc}), and composite
+wrapper files ({parsed: {…headline…}, tail, rc}), and composite
 files without a scalar headline (listed, excluded from the check).
 """
 
@@ -83,8 +83,8 @@ def _direction(unit: str) -> int:
 
 
 def _headline(d: dict) -> dict | None:
-    """{value, unit, metric} from one artifact, unwrapping bench_loop
-    wrappers; None when the file has no scalar headline."""
+    """{value, unit, metric} from one artifact, unwrapping
+    {parsed: ...} wrappers; None when the file has no scalar headline."""
     if isinstance(d.get("parsed"), dict):
         d = d["parsed"]
     # hot-spot observatory drill: the heat plane's load-rate separation

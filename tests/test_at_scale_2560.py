@@ -27,7 +27,7 @@ CACHE = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), ".cache")
 STORE = os.path.join(CACHE, "lubm2560_v2_p0.npz")
 STATS = os.path.join(CACHE, "lubm2560_v2_stats.npz")
-BASIC = "/root/reference/scripts/sparql_query/lubm/basic"
+from wukong_tpu.utils.paths import LUBM_BASIC as BASIC
 
 pytestmark = pytest.mark.skipif(
     not (os.path.exists(STORE) and os.path.exists(STATS)

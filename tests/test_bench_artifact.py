@@ -90,7 +90,7 @@ def test_side_file_failure_does_not_kill_headline(bench, monkeypatch,
 
 
 # ---------------------------------------------------------------------------
-# Partial-store contracts behind the flaky-relay capture path: provisional
+# Partial-store contracts behind the interrupted-capture path: provisional
 # stubs bank per trial, OOM restarts invalidate what they disprove, and
 # ladder-rung evidence surfaces without violating freshness/version rules.
 # ---------------------------------------------------------------------------

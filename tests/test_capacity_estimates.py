@@ -14,7 +14,7 @@ from wukong_tpu.planner.optimizer import Planner
 from wukong_tpu.planner.stats import Stats
 from wukong_tpu.sparql.parser import Parser
 
-BASIC = "/root/reference/scripts/sparql_query/lubm/basic"
+from wukong_tpu.utils.paths import LUBM_BASIC as BASIC
 
 
 @pytest.fixture(scope="module")

@@ -20,7 +20,7 @@ from wukong_tpu.sparql.parser import Parser
 from wukong_tpu.store.gstore import build_partition
 from wukong_tpu.types import BLANK_ID
 
-BASIC = "/root/reference/scripts/sparql_query/lubm/basic"
+from wukong_tpu.utils.paths import LUBM_BASIC as BASIC
 
 
 @pytest.fixture(scope="module")

@@ -14,7 +14,7 @@ from wukong_tpu.planner.heuristic import heuristic_plan
 from wukong_tpu.sparql.parser import Parser
 from wukong_tpu.store.gstore import build_all_partitions, build_partition
 
-BASIC = "/root/reference/scripts/sparql_query/lubm/basic"
+from wukong_tpu.utils.paths import LUBM_BASIC as BASIC
 
 # BGP-only, const-predicate queries (the distributed v1 support matrix —
 # same scope as the reference's GPU engine)

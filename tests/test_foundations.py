@@ -39,3 +39,34 @@ def test_error_codes():
     with pytest.raises(WukongError) as e:
         assert_ec(False, ErrorCode.VERTEX_INVALID, "col missing")
     assert e.value.code == ErrorCode.VERTEX_INVALID
+
+
+@pytest.mark.parametrize("env_dir", ["/some/dir", None])
+def test_compile_cache_dir_is_placed_from_outside(monkeypatch, env_dir):
+    """Where JAX_COMPILATION_CACHE_DIR is set, jax reads it itself and the
+    code sets no directory, whatever the knob says; unset, the directory is
+    the fixed <repo>/.cache/xla."""
+    import os
+
+    import jax
+
+    from wukong_tpu.config import Global
+    from wukong_tpu.utils import compilecache
+    from wukong_tpu.utils.paths import REPO
+
+    updates = {}
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.__setitem__(k, v))
+    monkeypatch.setattr(compilecache, "_logged_dir", None)
+    if env_dir:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+        monkeypatch.setattr(Global, "xla_cache_dir", "/from/the/knob")
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    got = compilecache.setup_persistent_cache()
+    if env_dir:
+        assert got == env_dir
+        assert "jax_compilation_cache_dir" not in updates
+    else:
+        assert got == os.path.join(REPO, ".cache", "xla")
+        assert updates["jax_compilation_cache_dir"] == got

@@ -116,12 +116,12 @@ def test_engine_results_identical_with_and_without_fp(tmp_path):
     from wukong_tpu.planner.heuristic import heuristic_plan
     from wukong_tpu.sparql.parser import Parser
     from wukong_tpu.store.gstore import build_partition
+    from wukong_tpu.utils.paths import LUBM_BASIC
 
     triples, _ = generate_lubm(1, seed=0)
     g = build_partition(triples, 0, 1)
     ss = VirtualLubmStrings(1, seed=0)
-    text = open(
-        "/root/reference/scripts/sparql_query/lubm/basic/lubm_q7").read()
+    text = open(f"{LUBM_BASIC}/lubm_q7").read()
     results = {}
     for flag in (True, False):
         old = Global.enable_fp_probe

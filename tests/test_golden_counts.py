@@ -17,7 +17,7 @@ from wukong_tpu.planner.heuristic import heuristic_plan
 from wukong_tpu.sparql.parser import Parser
 from wukong_tpu.store.gstore import build_partition
 
-BASIC = "/root/reference/scripts/sparql_query/lubm/basic"
+from wukong_tpu.utils.paths import LUBM_BASIC as BASIC
 
 # (query, rows) at LUBM-40 seed=0 — recorded from the CPU oracle, v2 dataset
 GOLDEN_LUBM40 = {

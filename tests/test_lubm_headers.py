@@ -21,7 +21,7 @@ from wukong_tpu.loader.lubm import generate_lubm, lubm_headers
 from wukong_tpu.store.gstore import build_partition
 from wukong_tpu.types import IN, NORMAL_ID_START, OUT
 
-BASIC = "/root/reference/scripts/sparql_query/lubm/basic"
+from wukong_tpu.utils.paths import LUBM_BASIC as BASIC
 HBM_BYTES = 16 * 2**30  # v5e: 16 GiB HBM per chip
 MESH_D = 8  # v5e-8
 

@@ -22,7 +22,7 @@ from wukong_tpu.planner.heuristic import heuristic_plan
 from wukong_tpu.sparql.parser import Parser
 from wukong_tpu.store.gstore import build_partition
 
-BASIC = "/root/reference/scripts/sparql_query/lubm/basic"
+from wukong_tpu.utils.paths import LUBM_BASIC as BASIC
 # the benchmark set; q8+ (versatile / attr shapes) are host-path queries
 QUERIES = [f"{BASIC}/lubm_q{k}" for k in range(1, 8)]
 

@@ -15,7 +15,7 @@ from wukong_tpu.sparql.parser import Parser
 from wukong_tpu.store.gstore import build_partition
 from wukong_tpu.types import IN, OUT, TYPE_ID
 
-BASIC = "/root/reference/scripts/sparql_query/lubm/basic"
+from wukong_tpu.utils.paths import LUBM_BASIC as BASIC
 
 
 @pytest.fixture(scope="module")
@@ -164,7 +164,7 @@ def test_plan_quality_vs_osdi16(qn):
     from wukong_tpu.sparql.parser import Parser
     from wukong_tpu.store.gstore import build_partition
 
-    basic = "/root/reference/scripts/sparql_query/lubm/basic"
+    from wukong_tpu.utils.paths import LUBM_BASIC as basic
     triples, _ = generate_lubm(1, seed=42)
     ss = VirtualLubmStrings(1, seed=42)
     g = build_partition(triples, 0, 1)

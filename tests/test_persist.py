@@ -43,6 +43,7 @@ def test_loaded_store_queries_identically(tmp_path):
     from wukong_tpu.loader.lubm import VirtualLubmStrings
     from wukong_tpu.planner.heuristic import heuristic_plan
     from wukong_tpu.sparql.parser import Parser
+    from wukong_tpu.utils.paths import LUBM_BASIC
 
     triples, _ = generate_lubm(1, seed=13)
     g = build_partition(triples, 0, 1)
@@ -50,7 +51,7 @@ def test_loaded_store_queries_identically(tmp_path):
     save_gstore(g, path)
     g2 = load_gstore(path)
     ss = VirtualLubmStrings(1, seed=13)
-    text = open("/root/reference/scripts/sparql_query/lubm/basic/lubm_q4").read()
+    text = open(f"{LUBM_BASIC}/lubm_q4").read()
     rows = []
     for store in (g, g2):
         q = Parser(ss).parse(text)

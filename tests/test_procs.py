@@ -481,6 +481,8 @@ def test_supervisor_spawn_serve_sync_kill_restart(proc_world):
         assert ss.transport is sup.transport
         assert all(sup.transport.peer_for(s) is not None for s in range(D))
         assert sorted(sup.groups) == [0, 1]
+        # workers stay off jax: the parent may hold the only chip
+        assert sup.worker_jax_loaded is False
         # wire fetches are byte-identical to the parent's local execution
         for sid in range(D):
             a = ss.transport.fetch(sid, ss.stores[sid], "segment", (3, OUT))

@@ -13,7 +13,7 @@ from wukong_tpu.runtime.monitor import Monitor
 from wukong_tpu.runtime.proxy import Proxy
 from wukong_tpu.store.gstore import build_partition
 
-BASIC = "/root/reference/scripts/sparql_query/lubm/basic"
+from wukong_tpu.utils.paths import LUBM_BASIC as BASIC
 EMU = "/root/reference/scripts/sparql_query/lubm/emulator"
 
 
@@ -243,7 +243,7 @@ def test_emulator_heavy_batched_device(proxy, monkeypatch):
     import os
     import tempfile
 
-    basic = "/root/reference/scripts/sparql_query/lubm/basic"
+    from wukong_tpu.utils.paths import LUBM_BASIC as basic
     d = tempfile.mkdtemp()
     with open(os.path.join(d, "mix"), "w") as f:
         f.write(f"0 1\n{basic}/lubm_q2 1\n")

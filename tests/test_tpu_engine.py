@@ -14,7 +14,7 @@ from wukong_tpu.planner.plan_file import set_plan
 from wukong_tpu.sparql.parser import Parser
 from wukong_tpu.store.gstore import build_partition
 
-BASIC = "/root/reference/scripts/sparql_query/lubm/basic"
+from wukong_tpu.utils.paths import LUBM_BASIC as BASIC
 
 
 @pytest.fixture(scope="module")
@@ -210,40 +210,6 @@ def test_prefetch_pipelining_stages_chain_segments(engines, world, monkeypatch):
     heuristic_plan(q)
     tpu.execute(q)
     assert q.result.status_code == 0 and not staged
-
-
-def test_pallas_probe_matches_xla(world):
-    """Pallas probe kernel (interpret mode) == the XLA _hash_find path."""
-    import jax.numpy as jnp
-    import numpy as np
-
-    from wukong_tpu.engine import tpu_kernels as K
-    from wukong_tpu.engine.device_store import DeviceStore
-    from wukong_tpu.loader.lubm import P
-    from wukong_tpu.types import OUT
-
-    g, ss = world
-    seg = DeviceStore(g).segment(P["memberOf"], OUT)
-    rng = np.random.default_rng(3)
-    C = 2048
-    keys = np.asarray(g.segments[(P["memberOf"], OUT)].keys)
-    cur = np.concatenate([
-        rng.choice(keys, C // 2),                  # hits
-        rng.integers(1 << 22, 1 << 23, C // 2),    # misses
-    ]).astype(np.int32)
-    rng.shuffle(cur)
-    n = C - 17  # some dead tail rows
-    valid = np.arange(C) < n
-
-    fx, sx, dx = K._hash_find(seg.bkey, seg.bstart, seg.bdeg,
-                              jnp.asarray(cur), jnp.asarray(valid),
-                              seg.max_probe)
-    fp, sp, dp = K.pallas_probe(seg.bkey, seg.bstart, seg.bdeg,
-                                jnp.asarray(cur), jnp.int32(n),
-                                seg.max_probe, interpret=True)
-    assert np.array_equal(np.asarray(fx), np.asarray(fp))
-    assert np.array_equal(np.asarray(sx), np.asarray(sp))
-    assert np.array_equal(np.asarray(dx), np.asarray(dp))
 
 
 def test_versatile_kuu_on_device(world):

@@ -621,11 +621,11 @@ def test_kernels_depth_bounded_pair_member_parity():
 
 def test_kernels_jit_values_past_int31_under_x64():
     """>2^31-safe ids/offsets through the jitted kernels: under
-    ``jax.experimental.enable_x64`` the SAME kernel source runs int64 and
+    ``jax.enable_x64`` the SAME kernel source runs int64 and
     matches NumPy on values past int32 range. (The default x64-off device
     path never sees such values — ``to_device_i32`` REFUSES them and the
     executor degrades to host, tested below.)"""
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     from wukong_tpu.join.kernels import jit_kernels
 

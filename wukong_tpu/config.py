@@ -28,8 +28,8 @@ class GlobalConfig:
     enable_merge_join: bool = True  # sort-merge batch chains (gather-free v2)
     # HBM segment-cache budget (reference: gpu_kvcache). Conservative default:
     # heavy-chain buffers at 32M-row capacity classes can hold several GiB
-    # live while dispatches pipeline, and a worker OOM crash takes the whole
-    # relay down — leave most of the 16 GiB to chain buffers.
+    # live while dispatches pipeline — leave most of the 16 GiB to chain
+    # buffers.
     tpu_mem_cache_gb: int = 4
     enable_dynamic_store: bool = False  # append-only delta segments
     enable_versatile: bool = True  # variable-predicate support (USE_VERSATILE)
@@ -56,7 +56,6 @@ class GlobalConfig:
     stealing_pattern: int = 0  # 0: pair, 1: ring (host engine work stealing)
     enable_budget: bool = True
     gpu_enable_pipeline: bool = True  # prefetch next pattern's segments to HBM
-    enable_pallas: bool = True  # Pallas probe kernel on TPU backends
     enable_fp_probe: bool = True  # fingerprint-packed hash probe (XLA path)
     # Pallas streaming merge-expand for dense heavy expansions (tpu_stream)
     enable_stream_expand: bool = True
@@ -331,7 +330,7 @@ class GlobalConfig:
     # cooldown_s posture: one journal + dump per storm, not per dispatch)
     device_storm_cooldown_s: float = 60.0
     # persistent XLA compile-cache directory (utils/compilecache.py);
-    # empty = the WUKONG_CACHE_DIR env form, then <repo>/.cache/xla
+    # where JAX_COMPILATION_CACHE_DIR is unset; empty = <repo>/.cache/xla
     xla_cache_dir: str = ""
     # XProf/Perfetto capture directory for obs/export.py
     # maybe_device_trace; empty = the WUKONG_XPROF_DIR env form, then no

@@ -4,9 +4,10 @@ Role: the emit half of known_to_unknown expansion (the reference computes it
 with per-row pointer chasing + prefix sums on CUDA — gpu_hash.cu:262-477 +
 gpu_engine_cuda.hpp:112-197). The XLA merge path (tpu_kernels.merge_expand)
 pays, per OUTPUT element, one scatter (~13 ns), one cummax (~2.5 ns) and one
-random gather (~9.5 ns) on the [cap_out] grid — ~25 ns/elem, measured on
-v5e. This kernel streams the segment's EDGE array through VMEM instead and
-re-derives everything from prefix sums of sparse per-edge deltas:
+random gather (~9.5 ns) on the [cap_out] grid — ~25 ns/elem (an earlier
+installation's figures; not measured on the attached chip). This kernel
+streams the segment's EDGE array through VMEM instead and re-derives
+everything from prefix sums of sparse per-edge deltas:
 
   - the XLA side scatters O(R) run boundaries (R = matched frontier rows)
     into two [E] delta arrays: dsel (+1 at run start, -1 at run end) and
@@ -66,11 +67,65 @@ FORCE_INTERPRET = False
 # silicon — the default single-pass dot rounds inputs to 8 significant
 # bits; each output row selects at most one input and halves are < 2^16 so
 # fp32 accumulation is lossless). stream_available() probes the MXU variant
-# first and flips to VPU if it fails to lower or corrupts; relative cost is
-# a first-healthy-session measurement, not a constant.
+# first and flips to VPU if it fails to lower or corrupts; their relative
+# cost is not measured on the attached chip.
 USE_MXU_COMPACT = True
 
-_stream_state = {"ok": None, "mhot": True}
+def _variant_name(mxu: bool, mhot: bool) -> str:
+    return f"{'mxu' if mxu else 'vpu'}+{'mhot' if mhot else 'nomhot'}"
+
+
+# the variant the code prefers; stream_available() reports which one runs
+FIRST_CHOICE = _variant_name(USE_MXU_COMPACT, True)
+
+_stream_state = {"ok": None, "mhot": True, "variant": None,
+                 "reason": "not probed"}
+
+
+def stream_report() -> dict:
+    """(kernel, variant, live, reason) as the capability probe left it —
+    read by chip_smoke.py and the /device report, so a kernel that was
+    dropped, or runs in other than its first-choice variant, shows."""
+    return {"kernel": "stream_expand",
+            "variant": _stream_state["variant"],
+            "live": bool(_stream_state["ok"]),
+            "reason": _stream_state["reason"]}
+
+
+def _probe_variant(mxu: bool, mhot: bool) -> str:
+    """Compile + run a tiny stream_expand in one variant; returns "" when
+    it is exact, else what was wrong. Compile errors propagate."""
+    # edge values near INT32_MAX with odd low bits: a backend that
+    # lowers the compaction dot but truncates fp32 inputs (bf16
+    # passes) would corrupt exactly these, so the probe must use
+    # values that exercise both 16-bit halves at full width
+    big = INT32_MAX - 2
+    skey = jnp.asarray([3, INT32_MAX], jnp.int32)
+    sstart = jnp.asarray([0, 0], jnp.int32)
+    sdeg = jnp.asarray([2, 0], jnp.int32)
+    edges = jnp.full(2 * TILE, INT32_MAX, jnp.int32)
+    edges = edges.at[0].set(big).at[1].set(65_537)
+    cur = jnp.full(8, INT32_MAX, jnp.int32).at[5].set(3)
+    live = jnp.ones(8, bool)
+    v, p, n, t = stream_expand(skey, sstart, sdeg, edges, cur,
+                               jnp.int32(6), live, cap_out=1024,
+                               mxu=mxu, mhot=mhot, mdup=stream_mdup())
+    got = [(int(v[i]), int(p[i])) for i in range(min(int(n), 8))]
+    if got != [(big, 5), (65_537, 5)]:
+        return f"distinct-anchor probe emitted {got}"
+    if mhot:
+        # duplicate anchors (multiplicity 2) through the m-hot arm:
+        # rows 1 and 5 both anchor key 3 — expect each edge twice
+        # with both parents (edge-repeat order)
+        cur2 = cur.at[1].set(3)
+        v, p, n, t = stream_expand(skey, sstart, sdeg, edges, cur2,
+                                   jnp.int32(6), live, cap_out=1024,
+                                   mxu=mxu, mhot=True, mdup=stream_mdup())
+        got = sorted((int(v[i]), int(p[i])) for i in range(min(int(n), 8)))
+        want = sorted([(big, 1), (big, 5), (65_537, 1), (65_537, 5)])
+        if int(t) != 4 or got != want:
+            return f"m-hot probe emitted {got} (total {int(t)})"
+    return ""
 
 
 def stream_available() -> bool:
@@ -78,64 +133,39 @@ def stream_available() -> bool:
     current backend (exercises the grid, SMEM carries, triangular matmuls,
     accumulator flush DMAs) and, when enabled, the m-hot duplicate-anchor
     arm. Preference order: (mxu, mhot) > (vpu, mhot) > (mxu, no-mhot) >
-    (vpu, no-mhot); total failure permanently selects the XLA path."""
+    (vpu, no-mhot); total failure selects the XLA path for the life of the
+    process. Every variant that is passed over is logged once with its
+    reason, and stream_report() keeps the outcome."""
     global USE_MXU_COMPACT
-    if _stream_state["ok"] is None:
-        if jax.devices()[0].platform != "tpu":
-            _stream_state["ok"] = False
-            return False
+    if _stream_state["ok"] is not None:
+        return _stream_state["ok"]
+    platform = jax.devices()[0].platform
+    if platform != "tpu":
+        _stream_state.update(ok=False, reason=f"platform is {platform}: "
+                             "the kernel runs on TPU only")
+        return False
+    from wukong_tpu.utils.logger import log_warn
 
-        def _probe(mxu: bool, mhot: bool) -> bool:
-            # edge values near INT32_MAX with odd low bits: a backend that
-            # lowers the compaction dot but truncates fp32 inputs (bf16
-            # passes) would corrupt exactly these, so the probe must use
-            # values that exercise both 16-bit halves at full width
-            big = INT32_MAX - 2
-            skey = jnp.asarray([3, INT32_MAX], jnp.int32)
-            sstart = jnp.asarray([0, 0], jnp.int32)
-            sdeg = jnp.asarray([2, 0], jnp.int32)
-            edges = jnp.full(2 * TILE, INT32_MAX, jnp.int32)
-            edges = edges.at[0].set(big).at[1].set(65_537)
-            cur = jnp.full(8, INT32_MAX, jnp.int32).at[5].set(3)
-            live = jnp.ones(8, bool)
-            v, p, n, t = stream_expand(skey, sstart, sdeg, edges, cur,
-                                       jnp.int32(6), live, cap_out=1024,
-                                       mxu=mxu, mhot=mhot,
-                                       mdup=stream_mdup())
-            if not (int(n) == 2 and int(v[0]) == big
-                    and int(v[1]) == 65_537 and int(p[0]) == 5
-                    and int(p[1]) == 5):
-                return False
-            if mhot:
-                # duplicate anchors (multiplicity 2) through the m-hot arm:
-                # rows 1 and 5 both anchor key 3 — expect each edge twice
-                # with both parents (edge-repeat order)
-                cur2 = cur.at[1].set(3)
-                v, p, n, t = stream_expand(skey, sstart, sdeg, edges, cur2,
-                                           jnp.int32(6), live, cap_out=1024,
-                                           mxu=mxu, mhot=True,
-                                           mdup=stream_mdup())
-                got = sorted((int(v[i]), int(p[i])) for i in range(int(n)))
-                want = sorted([(big, 1), (big, 5), (65_537, 1), (65_537, 5)])
-                return int(t) == 4 and got == want
-            return True
-
-        ok = False
-        mxu_opts = (True, False) if USE_MXU_COMPACT else (False,)
-        for mhot in (True, False):
-            for mxu in mxu_opts:
-                try:
-                    if _probe(mxu, mhot):
-                        USE_MXU_COMPACT = mxu
-                        _stream_state["mhot"] = mhot
-                        ok = True
-                        break
-                except Exception:
-                    continue
-            if ok:
-                break
-        _stream_state["ok"] = ok
-    return _stream_state["ok"]
+    mxu_opts = (True, False) if USE_MXU_COMPACT else (False,)
+    skipped = []
+    for mhot in (True, False):
+        for mxu in mxu_opts:
+            variant = _variant_name(mxu, mhot)
+            try:
+                why = _probe_variant(mxu, mhot)
+            except Exception as e:  # the compiler's or runtime's refusal
+                why = f"{type(e).__name__}: {e}"
+            if not why:
+                USE_MXU_COMPACT = mxu
+                _stream_state.update(ok=True, mhot=mhot, variant=variant,
+                                     reason="; ".join(skipped))
+                return True
+            log_warn(f"stream_expand variant {variant} not usable: {why}")
+            skipped.append(f"{variant}: {why[:300]}")
+    log_warn("stream_expand has no usable variant; dense expansions take "
+             "the XLA emit")
+    _stream_state.update(ok=False, reason="; ".join(skipped))
+    return False
 
 
 def mhot_enabled() -> bool:
@@ -385,15 +415,9 @@ def _emit_kernel(edges_ref, dsel_ref, dpar_ref,
 
 
 def _tpu_compiler_params(pltpu):
-    """Sequential-grid + side-effect compiler params across the pallas API
-    rename: ``CompilerParams`` (with ``has_side_effects``) is jax >= 0.5;
-    0.4.x only has ``TPUCompilerParams`` without the flag — safe to drop
-    there because every kernel's outputs are consumed by the caller, so the
-    call is never DCE'd."""
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is not None:
-        return cls(dimension_semantics=("arbitrary",), has_side_effects=True)
-    return pltpu.TPUCompilerParams(dimension_semantics=("arbitrary",))
+    """Sequential-grid + side-effect compiler params."""
+    return pltpu.CompilerParams(dimension_semantics=("arbitrary",),
+                                has_side_effects=True)
 
 
 def _stream_emit(edges2, dsel2, dpar2, cap_out: int, interpret: bool = False,

@@ -165,7 +165,7 @@ def to_device_i32(arr):
     any value outside int32 range instead of truncating. Offsets past
     2^31 (a >2G-edge segment) and out-of-range ids therefore degrade the
     query to the host kernels, never to wrong answers; the parity tests
-    drive the same kernels in int64 under ``jax.experimental.enable_x64``
+    drive the same kernels in int64 under ``jax.enable_x64``
     to pin >2^31-safe behavior when 64-bit mode is on."""
     import jax.numpy as jnp
 

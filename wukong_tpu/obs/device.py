@@ -597,7 +597,7 @@ def note_feedback(kind: str, reason: str) -> None:
 
 def note_compile_cache(outcome: str, site: str = "boot") -> None:
     """Compile-cache outcomes by site: utils/compilecache.py reports
-    persistent-cache setup (``available`` / ``unavailable``, site
+    persistent-cache setup (``available``, site
     ``boot``) and engine/template_compile.py charges its whole-plan
     program cache (``hit`` / ``miss`` / ``evict``, site ``template``)
     — a storm of whole-plan variants is visible to the same counter
@@ -715,6 +715,16 @@ def render_device(k: int | None = None) -> tuple[str, dict]:
         js["template_demotions"] = dict(demoted)
         lines.append("TEMPLATE  demoted  " + "  ".join(
             f"{t[:16]}:{r}" for t, r in sorted(demoted.items())))
+    # which Pallas kernel variant the capability probe selected, or why
+    # none (engine/tpu_stream.py) — a dropped kernel must not go unseen
+    from wukong_tpu.engine.tpu_stream import stream_report
+
+    kern = stream_report()
+    js["kernels"] = [kern]
+    lines.append(
+        f"KERNEL    {kern['kernel']}  "
+        f"{'live ' + kern['variant'] if kern['live'] else 'not live'}"
+        + (f"  ({kern['reason'][:120]})" if kern["reason"] else ""))
     lines.append(
         f"RESIDENT  total {res['total_bytes']:,}B  "
         f"high-water {res['high_water_bytes']:,}B  "

@@ -955,10 +955,7 @@ class DistEngine:
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
 
-        try:
-            from jax import shard_map
-        except ImportError:  # pre-0.5 JAX exposes it under experimental
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         D = self.D
         axis = self.axis
@@ -1098,14 +1095,9 @@ class DistEngine:
             }
 
         out_specs = {"table": P(axis), "n": P(axis), "totals": P(axis)}
-        try:
-            mapped = shard_map(shard_fn, mesh=self.mesh,
-                               in_specs=tuple(arg_specs), out_specs=out_specs,
-                               check_vma=False)
-        except TypeError:  # pre-0.5 JAX names the replication check check_rep
-            mapped = shard_map(shard_fn, mesh=self.mesh,
-                               in_specs=tuple(arg_specs), out_specs=out_specs,
-                               check_rep=False)
+        mapped = shard_map(shard_fn, mesh=self.mesh,
+                           in_specs=tuple(arg_specs), out_specs=out_specs,
+                           check_vma=False)
         return jax.jit(mapped)
 
 

@@ -586,9 +586,6 @@ def main(argv=None):
                     help="enable thread->core binding from a core.bind file "
                          "(reference: wukong -b, bind.hpp)")
     args = ap.parse_args(argv)
-    from wukong_tpu.utils.jaxenv import respect_platform_env
-
-    respect_platform_env()
     # cold-start economics (round-4 verdict Weak #3): compiled chains
     # persist across processes, so a restarted console re-loads programs
     # in ~ms instead of re-paying multi-second compiles

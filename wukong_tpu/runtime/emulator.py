@@ -43,6 +43,7 @@ from wukong_tpu.utils.errors import (
     WukongError,
 )
 from wukong_tpu.utils.logger import log_info
+from wukong_tpu.utils.paths import QUERIES
 from wukong_tpu.utils.timer import get_usec
 
 
@@ -64,13 +65,12 @@ def load_mix_config(path: str, str_server) -> MixConfig:
         entries.append((parts[0], int(parts[1])))
     templates, heavies, weights = [], [], []
     for i, (qpath, w) in enumerate(entries):
-        # mix-config paths are relative to the suite root (scripts/ dir)
-        for root in (os.path.dirname(path), base,
-                     "/root/reference/scripts", ""):
-            cand = os.path.join(root, qpath) if root else qpath
-            if os.path.exists(cand):
-                qpath = cand
-                break
+        # mix-config paths are relative to the reference's scripts/ dir,
+        # whose sparql_query/ is <repo>/queries here
+        in_tree = os.path.join(QUERIES, qpath.removeprefix("sparql_query/"))
+        qpath = next((c for c in (os.path.join(os.path.dirname(path), qpath),
+                                  os.path.join(base, qpath), qpath)
+                      if os.path.exists(c)), in_tree)
         text = open(qpath).read()
         if i < nlights:
             templates.append(Parser(str_server).parse_template(text))

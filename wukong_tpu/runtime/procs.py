@@ -191,6 +191,9 @@ def worker_main(conn, group_id: int, shard_ids: list, num_shards: int,
     """Entry point of one worker process (spawn context): recover the
     owned partitions (newest checkpoint + WAL tail — the normal PR 5
     paths), then serve transport ops on a loopback socket forever."""
+    # a chip belongs to one process and the parent may hold it: a worker is
+    # numpy-only, and should jax ever load here it must not ask for the chip
+    os.environ["JAX_PLATFORMS"] = "cpu"
     from wukong_tpu.store.dynamic import insert_triples
     from wukong_tpu.store.persist import (
         checkpoint_part_path,

@@ -127,7 +127,7 @@ def check_vid_range(triples: np.ndarray) -> None:
     radix sort (wukong_native.cpp) extracts unsigned digits and relies on
     non-negative ids, so a negative id mis-sorts on the native path while
     the np.lexsort fallback orders it correctly — a toolchain-dependent
-    store divergence unless rejected here (ADVICE.md round-5 #1)."""
+    store divergence unless rejected here."""
     if len(triples) and int(triples.max()) >= 2**31 - 1:
         from wukong_tpu.utils.errors import ErrorCode, WukongError
 
