@@ -1,0 +1,8 @@
+"""``memory_stats()["peak_bytes_in_use"]`` of the serving device after the
+window, in GiB."""
+
+
+def read(run):
+    if not run.memory_peak_bytes:
+        return None
+    return run.memory_peak_bytes / 2 ** 30
