@@ -274,7 +274,8 @@ def serve_phase(smoke: Smoke, proxy, want: dict, device: str | None) -> None:
         q = proxy.run_single_query(_text(qn), device=pin, blind=False)
         first_s = time.perf_counter() - t0
         comp = smoke.compile_since(mark)
-        attempts = getattr(proxy.tpu, "_last_attempts", None)
+        attempts = next((sp.attrs.get("attempts") for sp in q.trace.spans
+                         if sp.name == "tpu.chain"), None)
         fields = _served(smoke, f"{label} {qn}", q, want[qn], proxy.dist)
         mark = smoke.compile_mark()
         t0 = time.perf_counter()

@@ -63,6 +63,8 @@ def test_phases_run_and_parse(smoke_mod, capsys):
     assert serve["lubm_q3"]["route"] == "planner-empty"
     assert {ln["route"] for q, ln in serve.items() if q != "lubm_q3"} \
         <= {"tpu.chain", "template.plan"}
+    walked = [ln for ln in serve.values() if ln["route"] == "tpu.chain"]
+    assert walked and all(ln["chain_attempts"] >= 1 for ln in walked)
     batch = {ln["query"]: ln for ln in by_phase["batch"]}
     assert batch.pop("lubm_q3")["route"] == "planner-empty"
     assert len(batch) == 6 and all(ln["all_equal"] for ln in batch.values())

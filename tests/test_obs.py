@@ -202,20 +202,6 @@ def test_maybe_start_trace_respects_knobs(monkeypatch):
     assert got == 4  # 1 in N sampling
 
 
-def test_step_trace_shim_retired():
-    """runtime/tracing.py carried a deprecation shim for one release
-    (PR 3); PR 7 retired it — the import must fail with a pointer to the
-    canonical homes, and the canonical StepTrace must still work."""
-    with pytest.raises(ImportError, match="wukong_tpu.obs.trace"):
-        import wukong_tpu.runtime.tracing  # noqa: F401
-    from wukong_tpu.obs.trace import StepTrace
-
-    tr = StepTrace()
-    with tr.span("expand"):
-        pass
-    assert tr.summary()["expand"]["count"] == 1
-
-
 # ---------------------------------------------------------------------------
 # end-to-end: traced query through the proxy (acceptance span set)
 # ---------------------------------------------------------------------------

@@ -135,21 +135,6 @@ def test_engine_pool_executes_and_steals(proxy):
         pool.stop()
 
 
-def test_step_trace():
-    # canonical home is wukong_tpu.obs (PR 3); runtime.tracing re-exports
-    from wukong_tpu.obs import StepTrace
-
-    tr = StepTrace()
-    with tr.span("expand"):
-        pass
-    with tr.span("expand"):
-        pass
-    with tr.span("member"):
-        pass
-    s = tr.summary()
-    assert s["expand"]["count"] == 2 and s["member"]["count"] == 1
-
-
 def test_emulator_heavy_mix(proxy, monkeypatch):
     monkeypatch.setattr(Global, "enable_tpu", False)
     mix = load_mix_config(

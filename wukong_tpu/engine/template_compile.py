@@ -60,6 +60,7 @@ from wukong_tpu.obs.device import (
     read_device_input,
 )
 from wukong_tpu.obs.metrics import get_registry
+from wukong_tpu.obs.trace import span, traced_execute
 from wukong_tpu.runtime import faults
 from wukong_tpu.types import PREDICATE_ID, TYPE_ID, AttrType, IN
 from wukong_tpu.utils.timer import get_usec
@@ -582,7 +583,17 @@ class TemplateCompiledEngine:
         served (byte-identical to the host walk), False when the plan
         shape is not compilable (caller walks, nothing latched). Raises
         on compile/dispatch failure with ``q`` UNTOUCHED — the caller
-        latches the per-template demotion and walks."""
+        latches the per-template demotion and walks. Traced, the whole
+        attempt is one ``template.execute`` span; ``template.stage``
+        (program lookup, staging on a miss), ``template.dispatch``,
+        ``template.sync`` and ``template.commit`` lie inside it."""
+        return traced_execute(
+            q, "template.execute", lambda: self._try_execute(q),
+            lambda: {"label": getattr(q, "_template_label", None),
+                     "attempts": getattr(q, "_template_attempts", 0),
+                     "rows": q.result.nrows})
+
+    def _try_execute(self, q) -> bool:
         ext = extract_template(q)
         if ext is None:
             _M_EXEC.labels(outcome="unsupported").inc()
@@ -597,18 +608,22 @@ class TemplateCompiledEngine:
         version = self._version()
         caps = self._initial_caps(tsig, spec, est)
         retries = max(int(Global.template_capacity_retries), 0)
+        tr = getattr(q, "trace", None)
         for _attempt in range(retries + 1):
-            key = _program_key(tsig, version, caps, blind)
-            prog = self._cache_get(key)
-            if prog is None:
-                prog = self._cache_put(key, self._stage(
-                    tsig, spec, caps, v2c, proj, width, blind))
-            out = self._dispatch(prog, q)
+            q._template_attempts = _attempt + 1
+            with span(tr, "template.stage"):
+                key = _program_key(tsig, version, caps, blind)
+                prog = self._cache_get(key)
+                if prog is None:
+                    prog = self._cache_put(key, self._stage(
+                        tsig, spec, caps, v2c, proj, width, blind))
+            out = self._dispatch(prog, q, tr)
             if out is not None:
                 tbl, val = out
                 with self._lock:
                     self._good_caps[(tsig, version)] = caps
-                self._commit(q, prog, tbl, val)
+                with span(tr, "template.commit"):
+                    self._commit(q, prog, tbl, val)
                 q._template_compiled = True
                 q._template_label = prog.label
                 _M_EXEC.labels(outcome="compiled").inc()
@@ -619,25 +634,30 @@ class TemplateCompiledEngine:
         raise TemplateOverflow(
             f"padded table overflowed after {retries + 1} attempts")
 
-    def _dispatch(self, prog: _Program, q):
+    def _dispatch(self, prog: _Program, q, tr):
         """One fused dispatch, charged at the sync point. Returns the
         fetched (table, valid) on success, None on capacity overflow
         (per-step totals stashed for the regrow)."""
         faults.site("template.dispatch")
         t0 = get_usec()
-        if prog.blind:
-            totals, ovfs, live = prog.fn(*prog.args)
-            tbl = val = None
-            live = int(live)  # blocks: the sync point
-            nbytes = 12
-        else:
-            table, valid, totals, ovfs, live = prog.fn(*prog.args)
-            tbl = np.asarray(table)  # blocks: the sync point
-            val = np.asarray(valid)
-            live = int(live)
-            nbytes = int(tbl.nbytes) + int(val.nbytes)
-        self._last_totals = np.asarray(totals)
-        self._last_ovfs = np.asarray(ovfs)
+        with span(tr, "template.dispatch"):
+            if tr is not None:
+                tr.event("device.dispatch", kernel=prog.label)
+            outs = prog.fn(*prog.args)
+        with span(tr, "template.sync"):
+            if prog.blind:
+                totals, ovfs, live = outs
+                tbl = val = None
+                live = int(live)  # blocks: the sync point
+                nbytes = 12
+            else:
+                table, valid, totals, ovfs, live = outs
+                tbl = np.asarray(table)  # blocks: the sync point
+                val = np.asarray(valid)
+                live = int(live)
+                nbytes = int(tbl.nbytes) + int(val.nbytes)
+            self._last_totals = np.asarray(totals)
+            self._last_ovfs = np.asarray(ovfs)
         wall = get_usec() - t0
         rec = maybe_device_dispatch(
             SITE, template=prog.label, live=live,

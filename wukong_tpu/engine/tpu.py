@@ -36,6 +36,7 @@ from wukong_tpu.engine import tpu_kernels as K
 from wukong_tpu.engine.cpu import CPUEngine
 from wukong_tpu.engine.device_store import DeviceStore
 from wukong_tpu.obs.device import maybe_device_dispatch
+from wukong_tpu.obs.trace import span, traced_execute, traced_step
 from wukong_tpu.utils.timer import get_usec
 from wukong_tpu.sparql.ir import NO_RESULT, PGType, SPARQLQuery
 from wukong_tpu.types import IN, OUT, PREDICATE_ID, TYPE_ID, AttrType
@@ -73,7 +74,6 @@ class TPUEngine:
         # pattern-tuple -> {step: rows}; bounded LRU (a hot mixed workload
         # used to lose EVERY estimate at the old clear-at-4096 threshold)
         self._est_cache = LRUCache(4096)
-        self._last_attempts = 0  # chain attempts of the last query (trace)
         from wukong_tpu.engine.tpu_merge import MergeExecutor
 
         self.merge = MergeExecutor(self)  # sort-merge batch chains (v2)
@@ -111,8 +111,6 @@ class TPUEngine:
 
     # ------------------------------------------------------------------
     def execute(self, q: SPARQLQuery, from_proxy: bool = True) -> SPARQLQuery:
-        from wukong_tpu.obs.trace import traced_execute
-
         return traced_execute(
             q, "tpu.execute", lambda: self._execute_impl(q, from_proxy),
             lambda: {"rows": q.result.nrows,
@@ -172,12 +170,13 @@ class TPUEngine:
                         # no shared binding (e.g. optional-only queries) or
                         # attr columns: the in-place host formulation
                         self.cpu._execute_optional(q)
-            if q.pattern_group.filters:
-                self.cpu._execute_filters(q)
-            if getattr(q, "knn", None) is not None:
-                self.cpu._knn_post(q)
-            if from_proxy:
-                self.cpu._final_process(q)
+            with span(getattr(q, "trace", None), "tpu.finalize"):
+                if q.pattern_group.filters:
+                    self.cpu._execute_filters(q)
+                if getattr(q, "knn", None) is not None:
+                    self.cpu._knn_post(q)
+                if from_proxy:
+                    self.cpu._final_process(q)
         except (QueryTimeout, BudgetExceeded) as e:
             from wukong_tpu.runtime.resilience import mark_partial
 
@@ -200,6 +199,7 @@ class TPUEngine:
             probe.bind(pat)
             device_steps += 1
 
+        tr = getattr(q, "trace", None)
         if device_steps:
             # pin this query's segments for the chain's lifetime (the
             # GPUCache conflict-aware eviction analogue, gpu_cache.hpp).
@@ -218,30 +218,29 @@ class TPUEngine:
             pins += [("vpv", int(q.get_pattern(i).direction))
                      for i in range(vlo, q.pattern_step + device_steps)
                      if q.get_pattern(i).predicate < 0]
-            self.dstore.pin(pins)
-            if Global.gpu_enable_pipeline:
-                # stage every chain segment up front: device_put dispatches
-                # asynchronously, so the H2D transfers overlap the first
-                # steps' compute (gpu_engine_cuda.hpp:143-150's second-stream
-                # prefetch, collapsed into the async dispatch queue). An
-                # index-origin START consumes an index list, not a segment —
-                # staging its (TYPE_ID, dir) segment would build the whole
-                # type CSR for nothing, so it is skipped.
-                lo = max(q.pattern_step, vlo)
-                if lo == 0 and q.start_from_index() \
-                        and _is_index_start(q.get_pattern(0)):
-                    lo = 1
-                self.dstore.prefetch(
-                    q.get_pattern(i) for i in
-                    range(lo, q.pattern_step + device_steps))
+            with span(tr, "tpu.stage"):
+                self.dstore.pin(pins)
+                if Global.gpu_enable_pipeline:
+                    # stage every chain segment up front: device_put
+                    # dispatches asynchronously, so the H2D transfers overlap
+                    # the first steps' compute (gpu_engine_cuda.hpp:143-150's
+                    # second-stream prefetch, collapsed into the async
+                    # dispatch queue). An index-origin START consumes an
+                    # index list, not a segment — staging its (TYPE_ID, dir)
+                    # segment would build the whole type CSR for nothing, so
+                    # it is skipped.
+                    lo = max(q.pattern_step, vlo)
+                    if lo == 0 and q.start_from_index() \
+                            and _is_index_start(q.get_pattern(0)):
+                        lo = 1
+                    self.dstore.prefetch(
+                        q.get_pattern(i) for i in
+                        range(lo, q.pattern_step + device_steps))
             try:
                 self._run_chain_pinned(q, device_steps)
             finally:
                 self.dstore.unpin(pins)
         # host fallback for any remaining steps
-        from wukong_tpu.obs.trace import traced_step
-
-        tr = getattr(q, "trace", None)
         while not q.done_patterns():
             traced_step(tr, q, "tpu.host_step",
                         lambda: self.cpu._execute_one_pattern(q))
@@ -261,33 +260,35 @@ class TPUEngine:
                     if q.pattern_step == 0 else {})
         # chain-level span: per-BGP-step work is fused into one compiled
         # dispatch here, so the trace carries steps + kernel-dispatch count
-        # (attempts x steps) + rows out at chain granularity
+        # (attempts x steps) + rows out at chain granularity; each attempt
+        # is a tpu.dispatch / tpu.sync pair inside it
         tr = getattr(q, "trace", None)
-        sp = (tr.start_span("tpu.chain", steps=device_steps,
-                            rows_in=q.result.nrows)
-              if tr is not None else None)
-        try:
-            self._chain_attempts(q, device_steps, cap_override, step_est,
-                                 blind_ok)
-        finally:
-            if sp is not None:
-                tr.end_span(sp, attempts=self._last_attempts,
-                            dispatches=self._last_attempts * device_steps,
-                            rows_out=q.result.nrows)
+        with span(tr, "tpu.chain", steps=device_steps,
+                  rows_in=q.result.nrows, attempts=0) as sp:
+            try:
+                self._chain_attempts(q, device_steps, cap_override, step_est,
+                                     blind_ok, tr, sp)
+            finally:
+                if sp is not None:
+                    sp.attrs.update(
+                        dispatches=sp.attrs["attempts"] * device_steps,
+                        rows_out=q.result.nrows)
 
     def _chain_attempts(self, q: SPARQLQuery, device_steps: int,
                         cap_override: dict, step_est: dict,
-                        blind_ok: bool) -> None:
+                        blind_ok: bool, tr, chain_span) -> None:
         from wukong_tpu.runtime.resilience import charge_query, check_query
 
-        self._last_attempts = 0
         for _attempt in range(8):
-            self._last_attempts = _attempt + 1
+            if chain_span is not None:
+                chain_span.attrs["attempts"] = _attempt + 1
             check_query(q, f"tpu.chain attempt {_attempt}")
             t0 = get_usec()
-            state = self._dispatch_chain(q, device_steps, cap_override,
-                                         step_est)
-            host_table, n, totals = state.sync(blind=blind_ok)
+            with span(tr, "tpu.dispatch"):
+                state = self._dispatch_chain(q, device_steps, cap_override,
+                                             step_est)
+            with span(tr, "tpu.sync"):
+                host_table, n, totals = state.sync(blind=blind_ok)
             moved = 4 * (1 + len(totals))  # the ride-along scalars
             if not blind_ok and hasattr(host_table, "nbytes"):
                 moved += int(host_table.nbytes)
@@ -341,6 +342,9 @@ class TPUEngine:
         import jax.numpy as jnp
 
         start, pid, d, end = pat.subject, pat.predicate, pat.direction, pat.object
+        # traced, every call of a jitted kernel below is one device.dispatch
+        # event
+        tr = getattr(q, "trace", None)
 
         if state.table is None and state.width > 0:
             # seeded chain (UNION child over the parent's binding table):
@@ -371,6 +375,8 @@ class TPUEngine:
                     edges, real = edges[lo:hi], hi - lo
                 cap = cap_override.get(step) or K.next_capacity(real, self.cap_min,
                                                                 self.cap_max)
+                if tr is not None:
+                    tr.event("device.dispatch", kernel="init_from_list")
                 table, nn = K.init_from_list(edges, jnp.int32(real), cap)
                 state.begin(table, nn, end, est_rows=real)
                 state.local_var = end
@@ -438,6 +444,8 @@ class TPUEngine:
             cap_out = cap_override.get(step) or K.next_capacity(
                 max(est, self.cap_min), self.cap_min, self.cap_max)
             fd = self._fp_dup(vseg)
+            if tr is not None:
+                tr.event("device.dispatch", kernel="expand2")
             out, nn, total = K.expand2(
                 state.table, state.n, vseg.bkey, vseg.bstart, vseg.bdeg,
                 vseg.edges2, vseg.edges, col=col, cap_out=cap_out,
@@ -450,6 +458,8 @@ class TPUEngine:
                 # same program, then drop the value row — the surviving
                 # table binds only the predicate column (CPU layout parity)
                 state.totals.append((step, total, cap_out))
+                if tr is not None:
+                    tr.event("device.dispatch", kernel="compact")
                 keep = (jnp.arange(cap_out, dtype=jnp.int32) < nn) \
                     & (out[-1] == jnp.int32(end))
                 out, nn = K.compact(out, keep)
@@ -477,6 +487,8 @@ class TPUEngine:
             cap_out = cap_override.get(step) or K.next_capacity(
                 max(est, self.cap_min), self.cap_min, self.cap_max)
             fd = self._fp_dup(seg)
+            if tr is not None:
+                tr.event("device.dispatch", kernel="expand")
             out, nn, total = K.expand(
                 state.table, state.n, seg.bkey, seg.bstart, seg.bdeg,
                 seg.edges, col=col, cap_out=cap_out,
@@ -494,6 +506,8 @@ class TPUEngine:
                 else:
                     vals = jnp.full(state.table.shape[1], np.int32(end))
                 fd = self._fp_dup(seg)
+                if tr is not None:
+                    tr.event("device.dispatch", kernel="member_mask_known")
                 keep = K.member_mask_known(
                     state.table, state.n, vals, seg.bkey, seg.bstart,
                     seg.bdeg, seg.edges, col=col, max_probe=seg.max_probe,
@@ -510,10 +524,14 @@ class TPUEngine:
             if cap_new is not None and cap_new < C:
                 # estimate-driven shrink: totals ride-along so an
                 # underestimate retries the chain, never drops rows
+                if tr is not None:
+                    tr.event("device.dispatch", kernel="compact_to")
                 out, nn, total = K.compact_to(state.table, keep, cap_new)
                 state.advance_filter(out, nn)
                 state.totals.append((step, total, cap_new))
             else:
+                if tr is not None:
+                    tr.event("device.dispatch", kernel="compact")
                 out, nn = K.compact(state.table, keep)
                 state.advance_filter(out, nn)
 

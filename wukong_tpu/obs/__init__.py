@@ -4,7 +4,8 @@ What the reference never had (SURVEY §5: "no pervasive tracing framework")
 and every perf PR after this one stands on:
 
 - trace.py    — per-query :class:`QueryTrace` (trace id + span stack),
-  thread-ambient activation for deep layers, sampling knobs, StepTrace
+  thread-ambient activation for deep layers, sampling knobs, the ``span``
+  helper every layer boundary opens its span (and ``wk:`` annotation) through
 - metrics.py  — process-wide :class:`MetricsRegistry` (labeled counters /
   gauges / histograms; Prometheus-text + JSON snapshot exporters)
 - recorder.py — :class:`FlightRecorder` ring of recent traces with
@@ -81,7 +82,6 @@ from wukong_tpu.obs.slo import (
 from wukong_tpu.obs.trace import (
     QueryTrace,
     Span,
-    StepTrace,
     activate,
     current,
     maybe_start_trace,
@@ -93,7 +93,7 @@ __all__ = [
     "FlightRecorder", "MIGRATION_PLAN_FIELDS", "MetricsRegistry",
     "MetricsSnapshotter", "MetricsTSDB", "MigrationPlan",
     "PlacementAdvisor", "QueryTrace", "SLOSpec", "ShardLineage", "Span",
-    "StepTrace", "activate", "chrome_trace_events", "current",
+    "activate", "chrome_trace_events", "current",
     "device_trace", "emit_event", "get_advisor", "get_journal",
     "get_lineage", "get_overload", "get_recorder", "get_registry",
     "get_slo", "get_tsdb", "health_report", "maybe_device_trace",

@@ -6,11 +6,11 @@ complete ("X") events, span events become instants ("i"), one virtual
 thread row per (trace, real thread) so concurrent queries don't interleave
 on one track. ``write_chrome_trace`` wraps that in the JSON envelope.
 
-``device_trace`` (absorbed from the retired runtime/tracing.py) scopes the
-JAX profiler around a block — the XProf/TensorBoard view of the device side
-of a traced query. ``maybe_device_trace`` gates it on the ``xprof_dir``
-config knob (env form ``WUKONG_XPROF_DIR``) so the proxy/emulator wire it
-unconditionally at zero default cost.
+``device_trace`` scopes the JAX profiler around a block — the
+XProf/TensorBoard view of the device side of a traced query, with the
+trace's spans beside it as ``wk:`` annotations. ``maybe_device_trace``
+gates it on the ``xprof_dir`` config knob (env form ``WUKONG_XPROF_DIR``)
+so the proxy/emulator wire it unconditionally at zero default cost.
 """
 
 from __future__ import annotations
@@ -74,7 +74,8 @@ def chrome_trace_events(traces) -> list[dict]:
             events.append({
                 "name": sp.name, "cat": tr.kind, "ph": "X",
                 "ts": sp.t0_us, "dur": max(sp.dur_us, 1), "pid": 0, "tid": t,
-                "args": {**sp.attrs, "trace_id": tr.trace_id}})
+                "args": {**sp.attrs, "trace_id": tr.trace_id,
+                         "parent": sp.parent}})
             for (ts, name, attrs) in sp.events:
                 events.append({
                     "name": name, "cat": tr.kind, "ph": "i", "s": "t",

@@ -48,7 +48,7 @@ COMPONENTS = ("queue", "parse", "plan", "execute", "fetch")
 
 #: top-level engine execution spans (one per engine family)
 EXECUTE_SPANS = frozenset({"cpu.execute", "tpu.execute", "dist.execute",
-                           "wcoj.execute"})
+                           "wcoj.execute", "template.execute"})
 
 #: per-BGP-step spans carrying step index + rows in/out attributes
 STEP_SPANS = frozenset({"cpu.step", "tpu.host_step"})

@@ -14,9 +14,12 @@ Granularity contract: spans are opened per STEP (BGP step, chain dispatch,
 shard fetch, stream epoch phase), never per row — with tracing off, every
 hook is a single ``getattr``/``None`` check, so the hot path stays flat.
 
-The host-side per-label aggregate recorder (:class:`StepTrace`) and the
-scoped JAX device profiler (``device_trace`` in obs/export.py) were
-absorbed from the retired ``runtime/tracing.py``.
+One clock with the device trace: while a :class:`QueryTrace` is live,
+every span opened through :func:`span` (and ``traced_execute`` /
+``traced_step``) also enters ``jax.profiler.TraceAnnotation("wk:" + name)``,
+so the program's spans land on the host plane of whatever profile is being
+captured, on the profiler's clock. The program starts no profiler session
+for this (``device_trace`` in obs/export.py scopes one on request).
 """
 
 from __future__ import annotations
@@ -45,11 +48,17 @@ _sample_seqs: dict[str, itertools.count] = {}
 class Span:
     """One timed operation inside a trace. ``end()`` is idempotent and may
     run on a different thread than ``start`` (queue spans end in the engine
-    thread that popped the query)."""
+    thread that popped the query).
 
-    __slots__ = ("name", "t0_us", "t1_us", "attrs", "events", "depth", "tid")
+    ``index`` is the span's place in ``QueryTrace.spans`` and ``parent`` the
+    index of the span that caused it (the innermost span open on the same
+    thread at start; -1 at trace level)."""
 
-    def __init__(self, name: str, attrs: dict, depth: int, tid: int):
+    __slots__ = ("name", "t0_us", "t1_us", "attrs", "events", "depth", "tid",
+                 "index", "parent")
+
+    def __init__(self, name: str, attrs: dict, depth: int, tid: int,
+                 index: int = -1, parent: int = -1):
         self.name = name
         self.t0_us = get_usec()
         self.t1_us: int | None = None
@@ -57,6 +66,8 @@ class Span:
         self.events: list[tuple[int, str, dict]] = []
         self.depth = depth
         self.tid = tid
+        self.index = index
+        self.parent = parent
 
     def event(self, name: str, **attrs) -> None:
         self.events.append((get_usec(), name, attrs))
@@ -73,7 +84,8 @@ class Span:
 
     def to_dict(self) -> dict:
         return {"name": self.name, "t0_us": self.t0_us,
-                "dur_us": self.dur_us, "depth": self.depth, "tid": self.tid,
+                "dur_us": self.dur_us, "depth": self.depth,
+                "parent": self.parent, "tid": self.tid,
                 "attrs": dict(self.attrs),
                 "events": [{"t_us": t, "name": n, "attrs": a}
                            for t, n, a in self.events]}
@@ -109,7 +121,9 @@ class QueryTrace:
         tid = threading.get_ident()
         with self._lock:
             stack = self._stacks[tid]
-            sp = Span(name, attrs, depth=len(stack), tid=tid)
+            sp = Span(name, attrs, depth=len(stack), tid=tid,
+                      index=len(self.spans),
+                      parent=stack[-1].index if stack else -1)
             stack.append(sp)
             self.spans.append(sp)
         return sp
@@ -123,13 +137,10 @@ class QueryTrace:
                     stack.remove(sp)
                     break
 
-    @contextlib.contextmanager
     def span(self, name: str, **attrs):
-        sp = self.start_span(name, **attrs)
-        try:
-            yield sp
-        finally:
-            self.end_span(sp)
+        """``with trace.span(name) as sp``: :func:`span` on a trace that is
+        known to be there."""
+        return _SpanScope(self, name, attrs)
 
     def event(self, name: str, **attrs) -> None:
         """Attach to the current thread's innermost open span, falling back
@@ -140,7 +151,7 @@ class QueryTrace:
             if stack:
                 stack[-1].events.append((get_usec(), name, attrs))
                 return
-            sp = Span(name, attrs, depth=0, tid=tid)
+            sp = Span(name, attrs, depth=0, tid=tid, index=len(self.spans))
             sp.t1_us = sp.t0_us
             self.spans.append(sp)
 
@@ -223,8 +234,53 @@ def maybe_start_trace(kind: str = "query", qid: int | None = None,
 
 
 # ---------------------------------------------------------------------------
-# engine instrumentation helpers (one definition; cpu/tpu/dist share them)
+# instrumentation helpers (one definition; proxy and engines share them)
 # ---------------------------------------------------------------------------
+
+_BARE = contextlib.nullcontext()  # what span() hands out with tracing off
+_annotation = None  # jax.profiler.TraceAnnotation, imported on first use
+
+
+def _annotate(name: str):
+    """``TraceAnnotation("wk:" + name)``: the span on the profiler's clock,
+    next to the device's operations, where a profile is being captured."""
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    return _annotation("wk:" + name)
+
+
+class _SpanScope:
+    """``with span(tr, name) as sp``: one span of ``tr`` on this thread and
+    its ``wk:`` annotation."""
+
+    __slots__ = ("tr", "name", "attrs", "sp", "note")
+
+    def __init__(self, tr: QueryTrace, name: str, attrs: dict):
+        self.tr, self.name, self.attrs = tr, name, attrs
+
+    def __enter__(self) -> Span:
+        self.note = _annotate(self.name)
+        self.note.__enter__()
+        self.sp = self.tr.start_span(self.name, **self.attrs)
+        return self.sp
+
+    def __exit__(self, *exc):
+        self.tr.end_span(self.sp)
+        return self.note.__exit__(*exc)
+
+
+def span(tr: QueryTrace | None, name: str, **attrs):
+    """The context manager every layer boundary opens its span through.
+    ``tr is None`` (tracing off, or the query not sampled) hands back one
+    shared no-op whose ``as`` value is None, so the body runs bare; a caller
+    that closes the span with attributes guards on that None."""
+    if tr is None:
+        return _BARE
+    return _SpanScope(tr, name, attrs)
+
 
 def traced_execute(q, span_name: str, body, end_attrs=None):
     """Engine execute() wrapper: activate ``q.trace`` thread-ambiently and
@@ -234,12 +290,12 @@ def traced_execute(q, span_name: str, body, end_attrs=None):
     tr = getattr(q, "trace", None)
     if tr is None:
         return body()
-    with activate(tr):
-        sp = tr.start_span(span_name)
+    with activate(tr), _SpanScope(tr, span_name, {}) as sp:
         try:
             return body()
         finally:
-            tr.end_span(sp, **(end_attrs() if end_attrs is not None else {}))
+            if end_attrs is not None:
+                sp.attrs.update(end_attrs())
 
 
 def traced_step(tr, q, span_name: str, fn) -> None:
@@ -249,37 +305,9 @@ def traced_step(tr, q, span_name: str, fn) -> None:
         fn()
         return
     rows_in = q.result.nrows
-    sp = tr.start_span(span_name, step=q.pattern_step,
-                       pattern=repr(q.get_pattern()))
-    try:
-        fn()
-    finally:
-        tr.end_span(sp, rows_in=rows_in, rows_out=q.result.nrows)
-
-
-# ---------------------------------------------------------------------------
-# StepTrace — absorbed from runtime/tracing.py (host-side per-label
-# aggregates; engines can feed it when a full QueryTrace is overkill)
-# ---------------------------------------------------------------------------
-
-class StepTrace:
-    """Per-query step timings: step label -> [usec]. Feed from engine loops."""
-
-    def __init__(self):
-        self.records: dict[str, list[int]] = defaultdict(list)
-        self._open: dict[str, int] = {}
-
-    @contextlib.contextmanager
-    def span(self, label: str):
-        t0 = get_usec()
+    with _SpanScope(tr, span_name, {"step": q.pattern_step,
+                                    "pattern": repr(q.get_pattern())}) as sp:
         try:
-            yield
+            fn()
         finally:
-            self.records[label].append(get_usec() - t0)
-
-    def summary(self) -> dict[str, dict]:
-        out = {}
-        for label, xs in self.records.items():
-            out[label] = {"count": len(xs), "total_us": sum(xs),
-                          "max_us": max(xs)}
-        return out
+            sp.attrs.update(rows_in=rows_in, rows_out=q.result.nrows)

@@ -31,6 +31,7 @@ from wukong_tpu.obs import (
 from wukong_tpu.obs.device import note_feedback
 from wukong_tpu.obs.reuse import maybe_observe_reuse
 from wukong_tpu.obs.slo import get_overload, get_slo, tenant_label
+from wukong_tpu.obs.trace import span
 from wukong_tpu.runtime.admission import maybe_admission
 from wukong_tpu.planner.heuristic import heuristic_plan
 from wukong_tpu.planner.plan_file import set_plan
@@ -333,18 +334,7 @@ class Proxy:
         adm_d = None
 
         def prepare():
-            if trace is None:
-                qq = self._parse_text(text)
-                self._plan_prepared(qq, blind, plan_text, tenant=ten)
-                if adm_d is not None:
-                    adm_d.apply(qq)
-                return qq
-            with trace.span("proxy.parse"):
-                qq = self._parse_text(text)
-            qq.trace = trace
-            qq.qid = trace.qid
-            with trace.span("proxy.plan"):
-                self._plan_prepared(qq, blind, plan_text, tenant=ten)
+            qq = self._prepare(text, trace, blind, plan_text, ten)
             if adm_d is not None:
                 adm_d.apply(qq)
             return qq
@@ -409,16 +399,31 @@ class Proxy:
             self.print_result(q, min(print_results, q.result.nrows))
         return q
 
+    def _prepare(self, text: str, trace, blind, plan_text,
+                 ten: str) -> SPARQLQuery:
+        """Parse and plan one text under the ``proxy.parse`` and
+        ``proxy.plan`` spans; the query carries the trace from here."""
+        with span(trace, "proxy.parse"):
+            qq = self._parse_text(text)
+        if trace is not None:
+            qq.trace = trace
+            qq.qid = trace.qid
+        with span(trace, "proxy.plan"):
+            self._plan_prepared(qq, blind, plan_text, tenant=ten)
+        return qq
+
     def _run_repeats(self, prepare, repeats: int, device, trace):
         """The repeat/fallback execution loop (shape + capacity
-        degradation); returns (last query, total execution usec)."""
+        degradation); returns (last query, total execution usec). Each
+        execution, the fallbacks' too, is one ``proxy.execute`` span."""
         q = None
         total_us = 0
         for i in range(repeats):
             q = prepare()
             eng = self._engine_for(q, device)
             t0 = get_usec()
-            self._serve_execute(q, eng, pinned=device is not None)
+            with span(trace, "proxy.execute"):
+                self._serve_execute(q, eng, pinned=device is not None)
             total_us += get_usec() - t0
             if (q.result.status_code == ErrorCode.UNSUPPORTED_SHAPE
                     and eng is self.dist):
@@ -437,7 +442,8 @@ class Proxy:
                                 to="host")
                 q = prepare()
                 t0 = get_usec()
-                host.execute(q)
+                with span(trace, "proxy.execute"):
+                    host.execute(q)
                 total_us += get_usec() - t0
             elif (q.result.status_code == ErrorCode.CAPACITY_EXCEEDED
                   and eng is self.tpu and self.cpu is not None):
@@ -453,7 +459,8 @@ class Proxy:
                                 to="cpu")
                 q = prepare()
                 t0 = get_usec()
-                self.cpu.execute(q)
+                with span(trace, "proxy.execute"):
+                    self.cpu.execute(q)
                 total_us += get_usec() - t0
             if q.result.status_code in (ErrorCode.QUERY_TIMEOUT,
                                         ErrorCode.BUDGET_EXCEEDED):
@@ -1151,11 +1158,7 @@ class Proxy:
         adm_d = None
 
         def prepare():
-            qq = self._parse_text(text)
-            if trace is not None:
-                qq.trace = trace
-                qq.qid = trace.qid
-            self._plan_prepared(qq, blind, None, tenant=ten)
+            qq = self._prepare(text, trace, blind, None, ten)
             if adm_d is not None:
                 adm_d.apply(qq)
             return qq
@@ -1176,7 +1179,13 @@ class Proxy:
             raise
         status = q.result.status_code
         self._m_queries.labels(status=status.name, tenant=ten).inc()
+        # the reply-side accounting that does not read the trace runs while
+        # the trace is open, so its span closes before the recorder has it
+        with span(trace, "proxy.reply"):
+            self._note_admission_reply(ten, q)
+            self._observe_reuse(q, ten, text)
         if trace is not None:
+            # finishes the trace: nothing writes to it from here on
             self.recorder.on_complete(trace, status)
             self._attribute(trace, q, text)
         # SLO accounting after the trace is finished/recorded (burn
@@ -1184,8 +1193,6 @@ class Proxy:
         self._observe_slo(ten, get_usec() - t0_us,
                           ok=status == ErrorCode.SUCCESS, status=status,
                           trace=trace)
-        self._note_admission_reply(ten, q)
-        self._observe_reuse(q, ten, text)
         return q
 
     def _serve_fast_hit(self, text: str, blind, tenant: str):
