@@ -214,6 +214,21 @@ def test_template_execute_attributes(proxy, monkeypatch):
     assert sp.attrs["rows"] == q.result.nrows
 
 
+def test_template_execute_says_which_lookups_its_program_took(
+        proxy, monkeypatch):
+    """The lookup's form is chosen at trace time from static shapes, so it
+    is a property of the compiled program: the span of a reply names it.
+    Q_CHAIN is two expands, each one key lookup; at LUBM-1 the frontier
+    (2^13 rows and more) outweighs either segment's keys."""
+    q = _serve_traced(proxy, monkeypatch, "template")
+    (sp,) = _by_name(q.trace)["template.execute"]
+    assert (sp.attrs["direct_lookups"], sp.attrs["search_lookups"]) == (2, 0)
+    # served again from the cached program: the same program, the same count
+    q2 = _serve_traced(proxy, monkeypatch, "template")
+    (sp2,) = _by_name(q2.trace)["template.execute"]
+    assert (sp2.attrs["direct_lookups"], sp2.attrs["search_lookups"]) == (2, 0)
+
+
 @pytest.mark.parametrize("route", ROUTES)
 def test_spans_enter_wk_annotations(proxy, monkeypatch, route):
     """Each span of a live trace also enters ``TraceAnnotation("wk:" +

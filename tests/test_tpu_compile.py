@@ -149,5 +149,7 @@ def test_template_program_compiles(one_chip):
     caps = (_next_pow2(n_start),) * 3  # both hops have out-degree 1
     spec = (("index", P["advisor"], OUT), ("expand", P["advisor"], OUT, 0),
             ("expand", P["worksFor"], OUT, 1))
-    fn = _build_program(spec, caps, (), (0, 1, 2))
+    # vertex ids are dense from 2^17 up: the student segment ends near
+    # the graph's last id, which 2^25 bounds at LUBM-640
+    fn, _forms = _build_program(spec, caps, (), (1 << 25,) * 2, (0, 1, 2))
     _compile(fn.lower(i(caps[0]), i(), *args), "template two-hop", False)

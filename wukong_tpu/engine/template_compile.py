@@ -11,7 +11,7 @@ leaves the device — this module is the walk engine's equivalent.
 
 Byte identity with the host walk is structural, not tested-in: every
 fused op reproduces the corresponding ``engine/cpu.py`` kernel's row
-order exactly (``expand_padded`` is ``np.repeat`` order over live rows,
+order exactly (``expand_padded_device`` is ``np.repeat`` order over live rows,
 filters only mask, the final host-side validity compaction preserves
 position order), and anything the extractor cannot prove — unions,
 OPTIONAL, FILTER, attrs, predicate variables, TYPE_ID+IN adjacency,
@@ -45,8 +45,9 @@ from wukong_tpu.analysis.lockdep import declare_leaf, make_lock
 from wukong_tpu.config import Global
 from wukong_tpu.join.kernels import (
     DeviceRangeError,
-    expand_padded,
-    lookup_ranges,
+    direct_lookup_wins,
+    expand_padded_device,
+    lookup_ranges_device,
     pad_pow2,
     pair_member,
     to_device_i32,
@@ -293,52 +294,64 @@ def extract_template(q) -> tuple | None:
 # ---------------------------------------------------------------------------
 
 def _build_program(spec: tuple, caps: tuple, depths: tuple,
-                   proj: tuple | None, blind: bool = False):
+                   id_bounds: tuple, proj: tuple | None,
+                   blind: bool = False):
     """jax.jit the whole plan: one traced function from the padded
     start list to the (projected) padded result table. All structure —
-    op kinds, capacity classes, binary-search depths, projection — is
-    static; every value (start list, CSR triplets, member lists, const
-    ids) is a traced argument, so same-shape templates share compiles
-    and consts never mint variants."""
+    op kinds, capacity classes, binary-search depths, each CSR op's id
+    bound (its segment's last key + 1), projection — is static; every
+    value (start list, CSR triplets, member lists, const ids) is a
+    traced argument, so same-shape templates share compiles and consts
+    never mint variants.
+
+    Returns ``(fn, forms)``: ``forms`` lists, once ``fn`` has traced,
+    which form each key lookup of the program took (True the direct
+    table, False the search: ``join/kernels.py:direct_lookup_wins``)."""
     import jax
     import jax.numpy as jnp
 
     n_expand = sum(1 for op in spec if op[0] == "expand")
+    forms: list[bool] = []
 
     def run(*args):
+        del forms[:]
         it = iter(args)
         vals = next(it)
         n0 = next(it)
         valid = jnp.arange(caps[0]) < n0
         cols = [vals]
         totals, ovfs = [], []
-        ci, di = 1, 0
+        ci, di, bi = 1, 0, 0
         for op in spec[1:]:
             kind = op[0]
-            if kind == "expand":
+            if kind != "filter_member":
                 keys, offsets, edges = next(it), next(it), next(it)
-                cur = cols[op[3]]
-                start, deg = lookup_ranges(keys, offsets, cur, xp=jnp)
+                bound = id_bounds[bi]
+                bi += 1
+                forms.append(direct_lookup_wins(
+                    cols[op[3]].shape[0], keys.shape[0], bound))
+            if kind == "expand":
+                start, deg = lookup_ranges_device(keys, offsets,
+                                                  cols[op[3]], bound)
                 deg = jnp.where(valid, deg, 0)
-                rowc, newv, valid, total, ovf = expand_padded(
-                    start, deg, edges, caps[ci], xp=jnp)
+                rowc, newv, valid, total, ovf = expand_padded_device(
+                    start, deg, edges, caps[ci])
                 cols = [c[rowc] for c in cols] + [newv]
                 totals.append(total)
                 ovfs.append(ovf)
                 ci += 1
             elif kind == "filter_pair":
-                keys, offsets, edges = next(it), next(it), next(it)
                 ok = pair_member(keys, offsets, edges, cols[op[3]],
-                                 cols[op[4]], xp=jnp, depth=depths[di])
+                                 cols[op[4]], xp=jnp, depth=depths[di],
+                                 id_bound=bound)
                 di += 1
                 valid = valid & ok
             elif kind == "filter_pair_const":
-                keys, offsets, edges = next(it), next(it), next(it)
                 objc = next(it)
                 anchors = cols[op[3]]
                 ok = pair_member(keys, offsets, edges, anchors,
                                  jnp.broadcast_to(objc, anchors.shape),
-                                 xp=jnp, depth=depths[di])
+                                 xp=jnp, depth=depths[di], id_bound=bound)
                 di += 1
                 valid = valid & ok
             else:  # filter_member
@@ -362,7 +375,7 @@ def _build_program(spec: tuple, caps: tuple, depths: tuple,
         return table, valid, totals_a, ovfs_a, live
 
     assert len(caps) == n_expand + 1
-    return jax.jit(run)
+    return jax.jit(run), forms
 
 
 class _Program:
@@ -370,12 +383,13 @@ class _Program:
     staged device operands (start list, CSR triplets, member lists) —
     steady-state execution is ``fn(*args)`` and one result fetch."""
 
-    __slots__ = ("fn", "args", "caps", "spec", "v2c", "proj", "width",
-                 "nbytes", "label", "blind")
+    __slots__ = ("fn", "forms", "args", "caps", "spec", "v2c", "proj",
+                 "width", "nbytes", "label", "blind")
 
-    def __init__(self, fn, args, caps, spec, v2c, proj, width, nbytes,
-                 label, blind=False):
+    def __init__(self, fn, forms, args, caps, spec, v2c, proj, width,
+                 nbytes, label, blind=False):
         self.fn = fn
+        self.forms = forms  # per key lookup, once traced: direct form?
         self.args = args
         self.caps = caps
         self.spec = spec
@@ -497,6 +511,7 @@ class TemplateCompiledEngine:
         faults.site("template.compile")
         args: list = []
         depths: list[int] = []
+        id_bounds: list[int] = []
         nbytes = 0
         start_op = spec[0]
         vals = self._start_values(start_op)
@@ -509,9 +524,10 @@ class TemplateCompiledEngine:
         for op in spec[1:]:
             kind = op[0]
             if kind in ("expand", "filter_pair", "filter_pair_const"):
-                keys, offsets, edges, depth = self.tables.device_tables(
-                    op[1], op[2])
+                keys, offsets, edges, depth, id_bound = (
+                    self.tables.device_tables(op[1], op[2]))
                 args += [keys, offsets, edges]
+                id_bounds.append(id_bound)
                 if kind != "expand":
                     depths.append(int(depth))
                 if kind == "filter_pair_const":
@@ -530,14 +546,15 @@ class TemplateCompiledEngine:
                 dml = to_device_i32(pml)
                 args += [dml, np.int32(len(ml))]
                 nbytes += int(dml.nbytes)
-        fn = _build_program(spec, caps, tuple(depths), proj, blind)
+        fn, forms = _build_program(spec, caps, tuple(depths),
+                                   tuple(id_bounds), proj, blind)
         if not blind:
             # the result fetch buffer counts toward the residency
             # estimate (blind programs fetch three scalars)
             out_w = width if proj is None else len(proj)
             nbytes += caps[-1] * (out_w + 1) * 4
-        return _Program(fn, args, caps, spec, v2c, proj, width, nbytes,
-                        _label(tsig), blind)
+        return _Program(fn, forms, args, caps, spec, v2c, proj, width,
+                        nbytes, _label(tsig), blind)
 
     def _initial_caps(self, tsig, spec, est_rows: int | None) -> tuple:
         version = self._version()
@@ -586,12 +603,15 @@ class TemplateCompiledEngine:
         latches the per-template demotion and walks. Traced, the whole
         attempt is one ``template.execute`` span; ``template.stage``
         (program lookup, staging on a miss), ``template.dispatch``,
-        ``template.sync`` and ``template.commit`` lie inside it."""
+        ``template.sync`` and ``template.commit`` lie inside it; it ends
+        with how many of the program's key lookups took the direct form
+        and how many the search (``direct_lookups``, ``search_lookups``)."""
         return traced_execute(
             q, "template.execute", lambda: self._try_execute(q),
             lambda: {"label": getattr(q, "_template_label", None),
                      "attempts": getattr(q, "_template_attempts", 0),
-                     "rows": q.result.nrows})
+                     "rows": q.result.nrows,
+                     **getattr(q, "_template_lookups", {})})
 
     def _try_execute(self, q) -> bool:
         ext = extract_template(q)
@@ -626,6 +646,13 @@ class TemplateCompiledEngine:
                     self._commit(q, prog, tbl, val)
                 q._template_compiled = True
                 q._template_label = prog.label
+                if tr is not None:
+                    # which program this reply ran: the form is chosen at
+                    # trace time, so it is a property of the program
+                    direct = sum(prog.forms)
+                    q._template_lookups = {
+                        "direct_lookups": direct,
+                        "search_lookups": len(prog.forms) - direct}
                 _M_EXEC.labels(outcome="compiled").inc()
                 return True
             caps = self._grow_caps(caps, self._last_totals,

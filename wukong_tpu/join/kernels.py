@@ -4,8 +4,14 @@ Every kernel is written against a swappable array module ``xp`` (NumPy by
 default): the control flow is branch-free with statically-bounded loops, so
 the SAME functions trace and JIT-compile under XLA with ``xp=jax.numpy``
 (TrieJax's observation that LFTJ's per-level work is sorted search +
-gather — exactly what an accelerator's vector unit wants). The host path
-runs them as plain NumPy; the device path wraps them in ``jax.jit``.
+gather). The host path runs them as plain NumPy; the device path wraps them
+in ``jax.jit``. That they trace does not make them fast on the chip: a
+sorted search is ``log2(N)`` dependent gather rounds a row, and a gather is
+the slowest thing a TPU does an element (the readings are beside
+``DIRECT_NS``). Where the whole-plan template program searched a dense
+integer domain it addresses the domain instead: :func:`expand_padded_device`
+and :func:`lookup_ranges_device`, with the NumPy forms as their parity
+oracle.
 
 Data model: adjacency is the store's CSR triplet (sorted unique ``keys``,
 ``offsets``, ``edges`` sorted within each key run); candidate sets are
@@ -22,8 +28,11 @@ import numpy as np
 def member_sorted(sorted_arr, vals, xp=np):
     """Boolean mask: is ``vals[i]`` present in ``sorted_arr``?
 
-    One vectorized binary search (searchsorted lowers to XLA's sort-based
-    search under jit) + one gather. Empty set -> all-False.
+    One vectorized binary search + one gather. Empty set -> all-False.
+    Under jit ``searchsorted`` lowers to its default scan: a ``while`` of
+    ``ceil(log2(n + 1))`` rounds, one gather of ``len(vals)`` a round
+    (read in the compiled HLO and the chip's trace, PR 27), not to a
+    sort-based search.
     """
     n = int(sorted_arr.shape[0])
     if n == 0:
@@ -89,7 +98,8 @@ def expand_ragged(start: np.ndarray, deg: np.ndarray):
     return row_idx, start[row_idx] + local
 
 
-def pair_member(keys, offsets, edges, anchors, vals, xp=np, depth=None):
+def pair_member(keys, offsets, edges, anchors, vals, xp=np, depth=None,
+                id_bound=None):
     """Boolean mask: does edge (anchors[i] -> vals[i]) exist in the CSR?
 
     Branchless lower_bound over each row's sorted [start, end) edge range,
@@ -99,12 +109,18 @@ def pair_member(keys, offsets, edges, anchors, vals, xp=np, depth=None):
     iteration count: each row's range is ONE key's edge run, so
     ``log2(max_degree)+1`` converges every row — the device path passes
     the segment's cached degree bound and cuts the dominant per-iteration
-    gather cost by the log(len(edges))/log(max_degree) ratio.
+    gather cost by the log(len(edges))/log(max_degree) ratio. ``id_bound``
+    (device only: the segment's last key + 1, staged with its tables) lets
+    the anchors' key lookup take :func:`lookup_ranges_device`'s direct
+    form where its shape rule says so; without it the lookup searches.
     """
     ne = int(edges.shape[0])
     if ne == 0:
         return xp.zeros(anchors.shape[0], dtype=bool)
-    start, deg = lookup_ranges(keys, offsets, anchors, xp=xp)
+    if id_bound is None or xp is np:
+        start, deg = lookup_ranges(keys, offsets, anchors, xp=xp)
+    else:
+        start, deg = lookup_ranges_device(keys, offsets, anchors, id_bound)
     # int64 search cursors on the host; under an xp=jnp trace the inputs'
     # own dtype rules (int32 by default, int64 under enable_x64) — an
     # unconditional astype would fight the x64-off config every trace
@@ -266,8 +282,9 @@ def seed_masks_host(s, p, o, tp, ts, to, eq) -> np.ndarray:
 # whole-plan compiled-template kernels (engine/template_compile.py)
 # ---------------------------------------------------------------------------
 
-def expand_padded(start, deg, edges, out_cap, xp=np):
-    """Order-preserving ragged expansion to a STATIC output capacity.
+def expand_padded(start, deg, edges, out_cap):
+    """Order-preserving ragged expansion to a STATIC output capacity:
+    the NumPy form, parity oracle of :func:`expand_padded_device`.
 
     The padded twin of :func:`expand_ragged`: rows land in source-row
     order with each row's edges contiguous (np.repeat order), so a
@@ -286,21 +303,138 @@ def expand_padded(start, deg, edges, out_cap, xp=np):
     """
     n = int(start.shape[0])
     ne = int(edges.shape[0])
-    cum = xp.cumsum(deg)
+    cum = np.cumsum(deg)
     total = cum[n - 1]
-    pos = xp.arange(out_cap)
-    row = xp.searchsorted(cum, pos, side="right")
-    rowc = xp.clip(row, 0, n - 1)
-    prev = xp.where(rowc > 0, cum[xp.clip(rowc - 1, 0, n - 1)], 0)
+    pos = np.arange(out_cap)
+    row = np.searchsorted(cum, pos, side="right")
+    rowc = np.clip(row, 0, n - 1)
+    prev = np.where(rowc > 0, cum[np.clip(rowc - 1, 0, n - 1)], 0)
     local = pos - prev
     if ne:
-        values = edges[xp.clip(start[rowc] + local, 0, ne - 1)]
+        values = edges[np.clip(start[rowc] + local, 0, ne - 1)]
     else:
-        values = xp.zeros(out_cap, dtype=start.dtype)
+        values = np.zeros(out_cap, dtype=start.dtype)
     valid = (pos < total) & (total > 0)
-    fsum = xp.sum(deg.astype(np.float32))
+    fsum = np.sum(deg.astype(np.float32))
     overflow = (total > out_cap) | (total < 0) | (fsum > float(out_cap))
     return rowc, values, valid, total, overflow
+
+
+def expand_padded_device(start, deg, edges, out_cap):
+    """:func:`expand_padded` for the chip (``jax.numpy`` only): the same
+    five outputs, every slot of them equal to the NumPy form's, padding
+    included.
+
+    The NumPy form finds each slot's source row by a binary search of
+    the slot in the cumulative degrees: ``log2(n)`` gather rounds over
+    ``out_cap`` slots, 1820 ms a call at (2^21, 2^23) against 169 ms
+    for this form (my chip run, PR 27). Here every row's index is
+    scattered at its exclusive start and a running maximum carries it
+    over the row's slots (the form of ``tpu_kernels.expand``): one
+    scatter of ``n`` and one scan of ``out_cap``. A zero-degree row shares its start with the next
+    positive-degree row, which has the larger index and wins the
+    ``max``; so the starts go in as they are, non-decreasing, and the
+    scatter is told so (left to find out, the chip's compiler sorts
+    the ``n`` indices first). Slots at or past ``total`` take row
+    ``n - 1``, where the search's clip put them. The edge position is
+    one gather of ``start - exclusive start`` a slot, plus the slot:
+    in wrapping int32 that is the oracle's ``start[row] + (slot -
+    cum[row - 1])`` bit for bit.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    n = int(start.shape[0])
+    ne = int(edges.shape[0])
+    cum = jnp.cumsum(deg)
+    total = cum[n - 1]
+    first = cum - deg  # exclusive start: cum[row - 1], 0 for row 0
+    pos = jnp.arange(out_cap, dtype=first.dtype)
+    rows = jnp.arange(n, dtype=jnp.int32)
+    # a sum that wrapped int32 steps down where it did: then every index
+    # is parked out of range (sorted still) and nothing is marked; the
+    # overflow flag is up and the caller discards the table
+    monotone = jnp.all(cum >= first)
+    marks = jnp.zeros(out_cap, dtype=jnp.int32).at[
+        jnp.where(monotone, first, out_cap)].max(
+            rows + 1, mode="drop", indices_are_sorted=True)
+    src = jax.lax.cummax(marks) - 1
+    rowc = jnp.where(pos < total, jnp.maximum(src, 0), n - 1)
+    if ne:
+        values = edges[jnp.clip((start - first)[rowc] + pos, 0, ne - 1)]
+    else:
+        values = jnp.zeros(out_cap, dtype=start.dtype)
+    valid = (pos < total) & (total > 0)
+    fsum = jnp.sum(deg.astype(np.float32))
+    overflow = (total > out_cap) | (total < 0) | (fsum > float(out_cap))
+    return rowc, values, valid, total, overflow
+
+
+#: ns an element on the attached chip (TPU v5 lite; my chip run, PR 27,
+#: ``scripts/bench_direct_lookup.py``, 13,937,249 sorted unique keys in an
+#: id range of 14.07 M), the constants of :func:`direct_lookup_wins`:
+#: ``search_round`` one round of ``searchsorted``'s loop a row (16.3-16.6 at
+#: 2^16-2^21 rows, where the two forms cross; 9.8 at 2^23; 19-48 under 2^14,
+#: where a round's fixed 30 us shows); ``scatter`` a key into the table
+#: (83.1 ms the table, 111.6 without the sorted/unique promise);
+#: ``fill`` a slot of the table set to -1 (0.61 ms). The two forms read
+#: 52.2 against 86.4 ms at 2^17 rows and 103.7 against 89.5 at 2^18, 1968
+#: against 301 at 2^23; the three gathers a row both end in cost 8.7 each.
+DIRECT_NS = {"search_round": 16.4, "scatter": 6.0, "fill": 0.044}
+
+
+def direct_lookup_wins(rows: int, nkeys: int, id_bound: int) -> bool:
+    """THE shape rule of :func:`lookup_ranges_device`, from static shapes
+    alone: the search costs ``rows`` gathers in each of its
+    ``ceil(log2(nkeys + 1))`` rounds; the direct form costs one scatter
+    of ``nkeys`` into a table of ``id_bound`` that it fills first. Both
+    end in the same three gathers a row. A light-sized frontier over a
+    big segment (1024 rows over 13.9 M keys) keeps the search; the
+    heavy classes (2^20 rows and more) take the table."""
+    rounds = int(nkeys).bit_length()
+    search = rows * rounds * DIRECT_NS["search_round"]
+    direct = nkeys * DIRECT_NS["scatter"] + id_bound * DIRECT_NS["fill"]
+    return direct < search
+
+
+def lookup_ranges_device(keys, offsets, vids, id_bound: int):
+    """:func:`lookup_ranges` for the chip (``jax.numpy`` only): the same
+    (start, degree) a row, by the form :func:`direct_lookup_wins` picks
+    at trace time.
+
+    Direct form: vertex ids are a dense int32 domain, and ``id_bound``
+    (the segment's last key + 1, static, staged with its tables) bounds
+    it. ``arange(S)`` is scattered at ``keys`` (sorted, unique) into a
+    table of ``id_bound`` slots initialised to -1; a row's slot is one
+    gather, an id outside ``[0, id_bound)`` or with slot -1 is absent.
+    The table is a temporary of the program, never a staged operand,
+    and is not built before the rows it serves exist: tied to nothing
+    but ``keys``, the compiler built every table of q7's program (five,
+    85 MB each at LUBM-640) at the program's start, and its temporaries
+    read 650.6 MiB for the 378.9 they take in program order (compiled
+    for a described v5e, PR 27; the search's program took 418.3).
+    """
+    import jax
+    import jax.numpy as jnp
+
+    nkeys = int(keys.shape[0])
+    rows = int(vids.shape[0])
+    id_bound = int(id_bound)
+    if nkeys == 0:
+        z = jnp.zeros(rows, dtype=offsets.dtype)
+        return z, z
+    if not direct_lookup_wins(rows, nkeys, id_bound):
+        return lookup_ranges(keys, offsets, vids, xp=jnp)
+    keys, vids = jax.lax.optimization_barrier((keys, vids))
+    table = jnp.full(id_bound, -1, dtype=jnp.int32).at[keys].set(
+        jnp.arange(nkeys, dtype=jnp.int32), mode="drop",
+        indices_are_sorted=True, unique_indices=True)
+    slot = table[jnp.clip(vids, 0, id_bound - 1)]
+    found = (vids >= 0) & (vids < id_bound) & (slot >= 0)
+    slot = jnp.maximum(slot, 0)
+    start = jnp.where(found, offsets[slot], 0)
+    deg = jnp.where(found, offsets[slot + 1] - offsets[slot], 0)
+    return start, deg
 
 
 def unique_rows_padded(ca, cb, valid, xp=np):

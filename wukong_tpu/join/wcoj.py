@@ -218,12 +218,15 @@ class JoinTableCache:
 
     def device_tables(self, pid: int, d: int):
         """The (pid, dir) adjacency as device-resident int32 arrays
-        (keys, offsets, edges, depth) for the XLA level probe — built from
+        (keys, offsets, edges, depth, id_bound) for the XLA level probe
+        and the whole-plan template programs — built from
         the verified-sorted host segment and cached per store version like
         every other entry, so mutations self-invalidate and steady-state
         device levels never re-ship tables. ``depth`` is the segment's
         binary-search iteration bound (log2(max_degree)+1 — a probe range
-        is one key's edge run, never the whole edge array). Raises
+        is one key's edge run, never the whole edge array); ``id_bound``
+        is the last key + 1 (0 for an empty segment), the static size of
+        the table ``kernels.lookup_ranges_device`` addresses. Raises
         :class:`DeviceRangeError` (caller degrades to host) when any
         value exceeds int32 under the default x64-off JAX config."""
         key = (self._version(), "dseg", int(pid), int(d))
@@ -238,7 +241,8 @@ class JoinTableCache:
         return self._put(key, (to_device_i32(seg.keys),
                                to_device_i32(seg.offsets),
                                to_device_i32(seg.edges),
-                               max(max_deg, 1).bit_length() + 1))
+                               max(max_deg, 1).bit_length() + 1,
+                               int(seg.keys[-1]) + 1 if len(seg.keys) else 0))
 
     def clear(self) -> None:
         with self._lock:
@@ -587,7 +591,7 @@ class WCOJExecutor:
                     glob_dev if use_glob else dummy]
             depths = []
             for j in adj_ids:
-                keys, offsets, edges, depth = dev[j]
+                keys, offsets, edges, depth, _id_bound = dev[j]
                 avals = prefix[row_idx[lo:hi], adj[j][0]]
                 if len(avals):
                     # anchors come from the PREFIX, which host-route
