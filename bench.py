@@ -103,8 +103,6 @@ DATASET_NOTES = {
     "lubm": "synthetic-lubm (loader/lubm.py), not UBA-generated; result "
             "counts approximate vs the reference's published tables "
             "(q2@2560: 2,781,086 rows here vs 2,765,067 published)",
-    "watdiv": "synthetic watdiv-shaped data (loader/watdiv.py), not the "
-              "WatDiv generator's",
     "dbpedia": "synthetic dbpedia-shaped data (loader/generic_rdf.py); "
                "dbpsb template shapes, not DBpedia data",
 }
@@ -1650,7 +1648,9 @@ def cyclic_main(device_ok: bool) -> None:
                                     CPUEngine, WCOJExecutor, reps)
     # WatDiv-based cyclic set (social triangles/pentagon over the shaped
     # e-commerce world)
-    wscale = int(os.environ.get("WUKONG_CYCLIC_WATDIV_SCALE", "60"))
+    # WatDiv's scale factor (1000 users a unit; the sketch before PR 28
+    # counted 100 users a unit, so its 60 is this 6)
+    wscale = int(os.environ.get("WUKONG_CYCLIC_WATDIV_SCALE", "6"))
     wtriples, _lay = generate_watdiv(wscale, seed=0)
     wg = build_partition(wtriples, 0, 1)
     wstats = Stats.generate(wtriples)
@@ -1920,119 +1920,6 @@ def _est_ratio(planner, q) -> float | None:
     if not ests:
         return None
     return round(max(ests) / max(ests[-1], 1.0), 1)
-
-
-def watdiv_main(device_ok: bool) -> None:
-    """`bench.py --watdiv`: S1-S7/F1-F5 star/snowflake templates, batched
-    (BASELINE.json configs[3] — no published reference number for this
-    hardware, so vs_baseline is null)."""
-    from wukong_tpu.engine.cpu import CPUEngine
-    from wukong_tpu.engine.tpu import TPUEngine
-    from wukong_tpu.loader.watdiv import TEMPLATES, VirtualWatdivStrings, generate_watdiv
-    from wukong_tpu.runtime.proxy import Proxy
-    from wukong_tpu.sparql.parser import Parser
-    from wukong_tpu.planner.heuristic import heuristic_plan
-    from wukong_tpu.store.persist import load_gstore, save_gstore
-    from wukong_tpu.store.gstore import build_partition
-
-    scale = int(os.environ.get("WUKONG_WATDIV_SCALE", "0"))
-    if scale == 0:
-        scale = 28000 if os.path.exists(
-            os.path.join(CACHE, "watdiv28000_p0.npz")) else 2000
-    if not device_ok and scale > 2000 \
-            and os.environ.get("WUKONG_EMU_FORCE") != "1":
-        # same contract as the emu clamp: explicit force runs the cached
-        # at-scale world on the CPU backend (honest backend label)
-        scale = 2000
-    os.makedirs(CACHE, exist_ok=True)
-    store_path = os.path.join(CACHE, f"watdiv{scale}_p0.npz")
-    ss = VirtualWatdivStrings(scale, seed=0)
-    t0 = time.time()
-    from wukong_tpu.utils.errors import WukongError
-
-    g = None
-    if os.path.exists(store_path):
-        try:
-            g = load_gstore(store_path)
-        except WukongError as e:  # corrupt/stale cache: rebuild, don't die
-            print(f"# store cache invalid ({e}); rebuilding", file=sys.stderr)
-            os.remove(store_path)
-    if g is None:
-        triples, _ = generate_watdiv(scale, seed=0)
-        g = build_partition(triples, 0, 1)
-        del triples
-        try:
-            save_gstore(g, store_path)
-        except Exception as e:
-            print(f"# store cache save failed: {e}", file=sys.stderr)
-    print(f"# watdiv-{scale} ready in {time.time() - t0:.0f}s "
-          f"({g.stats_str()})", file=sys.stderr)
-
-    eng = TPUEngine(g, ss)
-    proxy = Proxy(g, ss, CPUEngine(g, ss), eng)
-    rng = np.random.default_rng(0)
-    lat_us = []
-    details = {}
-    failed = []
-    for name in sorted(TEMPLATES):
-        try:
-            tmpl = Parser(ss).parse_template(TEMPLATES[name])
-            proxy.fill_template(tmpl)
-            cand = tmpl.candidates[0]
-            bw = BATCH  # per-template: star templates at WatDiv-28000 can
-            # exceed the capacity ceiling at B=1024 — halve and restart,
-            # like the LUBM heavies' OOM backoff
-            best, q_best, rows_best = None, None, 0
-            trial = 0
-            while trial < 3:
-                consts = np.asarray(
-                    cand[rng.integers(0, len(cand), bw)], dtype=np.int64)
-                q = tmpl.instantiate(rng)
-                heuristic_plan(q)
-                q.result.blind = True
-                t = time.perf_counter()
-                try:
-                    counts = eng.execute_batch(q, consts)
-                except Exception as e:
-                    s = str(e)
-                    if bw > 1 and ("exceeds capacity" in s  # merge path
-                                   or "table_capacity_max" in s  # v1 chain
-                                   or "RESOURCE_EXHAUSTED" in s):  # HBM OOM
-                        bw = max(bw // 2, 1)
-                        best, q_best, trial = None, None, 0
-                        continue
-                    raise
-                dt = (time.perf_counter() - t) * 1e6 / bw
-                if best is None or dt < best:
-                    # us, rows, and roofline must all describe the SAME
-                    # instantiation (rev-list sizes, learned caps, and
-                    # result counts differ per instance)
-                    best, q_best, rows_best = dt, q, int(counts[0])
-                trial += 1
-            lat_us.append(best)
-            details[name] = {"us": round(best, 1), "rows": rows_best,
-                             "batch": bw}
-            _attach_roofline(details[name], eng, q_best, bw, "const",
-                             "tpu" if device_ok else "cpu")
-            print(f"# {name}: {best:,.0f} us (batch={bw})", file=sys.stderr)
-        except Exception as e:
-            failed.append(name)
-            details[name] = {"error": str(e)[:200]}
-            print(f"# {name}: FAILED ({e})", file=sys.stderr)
-    if not lat_us:
-        raise SystemExit("all watdiv templates failed")
-    backend = "TPU single chip" if device_ok else "cpu-fallback"
-    _emit_final({
-        "metric": f"WatDiv-{scale} S/F templates geomean latency, {backend},"
-                  f" blind, batch={_batch_label(details)}"
-                  + (f"; FAILED: {','.join(failed)}" if failed else ""),
-        "value": round(_geomean(lat_us), 1),
-        "unit": "us",
-        "vs_baseline": None,
-        "backend": "tpu" if device_ok else "cpu",
-        "dataset": DATASET_NOTES["watdiv"],
-        "detail": details,
-    }, "BENCH_WATDIV_DETAIL.json")
 
 
 def _batch_label(details: dict) -> str:
@@ -3369,7 +3256,7 @@ def main():
              "--emu": emu_main, "--cyclic": cyclic_main,
              "--devicecost": devicecost_main, "--tenants": tenants_main,
              "--hotspot": hotspot_main, "--rebalance": rebalance_main,
-             "--readmostly": readmostly_main, "--watdiv": watdiv_main,
+             "--readmostly": readmostly_main,
              "--dbpedia": dbpedia_main, "--yago": yago_main}
     for flag, mode_main in modes.items():
         if flag in sys.argv:

@@ -1,0 +1,7 @@
+"""Median reply time of WatDiv's snowflake templates (F1-...) in the
+``watdiv_basic`` queue (ms), send to the reply's table on the host."""
+from benchmark.stats import percentile
+
+
+def read(run):
+    return percentile(run.log.latencies_ms(kind="F"), 50)

@@ -5,6 +5,7 @@ populations take vectorized paths. These tests pin the vectorized
 signature grouping against an independent brute-force implementation."""
 
 import numpy as np
+import pytest
 
 from wukong_tpu.planner.stats import Stats
 from wukong_tpu.types import NORMAL_ID_START, TYPE_ID
@@ -88,3 +89,45 @@ def test_single_typed_fast_path_counts():
     assert len(neg) == 1
     typed_n = len(np.unique(s[p == TYPE_ID]))
     assert len(st.vtype_ids) == typed_n + st.tyscount[neg[0]]
+
+
+def _worlds():
+    from wukong_tpu.loader.lubm import generate_lubm
+    from wukong_tpu.loader.watdiv import generate_watdiv
+
+    return {"lubm1": lambda: generate_lubm(1, seed=0)[0],
+            "watdiv1": lambda: generate_watdiv(1, seed=0)[0],
+            "big_untyped": _world_with_big_untyped}
+
+
+def _fields(st: Stats) -> dict:
+    return {"vtype_ids": st.vtype_ids.tolist(), "vtype": st.vtype.tolist(),
+            "tyscount": st.tyscount, "complex_members": st.complex_members,
+            "pred_edges": st.pred_edges, "pstype": st.pstype,
+            "potype": st.potype, "distinct_subj": st.distinct_subj,
+            "distinct_obj": st.distinct_obj, "fine_type": st.fine_type}
+
+
+@pytest.mark.parametrize("world", sorted(_worlds()))
+def test_tables_by_id_and_sorting_give_the_same_statistics(world,
+                                                           monkeypatch):
+    """``Stats.generate`` has two ways to the same numbers: tables indexed
+    by id where ids are dense (``DENSE_ROOM``), sorting where they are not.
+    Every field, to the count, on a typed graph (LUBM), a mostly untyped
+    one whose classes are themselves typed (WatDiv) and the large untyped
+    population of the signature path."""
+    from wukong_tpu.planner import stats
+
+    triples = _worlds()[world]()
+    monkeypatch.setattr(stats, "DENSE_ROOM", float("inf"))
+    by_id = _fields(Stats.generate(triples))
+    monkeypatch.setattr(stats, "DENSE_ROOM", 0.0)
+    sorting = _fields(Stats.generate(triples))
+    for name in by_id:
+        assert by_id[name] == sorting[name], name
+    # the rule itself: these generators' ids are dense, a handful of
+    # triples over the same id range is not
+    monkeypatch.undo()
+    room = stats.DENSE_ROOM * triples.nbytes
+    assert 8 * (int(triples.max()) + 1) <= room
+    assert not 8 * (int(triples.max()) + 1) <= stats.DENSE_ROOM * triples[:100].nbytes

@@ -76,7 +76,6 @@ def _clean(monkeypatch):
     monkeypatch.setattr(Global, "template_min_rows", 4096)
     monkeypatch.setattr(Global, "template_capacity_retries", 3)
     monkeypatch.setattr(Global, "template_budget_mb", 256)
-    monkeypatch.setattr(Global, "template_demote_eff", 0.02)
     monkeypatch.setattr(Global, "join_strategy", "auto")
     monkeypatch.setattr(Global, "join_device_min_candidates", 65536)
     yield
@@ -349,25 +348,6 @@ def test_demotion_latch_and_store_version_rearm():
     assert demotion_report() == {}
 
 
-def test_low_efficiency_feedback_latches_host(monkeypatch):
-    """Measured demotion: a template site whose warm padding efficiency
-    collapsed (read ONLY through ``read_device_input``) latches host
-    after enough dispatches."""
-    from wukong_tpu.obs.device import get_device_obs, maybe_device_dispatch
-
-    monkeypatch.setattr(Global, "enable_device_obs", True)
-    get_device_obs().reset()
-    Global.template_device = "auto"
-    Global.template_min_rows = 1
-    Global.template_demote_eff = 0.5
-    sig = ("t", 3)
-    for _ in range(8):
-        maybe_device_dispatch("template.plan", template="tx", live=1,
-                              capacity=4096, wall_us=10, nbytes=0)
-    assert choose_template_route(sig, 10 ** 6, version=0) == "latched_host"
-    assert "low_efficiency" in demotion_report().values()
-
-
 # ---------------------------------------------------------------------------
 # serve-path: chaos degrade, invalidation, feedback, EXPLAIN (lockdep)
 # ---------------------------------------------------------------------------
@@ -503,6 +483,8 @@ def test_small_measured_feedback_demotes_auto_route(tri_proxy,
     assert q.template_route == "device"
     assert q._template_compiled
     assert "small_measured" in demotion_report().values()
+    # the demoted template's program goes with the latch
+    assert proxy.template_engine().program_count() == 0
     q2 = proxy.run_single_query(text, blind=False)
     assert q2.template_route == "latched_host"
 
@@ -518,6 +500,8 @@ def test_explain_renders_template_compiled_route(tri_proxy, monkeypatch):
     proxy, text = tri_proxy
     Global.join_strategy = "walk"
     Global.template_device = "device"
+    proxy.serve_query(text)  # settles the capacity classes (a retry is a
+    get_device_obs().reset()  # dispatch of its own)
     rep = proxy.explain_query(text, analyze=True)
     assert rep["route"] == "template-compiled"
     assert "route: template-compiled" in rep["rendered"]

@@ -517,10 +517,10 @@ class GlobalConfig:
     # template_compile.py): host (the NumPy walk engine), device (force
     # the fused XLA program on every eligible template), auto (route
     # device when the planner's estimated peak rows reach
-    # template_min_rows, with measured-feedback demotion reading only
-    # DEVICE_INPUTS). Any compile or mid-flight dispatch failure
-    # degrades the query to the host walk byte-identically and latches
-    # a per-template demotion.
+    # template_min_rows, demoted when a served reply's live rows do
+    # not). Any compile or mid-flight dispatch failure degrades the query
+    # to the host walk byte-identically and latches a per-template
+    # demotion.
     template_device: str = "auto"
     # dispatch-amortization floor: under `auto`, a template routes to
     # the compiled program only when the planner's estimated peak
@@ -536,10 +536,6 @@ class GlobalConfig:
     # staged CSR operand estimates; cold programs past it are
     # LRU-evicted (charged on the residency ledger, kind "template")
     template_budget_mb: int = 256
-    # measured-feedback demotion floor: a template whose observed
-    # padding efficiency (live rows / padded capacity, read from
-    # DEVICE_INPUTS) sits below this after warmup is demoted to host
-    template_demote_eff: float = 0.02
     # distributed generic join: max slice-range parts a cyclic query over
     # a sharded store fans out to on the heavy lane (hash-partitioning
     # the first eliminated variable); bounded by the shard count and the

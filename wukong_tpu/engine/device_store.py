@@ -16,6 +16,7 @@ from dataclasses import dataclass
 import numpy as np
 
 from wukong_tpu.obs.device import maybe_device_resident
+from wukong_tpu.obs.trace import trace_event
 from wukong_tpu.types import IN, OUT, PREDICATE_ID, TYPE_ID
 
 INT32_MAX = np.iinfo(np.int32).max
@@ -323,13 +324,19 @@ class DeviceStore:
             self._touch(key)
             return self._index_cache[key]
         arr = np.asarray(self.g.get_index(tpid, d), dtype=np.int32)
-        return self._stage_list(key, arr)
+        room = 0
+        if int(d) == IN and int(tpid) in self.g.type_ids:
+            # padded as its heaviest peer's list is: the length a kernel is
+            # compiled for is then not the drawn type's own
+            room = len(self.g.get_index(
+                self.g.heaviest_peer_type(tpid), d))
+        return self._stage_list(key, arr, room)
 
-    def _stage_list(self, key, arr: np.ndarray):
+    def _stage_list(self, key, arr: np.ndarray, room: int = 0):
         """Pad + device_put a host list and account it in the LRU/budget."""
         import jax.numpy as jnp
 
-        pad = _next_pow2(len(arr))
+        pad = _next_pow2(max(len(arr), room))
         padded = np.full(pad, INT32_MAX, dtype=np.int32)
         padded[: len(arr)] = arr
         dev = jnp.asarray(padded)
@@ -338,6 +345,7 @@ class DeviceStore:
         self._lru.append(key)
         self.bytes_used += dev.size * 4
         maybe_device_resident("fill", "index", dev.size * 4)
+        trace_event("device.stage", segment=str(key), bytes=dev.size * 4)
         self._enforce_budget()
         return entry
 
@@ -558,6 +566,8 @@ class DeviceStore:
         self._lru.append(key)
         self.bytes_used += seg.nbytes
         maybe_device_resident("fill", "segment", seg.nbytes)
+        # traced, inside a request: a segment put on the device for it
+        trace_event("device.stage", segment=str(key), bytes=seg.nbytes)
         self._enforce_budget()
 
     def _enforce_budget(self) -> None:

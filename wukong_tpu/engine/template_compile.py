@@ -27,9 +27,11 @@ compile ledger's variant-storm sentinel sees whole-plan variants too.
 
 Routing follows the JOIN_ROUTES/CONSUMED_INPUTS pattern: a
 ``template_device`` knob + the :data:`TEMPLATE_ROUTES` literal registry,
-with measured-feedback demotion whose every signal read is a
-``read_device_input()`` call against a declared ``DEVICE_INPUTS``
-member. A losing or failing compile degrades to the host walk
+with demotion on evidence a reply carries (its live rows, a failure);
+a measured signal the chooser reads has to come through
+``read_device_input()`` against a declared ``DEVICE_INPUTS`` member (it
+reads none: a site-wide figure would judge one template by what the
+others ran). A losing or failing compile degrades to the host walk
 byte-identically and latches a per-template demotion (re-armed by a
 store mutation), visible in ``/device`` and EXPLAIN.
 """
@@ -58,7 +60,6 @@ from wukong_tpu.obs.device import (
     maybe_device_resident,
     note_compile_cache,
     note_feedback,
-    read_device_input,
 )
 from wukong_tpu.obs.metrics import get_registry
 from wukong_tpu.obs.trace import span, traced_execute
@@ -160,7 +161,7 @@ def reset_demotions() -> None:
 
 
 # ---------------------------------------------------------------------------
-# route chooser — reads ONLY declared DEVICE_INPUTS
+# route chooser
 # ---------------------------------------------------------------------------
 
 def _route_knobs() -> tuple:
@@ -176,11 +177,10 @@ def choose_template_route(tsig, est_rows: int | None = None,
                           version: int | None = None) -> str:
     """Plan-time route for one template. The knob forces host/device;
     under ``auto`` the planner's estimated peak rows must amortize the
-    dispatch (``template_min_rows``) and the measured feedback may
-    demote: every measured signal is read through
-    :func:`read_device_input` against a declared ``DEVICE_INPUTS``
-    member — the gate-held contract that the actuator consumes nothing
-    the observatory does not publish."""
+    dispatch (``template_min_rows``); a served reply whose live rows do
+    not, or a failure, latches the demotion that is read here. A measured
+    signal would come through :func:`read_device_input` against a
+    declared ``DEVICE_INPUTS`` member (the gate-held contract)."""
     knob = str(Global.template_device).strip().lower()
     if knob == "host":
         return "host"
@@ -192,15 +192,6 @@ def choose_template_route(tsig, est_rows: int | None = None,
         return "host"
     if est_rows is None or est_rows < max(int(Global.template_min_rows), 1):
         return "host"
-    # measured feedback: a template site whose warm padding efficiency
-    # collapsed is burning capacity on padding — latch host until the
-    # next store mutation re-arms the estimate-driven decision
-    eff = read_device_input("padding_efficiency", SITE)
-    if eff is not None and eff < max(float(Global.template_demote_eff), 0.0):
-        counts = read_device_input("dispatches", SITE) or {}
-        if int(counts.get("count", 0)) >= 8:
-            latch_demotion(tsig, "low_efficiency", version)
-            return "latched_host"
     return "device"
 
 
@@ -379,9 +370,13 @@ def _build_program(spec: tuple, caps: tuple, depths: tuple,
 
 
 class _Program:
-    """One cached compiled template: the jitted fn plus its fully
-    staged device operands (start list, CSR triplets, member lists) —
-    steady-state execution is ``fn(*args)`` and one result fetch."""
+    """One cached compiled template: the jitted fn plus the device
+    operands every draw of the template shares (an index start list, the
+    CSR triplets). What a draw's own constants decide (a constant's start
+    list, a constant object, a constant's member list) is ``None`` in
+    ``args`` and bound query by query (``_bind``): the program is cached
+    under the template's signature, which leaves vertex constants out.
+    Steady-state execution is ``fn(*bound args)`` and one result fetch."""
 
     __slots__ = ("fn", "forms", "args", "caps", "spec", "v2c", "proj",
                  "width", "nbytes", "label", "blind")
@@ -390,7 +385,7 @@ class _Program:
                  nbytes, label, blind=False):
         self.fn = fn
         self.forms = forms  # per key lookup, once traced: direct form?
-        self.args = args
+        self.args = args  # None where a draw's constants decide
         self.caps = caps
         self.spec = spec
         self.v2c = v2c
@@ -484,6 +479,16 @@ class TemplateCompiledEngine:
         with self._lock:
             return len(self._programs)
 
+    def drop_template(self, tsig) -> None:
+        """A demoted template's programs go, and their program text on the
+        device with them (some MiB each): nothing runs them again before a
+        store mutation re-arms the template, and that makes them stale."""
+        with self._lock:
+            gone = [self._programs.pop(k)
+                    for k in [k for k in self._programs if k[0] == tsig]]
+        for prog in gone:
+            maybe_device_resident("evict", "template", prog.nbytes)
+
     def clear(self) -> None:
         with self._lock:
             dropped = sum(p.nbytes for p in self._programs.values())
@@ -513,14 +518,11 @@ class TemplateCompiledEngine:
         depths: list[int] = []
         id_bounds: list[int] = []
         nbytes = 0
-        start_op = spec[0]
-        vals = self._start_values(start_op)
-        n0 = len(vals)
-        padded = np.zeros(caps[0], dtype=np.int64)
-        padded[:n0] = vals
-        dv = to_device_i32(padded)
-        args += [dv, np.int32(n0)]
-        nbytes += int(dv.nbytes)
+        if spec[0][0] == "index":  # the same list for every draw
+            args += self._start_args(spec[0], caps[0])
+        else:
+            args += [None, None]
+        nbytes += caps[0] * 4
         for op in spec[1:]:
             kind = op[0]
             if kind in ("expand", "filter_pair", "filter_pair_const"):
@@ -531,21 +533,9 @@ class TemplateCompiledEngine:
                 if kind != "expand":
                     depths.append(int(depth))
                 if kind == "filter_pair_const":
-                    if not (0 <= op[4] < (1 << 31)):
-                        raise DeviceRangeError(
-                            f"const object {op[4]} exceeds int32")
-                    args.append(np.int32(op[4]))
+                    args.append(None)
             else:  # filter_member
-                ml = np.asarray(self.g.get_triples(op[1], op[2], op[3]),
-                                dtype=np.int64)
-                if len(ml) > 1 and not bool((ml[1:] >= ml[:-1]).all()):
-                    ml = np.sort(ml)
-                pml = np.full(pad_pow2(len(ml)), _PAD_SENTINEL,
-                              dtype=np.int64)
-                pml[:len(ml)] = ml
-                dml = to_device_i32(pml)
-                args += [dml, np.int32(len(ml))]
-                nbytes += int(dml.nbytes)
+                args += [None, None]
         fn, forms = _build_program(spec, caps, tuple(depths),
                                    tuple(id_bounds), proj, blind)
         if not blind:
@@ -556,21 +546,80 @@ class TemplateCompiledEngine:
         return _Program(fn, forms, args, caps, spec, v2c, proj, width,
                         nbytes, _label(tsig), blind)
 
-    def _initial_caps(self, tsig, spec, est_rows: int | None) -> tuple:
+    def _start_args(self, op, cap: int) -> list:
+        vals = self._start_values(op)
+        padded = np.zeros(cap, dtype=np.int64)
+        padded[:len(vals)] = vals
+        return [to_device_i32(padded), np.int32(len(vals))]
+
+    def _bind(self, prog: _Program, spec: tuple) -> list:
+        """``prog.args`` with this query's constants in their slots: the
+        constant's start list, constant objects, a constant's member list
+        (padded to a class of its own, so one constant's list length mints
+        no program)."""
+        args = list(prog.args)
+        if args[0] is None:
+            args[0:2] = self._start_args(spec[0], prog.caps[0])
+        at = 2
+        for op in spec[1:]:
+            if op[0] == "filter_member":
+                ml = np.asarray(self.g.get_triples(op[1], op[2], op[3]),
+                                dtype=np.int64)
+                if len(ml) > 1 and not bool((ml[1:] >= ml[:-1]).all()):
+                    ml = np.sort(ml)
+                pml = np.full(pad_pow2(len(ml), floor=max(
+                    int(Global.table_capacity_min), 1)), _PAD_SENTINEL,
+                    dtype=np.int64)
+                pml[:len(ml)] = ml
+                args[at:at + 2] = [to_device_i32(pml), np.int32(len(ml))]
+                at += 2
+                continue
+            at += 3
+            if op[0] == "filter_pair_const":
+                if not (0 <= op[4] < (1 << 31)):
+                    raise DeviceRangeError(
+                        f"const object {op[4]} exceeds int32")
+                args[at] = np.int32(op[4])
+                at += 1
+        return args
+
+    def _start_len(self, spec) -> int:
+        """Rows the start list may hold: an index list's length, or for a
+        constant the longest list any constant of its segment has, so that
+        a template's classes do not follow the constant drawn."""
+        op = spec[0]
+        n0 = len(self._start_values(op))
+        if op[0] == "const_list":
+            n0 = max(n0, self.g.max_degree(op[2], op[3]))
+        return n0
+
+    def _initial_caps(self, tsig, spec, est_rows: int | None,
+                      est_steps: list | None = None) -> tuple:
+        """The classes a first attempt runs at: where the planner walked
+        the chain, each expansion's own estimate with one class of room (a
+        start from a constant scaled to the heaviest constant, as its start
+        list is); else four times the step before, at least the peak."""
         version = self._version()
         with self._lock:
             good = self._good_caps.get((tsig, version))
-        if good is not None:
-            return good
-        n0 = len(self._start_values(spec[0]))
+        n0 = self._start_len(spec)
         floor = max(int(Global.table_capacity_min), 1)
+        if good is not None:
+            if good[0] >= n0:
+                return good
+            return (pad_pow2(n0, floor=floor),) + tuple(good[1:])
         caps = [pad_pow2(n0, floor=floor)]
-        for op in spec[1:]:
+        walked = est_steps is not None and len(est_steps) == len(spec)
+        scale = max(n0 / max(float(est_steps[0]), 1.0), 1.0) if walked else 1.0
+        for k, op in enumerate(spec):
             if op[0] == "expand":
-                guess = caps[-1] * 4
-                if est_rows:
-                    guess = max(guess, pad_pow2(est_rows, floor=floor))
-                caps.append(min(pad_pow2(guess, floor=floor),
+                if walked:
+                    guess = float(est_steps[k]) * scale * 2
+                else:
+                    guess = caps[-1] * 4
+                    if est_rows:
+                        guess = max(guess, pad_pow2(est_rows, floor=floor))
+                caps.append(min(pad_pow2(int(guess), floor=floor),
                                 int(Global.table_capacity_max)))
         return tuple(caps)
 
@@ -626,7 +675,8 @@ class TemplateCompiledEngine:
         # builds or fetches the padded result table at all
         blind = bool(q.result.blind)
         version = self._version()
-        caps = self._initial_caps(tsig, spec, est)
+        caps = self._initial_caps(
+            tsig, spec, est, getattr(q, "_template_est_steps", None))
         retries = max(int(Global.template_capacity_retries), 0)
         tr = getattr(q, "trace", None)
         for _attempt in range(retries + 1):
@@ -637,13 +687,13 @@ class TemplateCompiledEngine:
                 if prog is None:
                     prog = self._cache_put(key, self._stage(
                         tsig, spec, caps, v2c, proj, width, blind))
-            out = self._dispatch(prog, q, tr)
-            if out is not None:
-                tbl, val = out
+            tbl, val, live, totals, ovfs = self._dispatch(
+                prog, self._bind(prog, spec), q, tr)
+            if not (ovfs.size and bool(ovfs.any())):
                 with self._lock:
                     self._good_caps[(tsig, version)] = caps
                 with span(tr, "template.commit"):
-                    self._commit(q, prog, tbl, val)
+                    self._commit(q, prog, tbl, val, live)
                 q._template_compiled = True
                 q._template_label = prog.label
                 if tr is not None:
@@ -652,25 +702,40 @@ class TemplateCompiledEngine:
                     direct = sum(prog.forms)
                     q._template_lookups = {
                         "direct_lookups": direct,
-                        "search_lookups": len(prog.forms) - direct}
+                        "search_lookups": len(prog.forms) - direct,
+                        "steps": len(spec), "width": width}
                 _M_EXEC.labels(outcome="compiled").inc()
                 return True
-            caps = self._grow_caps(caps, self._last_totals,
-                                   self._last_ovfs)
+            grown = self._grow_caps(caps, totals, ovfs)
+            # the classes that overflowed are not come back to (the ones
+            # that fit are remembered): their program goes, and with it its
+            # few MiB of program text on the device
+            with self._lock:
+                dropped = self._programs.pop(key, None)
+            if dropped is not None:
+                maybe_device_resident("evict", "template", dropped.nbytes)
+            del prog, dropped
+            if tr is not None:
+                for k, (c0, c1) in enumerate(zip(caps, grown)):
+                    if c1 != c0:
+                        tr.event("capacity.retry", site="template.plan",
+                                 step=k, cap_from=c0, cap_to=c1)
+            caps = grown
         _M_EXEC.labels(outcome="overflow").inc()
         raise TemplateOverflow(
             f"padded table overflowed after {retries + 1} attempts")
 
-    def _dispatch(self, prog: _Program, q, tr):
+    def _dispatch(self, prog: _Program, args: list, q, tr):
         """One fused dispatch, charged at the sync point. Returns the
-        fetched (table, valid) on success, None on capacity overflow
-        (per-step totals stashed for the regrow)."""
+        fetched (table, valid, live rows, per-step totals, per-step
+        overflow flags); the caller regrows where a flag is set. Nothing is
+        kept on the engine: clients dispatch side by side."""
         faults.site("template.dispatch")
         t0 = get_usec()
         with span(tr, "template.dispatch"):
             if tr is not None:
                 tr.event("device.dispatch", kernel=prog.label)
-            outs = prog.fn(*prog.args)
+            outs = prog.fn(*args)
         with span(tr, "template.sync"):
             if prog.blind:
                 totals, ovfs, live = outs
@@ -683,8 +748,7 @@ class TemplateCompiledEngine:
                 val = np.asarray(valid)
                 live = int(live)
                 nbytes = int(tbl.nbytes) + int(val.nbytes)
-            self._last_totals = np.asarray(totals)
-            self._last_ovfs = np.asarray(ovfs)
+            totals, ovfs = np.asarray(totals), np.asarray(ovfs)
         wall = get_usec() - t0
         rec = maybe_device_dispatch(
             SITE, template=prog.label, live=live,
@@ -696,13 +760,10 @@ class TemplateCompiledEngine:
                 dev = q.device_steps = []
             dev.append({**rec, "step": len(q.pattern_group.patterns),
                         "eff": (int(live) / max(int(prog.caps[-1]), 1))})
-        if self._last_ovfs.size and bool(self._last_ovfs.any()):
-            return None
-        self._last_live = live
-        return tbl, val
+        return tbl, val, live, totals, ovfs
 
     def _commit(self, q, prog: _Program, tbl: np.ndarray,
-                val: np.ndarray) -> None:
+                val: np.ndarray, live: int) -> None:
         """Install the compiled result exactly as the walk would have
         left it: validity compaction preserves the host row order; the
         fused projection sets the walk's post-projection v2c map, the
@@ -713,7 +774,7 @@ class TemplateCompiledEngine:
         if prog.blind:
             res.v2c_map = dict(prog.v2c)
             res.col_num = prog.width
-            res.nrows = int(self._last_live)
+            res.nrows = int(live)
             q.pattern_step = len(q.pattern_group.patterns)
             return
         out = tbl[val].astype(np.int64)

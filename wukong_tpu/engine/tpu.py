@@ -38,8 +38,15 @@ from wukong_tpu.engine.device_store import DeviceStore
 from wukong_tpu.obs.device import maybe_device_dispatch
 from wukong_tpu.obs.trace import span, traced_execute, traced_step
 from wukong_tpu.utils.timer import get_usec
-from wukong_tpu.sparql.ir import NO_RESULT, PGType, SPARQLQuery
-from wukong_tpu.types import IN, OUT, PREDICATE_ID, TYPE_ID, AttrType
+from wukong_tpu.sparql.ir import NO_RESULT, Pattern, PGType, SPARQLQuery
+from wukong_tpu.types import (
+    IN,
+    NORMAL_ID_START,
+    OUT,
+    PREDICATE_ID,
+    TYPE_ID,
+    AttrType,
+)
 from wukong_tpu.utils.errors import (
     BudgetExceeded,
     CapacityExceeded,
@@ -74,6 +81,9 @@ class TPUEngine:
         # pattern-tuple -> {step: rows}; bounded LRU (a hot mixed workload
         # used to lose EVERY estimate at the old clear-at-4096 threshold)
         self._est_cache = LRUCache(4096)
+        # chain shape -> {step: capacity class}: the classes a retry had to
+        # grow to, so that the next draw of the template starts there
+        self._cap_memo = LRUCache(4096)
         from wukong_tpu.engine.tpu_merge import MergeExecutor
 
         self.merge = MergeExecutor(self)  # sort-merge batch chains (v2)
@@ -108,6 +118,52 @@ class TPUEngine:
                else {k: max(float(e), 1.0) for k, e in enumerate(ests)})
         self._est_cache.put(key, out)
         return out
+
+    def _const_start_sizing(self, q: SPARQLQuery, step_est: dict) -> dict:
+        """A chain that starts from a constant is sized for the heaviest
+        constant its start segment holds, not for the one drawn: degrees are
+        skewed, an estimate from the average overflows on a heavy draw, and
+        a class met for the first time is a program compiled inside a
+        request. The later steps' estimates scale with the start's, so a
+        template's capacity classes are its own, whatever constant comes."""
+        pat = q.get_pattern(q.pattern_step)
+        if q.pattern_step != 0 or q.start_from_index() or pat.predicate <= 0 \
+                or pat.subject <= 0:
+            return step_est
+        heavy = self.g.max_degree(pat.predicate, pat.direction)
+        mean = step_est.get(0)
+        if not mean or heavy <= mean:
+            return step_est
+        return {k: v * (heavy / mean) for k, v in step_est.items()}
+
+    def _sized_as(self, q: SPARQLQuery) -> list:
+        """The patterns a chain is sized by: its own, with every type (of an
+        index start, of a ``?x rdf:type T`` filter) replaced by its heaviest
+        peer (``GStore.heaviest_peer_type``): a template that draws its
+        type (WatDiv's S3 and S5: one of 15 product categories) then runs
+        every draw at one set of capacity classes, and a type met for the
+        first time compiles nothing."""
+        peer = self.g.heaviest_peer_type
+        out = []
+        for i, p in enumerate(q.pattern_group.patterns):
+            s, o = p.subject, p.object
+            if p.predicate == TYPE_ID and i == 0 and q.start_from_index():
+                s = peer(s)
+            elif p.predicate == TYPE_ID and 0 < o < NORMAL_ID_START:
+                o = peer(o)
+            out.append(p if (s, o) == (p.subject, p.object) else
+                       Pattern(s, p.predicate, p.direction, o, p.pred_type))
+        return out
+
+    @staticmethod
+    def _chain_shape(patterns) -> tuple:
+        """The plan with its vertex constants left out: what draws of one
+        template share."""
+        def elem(v):
+            return 0 if v >= NORMAL_ID_START else int(v)
+
+        return tuple((elem(p.subject), int(p.predicate), int(p.direction),
+                      elem(p.object)) for p in patterns)
 
     # ------------------------------------------------------------------
     def execute(self, q: SPARQLQuery, from_proxy: bool = True) -> SPARQLQuery:
@@ -191,11 +247,14 @@ class TPUEngine:
     def _run_pattern_chain(self, q: SPARQLQuery) -> None:
         # device prefix: the longest run of device-supported steps
         device_steps = 0
+        flipped: set = set()
         probe = _MetaResult(q.result)
         for i in range(q.pattern_step, len(q.pattern_group.patterns)):
             pat = q.get_pattern(i)
             if not self._device_supported(q, pat, probe, i == q.pattern_step):
                 break
+            if pat.subject > 0 and i > q.pattern_step:
+                flipped.add(i)  # const_to_known reads the reversed segment
             probe.bind(pat)
             device_steps += 1
 
@@ -212,7 +271,11 @@ class TPUEngine:
             if q.result.col_num == 0 and first.predicate < 0 \
                     and first.subject > 0:
                 vlo = q.pattern_step + 1
-            pins = [(q.get_pattern(i).predicate, q.get_pattern(i).direction)
+            def seg_pat(i):
+                pat = q.get_pattern(i)
+                return _flipped(pat, probe) if i in flipped else pat
+
+            pins = [(seg_pat(i).predicate, seg_pat(i).direction)
                     for i in range(q.pattern_step, q.pattern_step + device_steps)
                     if q.get_pattern(i).predicate > 0]
             pins += [("vpv", int(q.get_pattern(i).direction))
@@ -234,7 +297,7 @@ class TPUEngine:
                             and _is_index_start(q.get_pattern(0)):
                         lo = 1
                     self.dstore.prefetch(
-                        q.get_pattern(i) for i in
+                        seg_pat(i) for i in
                         range(lo, q.pattern_step + device_steps))
             try:
                 self._run_chain_pinned(q, device_steps)
@@ -255,9 +318,12 @@ class TPUEngine:
                     and not q.pattern_group.unions
                     and not q.pattern_group.optional
                     and not q.pattern_group.filters)
-        cap_override: dict[int, int] = {}
-        step_est = (self._chain_estimates(q.pattern_group.patterns)
-                    if q.pattern_step == 0 else {})
+        sized = self._sized_as(q) if q.pattern_step == 0 else None
+        shape = self._chain_shape(sized) if sized else None
+        cap_override: dict[int, int] = dict(
+            self._cap_memo.get(shape) or {}) if shape else {}
+        step_est = (self._const_start_sizing(q, self._chain_estimates(sized))
+                    if sized else {})
         # chain-level span: per-BGP-step work is fused into one compiled
         # dispatch here, so the trace carries steps + kernel-dispatch count
         # (attempts x steps) + rows out at chain granularity; each attempt
@@ -266,8 +332,15 @@ class TPUEngine:
         with span(tr, "tpu.chain", steps=device_steps,
                   rows_in=q.result.nrows, attempts=0) as sp:
             try:
-                self._chain_attempts(q, device_steps, cap_override, step_est,
-                                     blind_ok, tr, sp)
+                grown = self._chain_attempts(q, device_steps, cap_override,
+                                             step_est, blind_ok, tr, sp)
+                if grown and shape:
+                    # classes only grow: a lighter draw's retry takes none
+                    # back from a heavier one's
+                    kept = self._cap_memo.get(shape) or {}
+                    self._cap_memo.put(shape, {
+                        st: max(c, kept.get(st, 0))
+                        for st, c in {**kept, **cap_override}.items()})
             finally:
                 if sp is not None:
                     sp.attrs.update(
@@ -276,9 +349,12 @@ class TPUEngine:
 
     def _chain_attempts(self, q: SPARQLQuery, device_steps: int,
                         cap_override: dict, step_est: dict,
-                        blind_ok: bool, tr, chain_span) -> None:
+                        blind_ok: bool, tr, chain_span) -> bool:
+        """-> whether a step overflowed its class and the chain ran again
+        (traced: one ``capacity.retry`` event a step that grew)."""
         from wukong_tpu.runtime.resilience import charge_query, check_query
 
+        grown = False
         for _attempt in range(8):
             if chain_span is not None:
                 chain_span.attrs["attempts"] = _attempt + 1
@@ -295,8 +371,22 @@ class TPUEngine:
             _charge_chain(q, "tpu.chain", totals, get_usec() - t0, moved)
             over = [s for s, t, c in totals if t > c]
             if not over:
+                if grown:
+                    # what is remembered for the template's next draw is
+                    # what the data asked for, not what the retries guessed
+                    for s, t, _c in totals:
+                        if s in cap_override:
+                            cap_override[s] = K.next_capacity(
+                                int(t), self.cap_min, self.cap_max)
                 break
+            # steps after the first overflow counted over a cut table: their
+            # totals are too low by about the share that was cut, so they
+            # grow by it too, or a chain of k steps needs k attempts
+            first = min(over)
+            lost = max(t / c for s, t, c in totals if s == first)
             for s, t, c in totals:
+                if s > first and t <= c:
+                    t = min(int(max(t, 1) * lost), self.cap_max)
                 if t > c:
                     if t > self.cap_max:
                         # CapacityExceeded (not a query bug): the proxy
@@ -307,6 +397,10 @@ class TPUEngine:
                             f"table_capacity_max ({self.cap_max:,})")
                     cap_override[s] = K.next_capacity(int(t), self.cap_min,
                                                       self.cap_max)
+                    grown = True
+                    if tr is not None:
+                        tr.event("capacity.retry", site="tpu.chain", step=s,
+                                 cap_from=c, cap_to=cap_override[s])
         else:
             raise WukongError(ErrorCode.UNKNOWN_PATTERN,
                               "capacity retry limit exceeded")
@@ -322,6 +416,7 @@ class TPUEngine:
         q.pattern_step += device_steps
         if device_steps and q.get_pattern(q.pattern_step - 1) is not None:
             q.local_var = state.local_var
+        return grown
 
     def _dispatch_chain(self, q: SPARQLQuery, device_steps: int,
                         cap_override: dict,
@@ -370,15 +465,20 @@ class TPUEngine:
             if q.start_from_index() and step == q.pattern_step == 0 \
                     and _is_index_start(pat):
                 edges, real = self.dstore.index_list(start, d)
+                heavy = real
                 if q.mt_factor > 1:
                     lo, hi = _mt_slice(real, q.mt_factor, q.mt_tid)
                     edges, real = edges[lo:hi], hi - lo
-                cap = cap_override.get(step) or K.next_capacity(real, self.cap_min,
-                                                                self.cap_max)
+                    heavy = real
+                elif pid == TYPE_ID:  # the class of the heaviest peer: _sized_as
+                    heavy = max(real, len(self.g.get_index(
+                        self.g.heaviest_peer_type(start), d)))
+                cap = max(cap_override.get(step, 0),
+                          K.next_capacity(heavy, self.cap_min, self.cap_max))
                 if tr is not None:
                     tr.event("device.dispatch", kernel="init_from_list")
                 table, nn = K.init_from_list(edges, jnp.int32(real), cap)
-                state.begin(table, nn, end, est_rows=real)
+                state.begin(table, nn, end, est_rows=heavy)
                 state.local_var = end
                 return
             if pid < 0:
@@ -422,14 +522,20 @@ class TPUEngine:
             assert_ec(q.result.col_num == 0 and state.width == 0,
                       ErrorCode.FIRST_PATTERN_ERROR)
             vids = np.asarray(self.g.get_triples(start, pid, d), dtype=np.int64)
-            cap = cap_override.get(step) or K.next_capacity(len(vids), self.cap_min,
-                                                            self.cap_max)
+            # the class of the segment's heaviest constant: _const_start_sizing
+            heavy = max(len(vids), self.g.max_degree(pid, d))
+            cap = max(cap_override.get(step, 0),
+                      K.next_capacity(heavy, self.cap_min, self.cap_max))
             pad = np.zeros((1, cap), dtype=np.int32)  # [width=1, capacity]
             pad[0, : len(vids)] = vids
             state.begin(jnp.asarray(pad), jnp.int32(len(vids)), end,
-                        est_rows=len(vids))
+                        est_rows=heavy)
             return
 
+        if start > 0 and anchor_col is None:  # const_to_known: see _flipped
+            pat = _flipped(pat, state)
+            assert_ec(pat is not None, ErrorCode.VERTEX_INVALID)
+            start, d, end = pat.subject, pat.direction, pat.object
         col = anchor_col if anchor_col is not None else state.col_of(start)
         assert_ec(col is not None, ErrorCode.VERTEX_INVALID)
         if pid < 0:  # versatile known_unknown_* via expand2
@@ -441,8 +547,8 @@ class TPUEngine:
                 return
             fan = max(1.0, vseg.num_edges / max(vseg.num_keys, 1)) * 2
             est = min(int(state.est_rows * fan) or 1, self.cap_max)
-            cap_out = cap_override.get(step) or K.next_capacity(
-                max(est, self.cap_min), self.cap_min, self.cap_max)
+            cap_out = max(cap_override.get(step, 0), K.next_capacity(
+                max(est, self.cap_min), self.cap_min, self.cap_max))
             fd = self._fp_dup(vseg)
             if tr is not None:
                 tr.event("device.dispatch", kernel="expand2")
@@ -484,8 +590,8 @@ class TPUEngine:
                 state.append_empty_col(end)
                 return
             est = self._estimate_rows(state, pat, seg, step=step)
-            cap_out = cap_override.get(step) or K.next_capacity(
-                max(est, self.cap_min), self.cap_min, self.cap_max)
+            cap_out = max(cap_override.get(step, 0), K.next_capacity(
+                max(est, self.cap_min), self.cap_min, self.cap_max))
             fd = self._fp_dup(seg)
             if tr is not None:
                 tr.event("device.dispatch", kernel="expand")
@@ -517,10 +623,10 @@ class TPUEngine:
             C = state.table.shape[1]
             se = state.step_est.get(step)
             cap_new = cap_override.get(step)
-            if cap_new is None and se is not None:
-                cap_new = K.next_capacity(
-                    max(int(se * self.EST_SAFETY), self.cap_min),
-                    self.cap_min, self.cap_max)
+            if se is not None:
+                cap_new = max(cap_new or 0, K.next_capacity(
+                    max(int(se * self.EST_SAFETY * state.skew), self.cap_min),
+                    self.cap_min, self.cap_max))
             if cap_new is not None and cap_new < C:
                 # estimate-driven shrink: totals ride-along so an
                 # underestimate retries the chain, never drops rows
@@ -829,12 +935,23 @@ class TPUEngine:
         Prefers the planner's joint-type-table per-step estimate
         (state.step_est) with EST_SAFETY headroom; falls back to the shared
         _fanout estimate. A wrong estimate costs one chain retry, never
-        correctness."""
+        correctness. Never under the longest edge list of the segment:
+        one row of the frontier may be its heaviest key (a chain that goes
+        constant -> hub -> expansion meets the skew at its second step).
+        Where that floor lifts a step, the steps after it are lifted by the
+        same factor (``state.skew``): they expand what the heavy key
+        brought, not what the average key would have (WatDiv's L5: city ->
+        country -> everyone of that nationality -> their job titles)."""
         se = state.step_est.get(step) if step is not None else None
+        heavy = self.g.max_degree(pat.predicate, pat.direction)
         if se is not None:
-            return max(min(int(se * self.EST_SAFETY), self.cap_max), 1)
-        est = int(min(state.est_rows * self._fanout(pat, seg), self.cap_max))
-        return max(est, 1)
+            mean = max(se * state.skew, 1.0)
+            if heavy > mean:  # the headroom is for the mean, not the skew
+                state.skew *= heavy / mean
+            est = int(mean * self.EST_SAFETY)
+        else:
+            est = int(state.est_rows * self._fanout(pat, seg))
+        return max(min(max(est, heavy), self.cap_max), 1)
 
     @staticmethod
     def _fp_dup(seg) -> int:
@@ -881,7 +998,24 @@ class TPUEngine:
         s_known = pat.subject > 0 or probe.col_of(pat.subject) is not None
         if is_first and probe.width == 0:
             return pat.subject > 0  # const start
-        return s_known and pat.subject < 0
+        if pat.subject > 0:
+            # const_to_known mid-chain (a plan that starts from one constant
+            # and comes back to another): the membership test of the known
+            # end against the constant, over the reversed segment
+            return _flipped(pat, probe) is not None
+        return s_known
+
+
+def _flipped(pat, cols):
+    """``(CONST p ?known)`` as ``(?known p^-1 CONST)``, or None where the
+    object is not a bound variable or the predicate has no reversed
+    segment (``rdf:type``: the object side stores no type triples)."""
+    if pat.object >= 0 or cols.col_of(pat.object) is None \
+            or pat.predicate in (PREDICATE_ID, TYPE_ID):
+        return None
+    return Pattern(pat.object, pat.predicate,
+                   OUT if int(pat.direction) == IN else IN, pat.subject,
+                   pat.pred_type)
 
 
 def _is_index_start(pat) -> bool:
@@ -940,6 +1074,7 @@ class _ChainState:
         self.totals: list = []  # (step, device_total, cap)
         self.est_rows = 1
         self.step_est: dict = {}  # {step: planner row estimate}
+        self.skew = 1.0  # what heavy keys lifted the steps so far by
         self.local_var = 0
 
     def col_of(self, var: int):

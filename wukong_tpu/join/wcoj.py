@@ -54,7 +54,7 @@ from wukong_tpu.join.kernels import (
 from wukong_tpu.join.qgraph import U_CONST, U_PINDEX, U_TYPE, analyze
 from wukong_tpu.obs.device import maybe_device_dispatch, maybe_device_resident
 from wukong_tpu.obs.metrics import get_registry
-from wukong_tpu.obs.trace import traced_execute
+from wukong_tpu.obs.trace import trace_event, traced_execute
 from wukong_tpu.runtime import faults
 from wukong_tpu.runtime.resilience import (
     charge_query,
@@ -238,6 +238,9 @@ class JoinTableCache:
         seg = self.segment(pid, d)  # host twin first (verify + fault site)
         max_deg = (int(np.diff(seg.offsets).max())
                    if len(seg.offsets) > 1 else 0)
+        trace_event("device.stage", segment=f"dseg:{(int(pid), int(d))}",
+                    bytes=4 * (len(seg.keys) + len(seg.offsets)
+                               + len(seg.edges)))
         return self._put(key, (to_device_i32(seg.keys),
                                to_device_i32(seg.offsets),
                                to_device_i32(seg.edges),

@@ -94,15 +94,13 @@ class Planner:
         if best is None:
             heuristic_plan(q)
             return True
-        pg.patterns[:] = [pat for (pat, _src) in best]
+        pg.patterns[:] = [pat for (pat, _src) in best.plan]
         # provably-empty conjunction (reference "identified empty result
         # query", planner.hpp:1505-1509): engines may skip execution. Sound
         # with filters (only remove rows) and OPTIONAL (left join keeps only
         # parent rows), but NOT with UNION — a branch starting from its own
         # index explores independently of the (empty) parent table.
-        q.planner_empty = bool(self._best_state is not None
-                               and self._best_state.empty
-                               and not pg.unions)
+        q.planner_empty = bool(best.empty and not pg.unions)
         from wukong_tpu.planner.heuristic import bound_vars, plan_seeded_group
 
         parent_bound = bound_vars(pg)
@@ -117,23 +115,23 @@ class Planner:
         return True
 
     # ------------------------------------------------------------------
-    def _plan_group(self, pg: PatternGroup) -> list | None:
+    def _plan_group(self, pg: PatternGroup) -> "_State | None":
+        """The cheapest complete state, or None. The search keeps its best
+        in a cell of its own, not on the planner: queries that no cached
+        plan covers (a plan proved empty is not kept) are planned side by
+        side by the serving threads."""
         pats = list(pg.patterns)
-        self._best_cost = float("inf")
-        self._best_plan = None
-        self._best_state = None
+        best: list = [None]
         for start_state in self._start_candidates(pats):
-            self._dfs(start_state, pats)
-        return self._best_plan
+            self._dfs(start_state, pats, best)
+        return best[0]
 
-    def _dfs(self, state: _State, pats: list) -> None:
-        if state.cost >= self._best_cost:  # branch and bound
-            return
+    def _dfs(self, state: _State, pats: list, best: list) -> None:
+        if best[0] is not None and state.cost >= best[0].cost:
+            return  # branch and bound
         remaining = [p for p in pats if not self._picked(state, p)]
         if not remaining:
-            self._best_cost = state.cost
-            self._best_plan = state.plan
-            self._best_state = state
+            best[0] = state
             return
         cands = []
         for p in remaining:
@@ -142,7 +140,7 @@ class Planner:
                 cands.append(step)
         cands.sort(key=lambda s: s.cost)
         for nxt in cands[: self.max_branch]:
-            self._dfs(nxt, pats)
+            self._dfs(nxt, pats, best)
 
     def _picked(self, state: _State, p: Pattern) -> bool:
         return any(src is p for (_, src) in state.plan)
@@ -446,13 +444,34 @@ class Planner:
         if state is None:
             return None
         states = [state]
+        # a var bound by the index of a predicate holds only vertices that
+        # have it; fine_type's fanout averages over all vertices of a type,
+        # those without the predicate too, so the step that expands that
+        # var by that predicate is put right type by type (_having)
+        having = (p0.object, p0.subject, p0.direction) \
+            if p0.predicate == PREDICATE_ID and p0.object < 0 else None
         for p in patterns[1:]:
             nxt = self._estimate_step(state, p, pre_oriented=True)
             if nxt is None:
                 return None
+            if having and (p.subject, p.predicate) == having[:2] \
+                    and p.direction != having[2] and len(nxt.vars) > len(state.vars):
+                nxt = self._having(nxt, state.vars.index(having[0]),
+                                   self._pred_index_dist(*having[1:]))
             state = nxt
             states.append(state)
         return states
+
+    def _having(self, st: _State, ia: int, index_dist: dict) -> _State:
+        """``st`` with each joint row scaled by its anchor type's population
+        over the type's share of the predicate's index (at least 1)."""
+        pop = self.stats.tyscount
+        ttab = {types: c * max(pop.get(types[ia], 0)
+                               / (index_dist.get(types[ia]) or float("inf")),
+                               1.0)
+                for types, c in st.ttab.items()}
+        return _State(sum(ttab.values()), st.vars, ttab, st.cost, st.plan,
+                      empty=st.empty, exact=st.exact)
 
     def estimate_chain(self, patterns: list) -> list | None:
         """Per-step output-row estimates for an already-ordered pattern list.
@@ -465,17 +484,6 @@ class Planner:
         kernel's cost: kernels pay for capacity, not live rows)."""
         states = self._walk_chain(patterns)
         return None if states is None else [st.rows for st in states]
-
-    def estimate_peak_rows(self, patterns: list) -> int | None:
-        """Peak intermediate cardinality across an already-ordered chain,
-        or None when the shape cannot be walked. The compiled-template
-        route chooser gates on this: a whole-plan XLA dispatch only
-        amortizes when the binding tables it fuses are large enough
-        (``template_min_rows``) to beat the per-step host kernels."""
-        ests = self.estimate_chain(patterns)
-        if not ests:
-            return None
-        return int(max(ests))
 
     def explain_steps(self, patterns: list) -> list | None:
         """EXPLAIN estimate capture: one record per plan step with the

@@ -25,6 +25,14 @@ import numpy as np
 
 from wukong_tpu.types import IN, NORMAL_ID_START, OUT, TYPE_ID
 
+# Tables indexed by id (a type a vertex: 8 bytes an id; a flag a predicate
+# and id: one byte) replace sorting and searching where each takes no more
+# room than the triples the caller already holds, times this: ids as dense
+# as the generators and the id-format loaders make them (WatDiv at scale
+# factor 1000: 0.11 and 1.2 GB beside 2.6 GB of triples; LUBM-640: 0.17 and
+# 0.38 beside 1.9). Sparse ids, or a handful of triples, sort.
+DENSE_ROOM = 1.0
+
 
 class Stats:
     def __init__(self):
@@ -68,51 +76,78 @@ class Stats:
         is_type = p == TYPE_ID
 
         # ---- per-vertex simple/complex type ------------------------------
+        max_id = int(max(s.max(), o.max())) if len(triples) else 0
+        # ids dense enough for tables indexed by id: a flag or a type a
+        # vertex, instead of sorting and searching 10^8 edge endpoints
+        room = DENSE_ROOM * triples.nbytes
+        dense = 8 * (max_id + 1) <= room
         ts, to = s[is_type], o[is_type]
-        order = np.argsort(ts, kind="stable")
+        order = np.lexsort((to, ts))
         ts, to = ts[order], to[order]
+        if len(ts):  # a repeated (vertex, type) pair is one type
+            first = np.ones(len(ts), dtype=bool)
+            first[1:] = (ts[1:] != ts[:-1]) | (to[1:] != to[:-1])
+            ts, to = ts[first], to[first]
         uniq_v, starts = np.unique(ts, return_index=True)
         bounds = np.append(starts, len(ts))
         complex_ids: dict[frozenset, int] = {}
         next_complex = -1
         simple_counts: dict[int, int] = defaultdict(int)
-        if len(uniq_v) == len(ts):
-            # every vertex single-typed (all LUBM-shaped data): the
-            # per-vertex frozenset loop is O(V) Python objects — at
-            # LUBM-10240 (220 M typed vertices) it OOM-killed the host;
-            # the vectorized equivalent is two array ops
-            typed_types = to[starts].astype(np.int64)
-            for t, c in zip(*np.unique(typed_types, return_counts=True)):
-                simple_counts[int(t)] += int(c)
-        else:
-            vtypes: list[int] = []
-            for i, v in enumerate(uniq_v):
-                tset = frozenset(int(x) for x in to[bounds[i]:bounds[i + 1]])
-                if len(tset) == 1:
-                    t = next(iter(tset))
-                else:
-                    if tset not in complex_ids:
-                        complex_ids[tset] = next_complex
-                        next_complex -= 1
-                    t = complex_ids[tset]
-                vtypes.append(t)
-                simple_counts[t] += 1
-            typed_types = np.asarray(vtypes, dtype=np.int64)
+        # single-typed vertices take their type; the others a complex type a
+        # distinct type SET, numbered in the order the sets first appear
+        # over the vertices by id. No Python object a vertex: at LUBM-10240
+        # (220 M typed vertices) the per-vertex frozenset loop OOM-killed
+        # the host, and WatDiv types a million users twice (role and class).
+        typed_types = to[starts].astype(np.int64) if len(ts) else \
+            np.empty(0, dtype=np.int64)
+        multi = np.flatnonzero(np.diff(bounds) > 1)
+        if len(multi):
+            from wukong_tpu.utils.mathutil import hash_u64
+
+            utypes = np.unique(to)
+            hmap = np.asarray([hash_u64(int(x)) for x in utypes],
+                              dtype=np.uint64)
+            mixed = hmap[np.searchsorted(utypes, to)]
+            sig = np.add.reduceat(mixed, starts)[multi] \
+                + np.diff(bounds)[multi].astype(np.uint64)
+            _u, first_at, inv = np.unique(sig, return_index=True,
+                                          return_inverse=True)
+            by_first = np.argsort(first_at)
+            rank = np.empty(len(first_at), dtype=np.int64)
+            rank[by_first] = np.arange(len(first_at))
+            for k in by_first:
+                i = multi[first_at[k]]
+                complex_ids[frozenset(
+                    int(x) for x in to[bounds[i]:bounds[i + 1]])] = \
+                    next_complex - int(rank[k])
+            typed_types[multi] = next_complex - rank[inv]
+            next_complex -= len(first_at)
+        for t, c in zip(*np.unique(typed_types, return_counts=True)):
+            simple_counts[int(t)] += int(c)
         # untyped vertices: complex type from their out-predicate set
-        all_vs = np.unique(np.concatenate(
-            [s, o[o >= NORMAL_ID_START]]))
-        untyped = np.setdiff1d(all_vs, uniq_v)
+        if dense:
+            seen = np.zeros(max_id + 1, dtype=bool)
+            seen[o] = True
+            seen[:NORMAL_ID_START] = False
+            seen[s] = True
+            seen[uniq_v] = False
+            untyped = np.flatnonzero(seen)
+        else:
+            all_vs = np.unique(np.concatenate(
+                [s, o[o >= NORMAL_ID_START]]))
+            untyped = np.setdiff1d(all_vs, uniq_v)
         untyped_types = np.empty(0, dtype=np.int64)
         if len(untyped):
-            norm = ~is_type
-            so_, po_ = s[norm], p[norm]
             # untyped subjects actually carrying out-edges (in LUBM-shaped
             # data the untyped set is literal pools with NO out-edges, so
             # this mask is empty and the whole branch is one shared class).
             # ONE membership pass serves both the branch decision and the
-            # vectorized path below — each isin sorts the full edge list
-            keep = np.isin(so_, untyped)
-            n_out_subj = len(np.unique(so_[keep])) if keep.any() else 0
+            # vectorized path below — each isin sorts the full edge list.
+            # (An untyped subject has no rdf:type edge to leave out.)
+            keep = seen[s] if dense else np.isin(s, untyped)
+            so_, po_ = s[keep], p[keep]
+            del keep
+            n_out_subj = len(np.unique(so_)) if len(so_) else 0
             if n_out_subj > 200_000:
                 # vectorized signature path: group by out-predicate SET
                 # via a commutative 64-bit mix over unique (s, p) pairs —
@@ -122,8 +157,8 @@ class Stats:
 
                 # pack (s, p) into one int64: pred ids < 2^17 (NORMAL_ID_
                 # START) by construction, subject ids < 2^31 -> 48 bits
-                code = np.unique((so_[keep].astype(np.int64) << 17)
-                                 | po_[keep].astype(np.int64))
+                code = np.unique((so_.astype(np.int64) << 17)
+                                 | po_.astype(np.int64))
                 cs_, cp_ = code >> 17, code & ((1 << 17) - 1)
                 upids = np.unique(cp_)
                 hmap = np.asarray([hash_u64(int(x)) for x in upids],
@@ -203,28 +238,67 @@ class Stats:
             for key, cid in complex_ids.items()}
 
         # ---- predicate histograms ----------------------------------------
-        norm = ~is_type
-        sn, pn, on = s[norm], p[norm], o[norm]
-        stype = st._lookup_types(sn)
-        otype = st._lookup_types(on)
-        for pid in np.unique(pn):
-            m = pn == pid
-            st.pred_edges[int(pid)] = int(m.sum())
-            st.distinct_subj[int(pid)] = int(len(np.unique(sn[m])))
-            st.distinct_obj[int(pid)] = int(len(np.unique(on[m])))
-            st.pstype[int(pid)] = _hist(stype[m])
-            st.potype[int(pid)] = _hist(otype[m])
-            for t, c in _hist_pairs(stype[m], otype[m]).items():
-                st.fine_type.setdefault((t[0], int(pid), OUT), {})
-                st.fine_type[(t[0], int(pid), OUT)][t[1]] = \
-                    st.fine_type[(t[0], int(pid), OUT)].get(t[1], 0) + c
-                st.fine_type.setdefault((t[1], int(pid), IN), {})
-                st.fine_type[(t[1], int(pid), IN)][t[0]] = \
-                    st.fine_type[(t[1], int(pid), IN)].get(t[0], 0) + c
-        # rdf:type participates as a predicate too (k2c type filters)
-        st.pred_edges[int(TYPE_ID)] = int(is_type.sum())
-        st.pstype[int(TYPE_ID)] = _hist(st._lookup_types(s[is_type]))
+        tvals = np.unique(np.append(st.vtype, 0))  # 0: no type known
+        nt = len(tvals)
+        counts = np.bincount(p, minlength=1) if len(p) else \
+            np.zeros(1, dtype=np.int64)
+        upids = np.flatnonzero(counts)
+        rank_of = np.zeros(len(counts), dtype=np.int64)
+        rank_of[upids] = np.arange(len(upids))
+        if dense and len(upids) * (max_id + 1) <= room:
+            # one pass each, nothing sorted and no edge moved: a count a
+            # (predicate, subject type, object type) and a flag a
+            # (predicate, vertex), both indexed by id
+            table = st._dense_types(tvals, max_id)
+            r = rank_of[p]
+            cube = np.bincount((r * nt + table[s]) * nt + table[o],
+                               minlength=len(upids) * nt * nt
+                               ).reshape(len(upids), nt, nt)
+            flags = np.zeros((len(upids), max_id + 1), dtype=bool)
+            flags[r, s] = True
+            n_subj = flags.sum(axis=1)
+            flags[r, s] = False
+            flags[r, o] = True
+            n_obj = flags.sum(axis=1)
+            del flags, r
+        else:
+            # sparse ids: a predicate at a time, by sorting
+            cube = np.zeros((len(upids), nt, nt), dtype=np.int64)
+            n_subj = np.zeros(len(upids), dtype=np.int64)
+            n_obj = np.zeros(len(upids), dtype=np.int64)
+            for k, pid in enumerate(upids):
+                m = p == pid
+                sm, om = s[m], o[m]
+                n_subj[k], n_obj[k] = len(np.unique(sm)), len(np.unique(om))
+                np.add.at(cube[k], (
+                    np.searchsorted(tvals, st._lookup_types(sm)),
+                    np.searchsorted(tvals, st._lookup_types(om))), 1)
+        for k, pid in enumerate(upids.tolist()):
+            pairs = cube[k]
+            st.pred_edges[pid] = int(counts[pid])
+            st.pstype[pid] = _hist_of(tvals, pairs.sum(axis=1))
+            if pid == TYPE_ID:
+                # rdf:type participates as a predicate too (k2c type
+                # filters), with its edge count and its subjects' types only
+                continue
+            st.distinct_subj[pid] = int(n_subj[k])
+            st.distinct_obj[pid] = int(n_obj[k])
+            st.potype[pid] = _hist_of(tvals, pairs.sum(axis=0))
+            for a, b in zip(*np.nonzero(pairs)):
+                ta, tb, c = int(tvals[a]), int(tvals[b]), int(pairs[a, b])
+                st.fine_type.setdefault((ta, pid, OUT), {})[tb] = c
+                st.fine_type.setdefault((tb, pid, IN), {})[ta] = c
+        if TYPE_ID not in st.pred_edges:
+            st.pred_edges[int(TYPE_ID)] = 0
+            st.pstype[int(TYPE_ID)] = {}
         return st
+
+    def _dense_types(self, tvals: np.ndarray, max_id: int) -> np.ndarray:
+        """Id -> index of its vertex's type in ``tvals`` (of 0 where the id
+        is no vertex with a type), for ids that are dense."""
+        table = np.full(max_id + 1, np.searchsorted(tvals, 0), dtype=np.int64)
+        table[self.vtype_ids] = np.searchsorted(tvals, self.vtype)
+        return table
 
     def _lookup_types(self, vids: np.ndarray) -> np.ndarray:
         idx = np.searchsorted(self.vtype_ids, vids)
@@ -275,18 +349,5 @@ class Stats:
         return st
 
 
-def _hist(arr: np.ndarray) -> dict[int, int]:
-    u, c = np.unique(arr, return_counts=True)
-    return {int(a): int(b) for a, b in zip(u, c)}
-
-
-def _hist_pairs(a: np.ndarray, b: np.ndarray) -> dict[tuple, int]:
-    if len(a) == 0:
-        return {}
-    order = np.lexsort((b, a))
-    aa, bb = a[order], b[order]
-    new = np.ones(len(aa), dtype=bool)
-    new[1:] = (aa[1:] != aa[:-1]) | (bb[1:] != bb[:-1])
-    starts = np.flatnonzero(new)
-    counts = np.diff(np.append(starts, len(aa)))
-    return {(int(aa[i]), int(bb[i])): int(c) for i, c in zip(starts, counts)}
+def _hist_of(tvals: np.ndarray, counts: np.ndarray) -> dict[int, int]:
+    return {int(tvals[i]): int(counts[i]) for i in np.flatnonzero(counts)}

@@ -181,6 +181,12 @@ def build_plan_recipe(parsed_patterns: list, q: SPARQLQuery):
         if sl is None:
             # plan-introduced structural ids only (index-start rewrites)
             return ("lit", v) if v in (PREDICATE_ID, TYPE_ID) else None
+        if len(sl) == 1 and sl[0][1] == 2 and v < NORMAL_ID_START \
+                and parsed_patterns[sl[0][0]][1] == TYPE_ID:
+            # the type of one ``?x rdf:type T``: read from the query the
+            # recipe is replayed onto, since the proxy keeps plans by the
+            # template's family, in which peers of a type share a recipe
+            return ("slot", sl[0])
         # positions that are concrete in the signature (predicates, type
         # ids) pin the value — no substitution needed
         if any(fi == 1 or v < NORMAL_ID_START for (_i, fi) in sl):

@@ -47,7 +47,7 @@ from wukong_tpu.runtime.monitor import Monitor
 from wukong_tpu.runtime.resilience import Deadline
 from wukong_tpu.sparql.ir import SPARQLQuery, SPARQLTemplate
 from wukong_tpu.sparql.parser import Parser
-from wukong_tpu.types import IN, OUT, is_tpid
+from wukong_tpu.types import IN, OUT, TYPE_ID, is_tpid
 from wukong_tpu.utils.errors import ErrorCode, WukongError
 from wukong_tpu.utils.logger import log_error, log_info
 from wukong_tpu.utils.lru import LRUCache
@@ -278,21 +278,25 @@ class Proxy:
         q._tsig = sig
         version = self._plan_version()
         q._rver = version[0]
+        # plans are kept by the template's family (_template_family): a
+        # template that draws its type shares one join order, so its
+        # draws run one set of programs
+        fam = self._template_family(sig)
         if sig is None:
             # unions/optionals/empty groups plan recursively — shapes the
             # recipe cache (and the item-7 result cache) cannot key
             _M_PLAN_CACHE.labels(result="uncacheable").inc()
-        elif self._plan_cache.lookup(q, sig, version):
+        elif self._plan_cache.lookup(q, fam, version):
             return
         parsed = snapshot_patterns(q) if sig is not None else None
         if self.planner is not None and Global.enable_planner:
             if self.planner.generate_plan(q):
                 if sig is not None:
-                    self._plan_cache.record(parsed, q, sig, version)
+                    self._plan_cache.record(parsed, q, fam, version)
                 return
         heuristic_plan(q)
         if sig is not None:
-            self._plan_cache.record(parsed, q, sig, version)
+            self._plan_cache.record(parsed, q, fam, version)
 
     def _engine_for(self, q: SPARQLQuery, device: str | None):
         if device == "tpu" or (device is None and Global.enable_tpu and self.tpu):
@@ -788,12 +792,12 @@ class Proxy:
     # ------------------------------------------------------------------
     def classify_template_route(self, q: SPARQLQuery) -> str:
         """Plan-time host/device route for a walk-strategy query through
-        the whole-plan compiled engine. Only the planner's peak-rows
-        ESTIMATE is memoized (per template signature + store version,
+        the whole-plan compiled engine. Only the planner's per-step
+        ESTIMATES are memoized (per template signature + store version,
         the ``lane`` pattern) — the route itself is chosen live by
-        ``choose_template_route`` so the per-template demotion latch and
-        the measured padding-efficiency feedback apply on the very next
-        query, not at the next memo invalidation."""
+        ``choose_template_route`` so the per-template demotion latch
+        applies on the very next query, not at the next memo
+        invalidation."""
         from wukong_tpu.engine.template_compile import \
             choose_template_route
 
@@ -811,15 +815,44 @@ class Proxy:
 
             def compute():
                 try:
-                    return self.planner.estimate_peak_rows(pats)
+                    return self.planner.estimate_chain(pats)
                 except Exception:
                     return None
 
-            est = self._plan_cache.aux("template_est", sig,
-                                       self._plan_version(), compute)
+            steps = self._plan_cache.aux("template_est", sig,
+                                         self._plan_version(), compute)
+            # the program's capacity classes start from the steps' own
+            # estimates; the route is gated on their peak
+            q._template_est_steps = steps
+            est = int(max(steps)) if steps else None
         q._template_est_rows = est
-        return choose_template_route(sig, est,
+        return choose_template_route(self._template_family(sig), est,
                                      getattr(self.g, "version", 0))
+
+    def _template_family(self, sig):
+        """``sig`` with each type constant replaced by its heaviest peer
+        (``GStore.heaviest_peer_type``): what the plan cache, the compiled
+        route and its demotion latch go by. A template that draws its type
+        from a class of classes (WatDiv's S3 and S5: one of 15 product
+        categories) is then planned, routed and demoted once, not once a
+        type: a type first met later runs the join order, and so the
+        programs, of the first, and builds no template program of its own
+        after the demotion. (``q._tsig`` keeps the type: it keys replies.)"""
+        peer = getattr(self.g, "heaviest_peer_type", None)
+        if sig is None or peer is None:
+            return sig
+
+        def typed(row):
+            o = row[3]
+            return row[1] == TYPE_ID and isinstance(o, tuple) and o[0] == "k"
+
+        # a type named twice stays itself: a recipe cannot tell its two
+        # places apart (build_plan_recipe pins it)
+        once = {o for o in (r[3] for r in sig if typed(r))
+                if sum(1 for r in sig if typed(r) and r[3] == o) == 1}
+        return tuple(
+            (s, p, d, ("k", peer(o[1])), t) if o in once and p == TYPE_ID
+            else (s, p, d, o, t) for (s, p, d, o, t) in sig)
 
     def template_engine(self):
         """Lazily-built whole-plan compiled engine over the host
@@ -853,8 +886,14 @@ class Proxy:
         if live < max(int(Global.template_min_rows), 1):
             from wukong_tpu.engine.template_compile import latch_demotion
 
-            latch_demotion(getattr(q, "_tsig", None), "small_measured",
-                           getattr(self.g, "version", 0))
+            latch_demotion(
+                self._template_family(getattr(q, "_tsig", None)),
+                "small_measured", getattr(self.g, "version", 0))
+            self.template_engine().drop_template(getattr(q, "_tsig", None))
+            tr = getattr(q, "trace", None)
+            if tr is not None:
+                tr.event("proxy.route", route="template", demoted="walk",
+                         why=f"live rows {live} < template_min_rows")
             log_info(f"compiled template demoted to the host walk "
                      f"(measured live rows {live:,} < template_min_rows "
                      f"{Global.template_min_rows:,})")
@@ -904,6 +943,10 @@ class Proxy:
             self._plan_cache.put_aux("strategy", sig, key, "walk")
             self._m_join_demoted.inc()
             note_feedback("strategy", "demote_walk")
+            tr = getattr(q, "trace", None)
+            if tr is not None:
+                tr.event("proxy.route", route="wcoj", demoted="walk",
+                         why=f"prefix blowup {measured:.1f} > wcoj_ratio")
             log_info(f"wcoj auto-routing: template demoted to the walk "
                      f"(measured prefix blowup {measured:.1f}x > "
                      f"wcoj_ratio {Global.wcoj_ratio} — wcoj did not keep "
@@ -1016,6 +1059,18 @@ class Proxy:
                         suggest_heavy_b=self.heavy_index_batch)
         return self._batcher  # unguarded: write-once reference, non-None past init
 
+    @staticmethod
+    def _note_route(q: SPARQLQuery, route: str) -> None:
+        """Traced: one ``proxy.route`` event naming the route that answered
+        (``wcoj``, ``template`` or ``walk``) and what the plan-time choices
+        were; a demotion decided on this reply adds an event of its own
+        with the reason."""
+        tr = getattr(q, "trace", None)
+        if tr is not None:
+            tr.event("proxy.route", route=route,
+                     strategy=getattr(q, "join_strategy", "walk"),
+                     template_route=getattr(q, "template_route", "host"))
+
     def _serve_execute(self, q: SPARQLQuery, eng,
                        pinned: bool = False) -> SPARQLQuery:
         """One serving-path dispatch: with ``enable_batching`` on,
@@ -1059,6 +1114,7 @@ class Proxy:
                         self.wcoj_dist().try_execute(q)
                     else:
                         self.wcoj().try_execute(q)
+                    self._note_route(q, "wcoj")
                     self._record_wcoj_feedback(q)
                     self._record_route_feedback(q)
                     return q
@@ -1081,6 +1137,7 @@ class Proxy:
                 # failed device attempt until a store mutation re-arms
                 try:
                     if self.template_engine().try_execute(q):
+                        self._note_route(q, "template")
                         self._record_template_feedback(q)
                         return q
                 except Exception as e:
@@ -1089,8 +1146,9 @@ class Proxy:
 
                     reason = (e.code.name if isinstance(e, WukongError)
                               else type(e).__name__)
-                    latch_demotion(getattr(q, "_tsig", None), reason,
-                                   getattr(self.g, "version", 0))
+                    latch_demotion(
+                        self._template_family(getattr(q, "_tsig", None)),
+                        reason, getattr(self.g, "version", 0))
                     self._m_template_fallback.labels(reason=reason).inc()
                     tr = getattr(q, "trace", None)
                     if tr is not None:
@@ -1118,6 +1176,7 @@ class Proxy:
             if getattr(q, "knn", None) is not None:
                 self._maybe_presolve_knn(q)
             eng.execute(q)  # batcher bypass: direct dispatch
+            self._note_route(q, "walk")
             self._record_knn_feedback(q)
             return q
         finally:

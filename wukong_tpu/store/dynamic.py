@@ -102,6 +102,10 @@ class DeltaCSRSegment:
         return self._mat().num_keys
 
     @property
+    def max_degree(self) -> int:
+        return self._mat().max_degree
+
+    @property
     def num_edges(self) -> int:  # exact without materializing
         return self._base.num_edges + self._n_pending
 

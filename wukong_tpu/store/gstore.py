@@ -93,6 +93,29 @@ class GStore:
             return self.p_set
         return self.index.get((int(tpid), int(d)), np.empty(0, dtype=np.int64))
 
+    def max_degree(self, pid: int, d: int) -> int:
+        """The longest edge list of any key of the (pid, dir) segment: what
+        the heaviest constant a template could draw expands to."""
+        seg = self.segments.get((int(pid), int(d)))
+        return seg.max_degree if seg is not None else 0
+
+    def heaviest_peer_type(self, tid: int) -> int:
+        """Of ``tid`` and its peers, the type with the most members. Peers
+        are the types that are instances of a class ``tid`` is an instance
+        of (a class of classes: WatDiv's 15 product categories are the
+        instances of ``wsdbm:ProductCategory``); a type with no class is
+        its own only peer. A template that draws its type from such a class
+        is sized for this one, as a start from a constant is sized for the
+        heaviest constant of its segment."""
+        best, most = int(tid), len(self.get_index(tid, IN))
+        for cls in self.get_triples(tid, TYPE_ID, OUT):
+            members = self.get_index(int(cls), IN)
+            for peer in members[members < NORMAL_ID_START].tolist():
+                n = len(self.index.get((peer, IN), ()))
+                if peer in self.type_ids and (n, -peer) > (most, -best):
+                    best, most = peer, n
+        return best
+
     def get_attr(self, vid: int, aid: int, d: int = OUT):
         seg = self.attrs.get(int(aid))
         if seg is None:

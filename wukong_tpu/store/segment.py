@@ -13,6 +13,7 @@ probes (core/gpu/gpu_hash.cu:149-260).
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import cached_property
 
 import numpy as np
 
@@ -60,6 +61,12 @@ class CSRSegment:
     @property
     def num_edges(self) -> int:
         return len(self.edges)
+
+    @cached_property
+    def max_degree(self) -> int:
+        """The longest edge list of any key; one pass over the offsets,
+        kept with the segment (a write makes a new segment)."""
+        return int(np.diff(self.offsets).max()) if len(self.keys) else 0
 
     def lookup(self, vid: int) -> np.ndarray:
         """Edge list of one key (empty if absent) — GStore::get_edges analogue."""
