@@ -229,6 +229,65 @@ def test_template_execute_says_which_lookups_its_program_took(
     assert (sp2.attrs["direct_lookups"], sp2.attrs["search_lookups"]) == (2, 0)
 
 
+Q_LIGHT = PREFIX + """SELECT ?X WHERE {
+    ?X ub:subOrganizationOf <http://www.Department0.University0.edu> .
+    ?X rdf:type ub:ResearchGroup .
+}"""
+
+
+def test_a_program_reply_is_one_call_and_one_fetch(proxy, monkeypatch):
+    """One run of a program costs the host one call and one fetch: a traced
+    reply holds one ``template.sync`` span and one ``device.dispatch``
+    event, and the draw's constants are bound inside ``template.stage``
+    (so the metric that reads the stage spans holds the bind)."""
+    eng = proxy.template_engine()
+    bind, seen = eng._bind, []
+
+    def bound(prog, spec):
+        tr = obs_trace.current()
+        stack = tr._stacks.get(threading.get_ident())
+        seen.append(stack[-1].name if stack else None)
+        return bind(prog, spec)
+
+    monkeypatch.setattr(eng, "_bind", bound)
+    _route(monkeypatch, "walk")
+    monkeypatch.setattr(Global, "template_device", "auto")
+    monkeypatch.setattr(Global, "enable_tracing", True)
+    for k in range(2):  # the first draw builds the program, the second finds it
+        q = proxy.serve_query(Q_LIGHT, blind=False)
+        assert q._template_compiled and q.result.nrows > 0
+        spans = _by_name(q.trace)
+        assert len(spans["template.sync"]) == 1
+        assert len(spans["template.dispatch"]) == 1
+        assert len(spans["template.stage"]) == 1
+        assert q.trace.event_names().count("device.dispatch") == 1
+        assert "capacity.retry" not in q.trace.event_names()
+    assert seen == ["template.stage", "template.stage"]
+
+
+@pytest.mark.parametrize("knob,text,why", [
+    ("auto", Q_LIGHT, "small_classes"), ("auto", Q_CHAIN, "estimate"),
+    ("device", Q_LIGHT, "knob"), ("host", Q_LIGHT, None)],
+    ids=["small_classes", "estimate", "knob", "walk"])
+def test_route_event_says_why(proxy, monkeypatch, knob, text, why):
+    """The ``proxy.route`` event of a reply from a template program says
+    which half of the rule sent it there: every class of the program under
+    ``template_min_rows``, or the estimated peak at or over it."""
+    reset_demotions()
+    monkeypatch.setattr(Global, "template_device", knob)
+    # with the batcher on the small end does not apply (no classes are
+    # asked for), and the light's estimate is far under the threshold:
+    # neither half of the rule, the knob alone sends it to its program
+    monkeypatch.setattr(Global, "enable_batching", why == "knob")
+    monkeypatch.setattr(Global, "enable_tracing", True)
+    q = proxy.serve_query(text, blind=False)
+    (attrs,) = [a for sp in q.trace.spans for _t, n, a in sp.events
+                if n == "proxy.route"]
+    assert attrs["route"] == ("template" if why else "walk")
+    assert attrs.get("why") == why
+    assert attrs["template_route"] == q.template_route
+
+
 @pytest.mark.parametrize("route", ROUTES)
 def test_spans_enter_wk_annotations(proxy, monkeypatch, route):
     """Each span of a live trace also enters ``TraceAnnotation("wk:" +

@@ -26,6 +26,7 @@ from wukong_tpu.engine.template_compile import (
     is_demoted,
     latch_demotion,
     reset_demotions,
+    route_why,
 )
 from wukong_tpu.loader.datagen import (
     CyclicStrings,
@@ -42,7 +43,7 @@ from wukong_tpu.runtime.faults import FaultPlan, FaultSpec
 from wukong_tpu.runtime.proxy import Proxy
 from wukong_tpu.sparql.ir import Pattern, SPARQLQuery
 from wukong_tpu.store.gstore import build_partition
-from wukong_tpu.types import IN, OUT, PREDICATE_ID
+from wukong_tpu.types import IN, OUT, PREDICATE_ID, TYPE_ID
 from wukong_tpu.utils.errors import ErrorCode
 
 pytestmark = pytest.mark.template
@@ -321,9 +322,15 @@ def test_overflow_past_ceiling_degrades_on_serve_path():
 # ---------------------------------------------------------------------------
 
 def test_route_chooser_knobs_and_thresholds():
+    """Under ``auto`` programs win at both ends: every capacity class
+    under ``template_min_rows`` (the calls are the cost), or the estimated
+    peak at or over it (the device is the cost); the walk keeps the
+    middle. With no classes given (the walk is NumPy on the host) only the
+    estimate routes to a program."""
     sig = ("t", 1)
     Global.template_device = "host"
     assert choose_template_route(sig, 10 ** 6) == "host"
+    assert choose_template_route(sig, 10, caps=(8, 8)) == "host"
     Global.template_device = "device"
     assert choose_template_route(sig, None) == "device"
     Global.template_device = "auto"
@@ -331,6 +338,22 @@ def test_route_chooser_knobs_and_thresholds():
     assert choose_template_route(sig, 999) == "host"
     assert choose_template_route(sig, None) == "host"
     assert choose_template_route(sig, 1000) == "device"
+    # the small end: decided by the classes, whatever the estimate
+    assert choose_template_route(sig, 10, caps=(512, 512)) == "device"
+    assert choose_template_route(sig, None, caps=(512,)) == "device"
+    assert route_why(10, (512, 512)) == "small_classes"
+    # the middle: one class at the threshold and a small estimate
+    assert choose_template_route(sig, 999, caps=(512, 1024)) == "host"
+    assert choose_template_route(sig, 999, caps=(512, 1000)) == "host"
+    assert route_why(999, (512, 1024)) is None
+    # a plan that cannot be compiled has no classes
+    assert choose_template_route(sig, 999, caps=()) == "host"
+    # the large end as before
+    assert choose_template_route(sig, 1000, caps=(512, 4096)) == "device"
+    assert route_why(1000, (512, 4096)) == "estimate"
+    # a latch outranks both halves
+    latch_demotion(sig, "small_measured", version=3)
+    assert choose_template_route(sig, 10, 3, caps=(512,)) == "latched_host"
     assert set(TEMPLATE_ROUTES) == {"device", "host", "latched_host"}
 
 
@@ -489,6 +512,20 @@ def test_small_measured_feedback_demotes_auto_route(tri_proxy,
     assert q2.template_route == "latched_host"
 
 
+def test_numpy_walk_keeps_small_plans(tri_proxy):
+    """A proxy whose walk is the NumPy engine makes no jitted call a step:
+    there the small end of the rule does not apply, and a plan estimated
+    under ``template_min_rows`` walks as it always did."""
+    proxy, text = tri_proxy
+    Global.join_strategy = "walk"
+    Global.template_min_rows = 1 << 20  # over the estimate and the classes
+    q = proxy.run_single_query(text, blind=False)
+    assert q.template_route == "host"
+    assert q._template_plan_caps is None
+    assert not getattr(q, "_template_compiled", False)
+    assert proxy.template_engine().program_count() == 0
+
+
 def test_explain_renders_template_compiled_route(tri_proxy, monkeypatch):
     """EXPLAIN / EXPLAIN ANALYZE (satellite b): the route line says
     ``template-compiled`` and the per-step device table carries the
@@ -523,11 +560,16 @@ def test_budget_eviction_under_template_budget_mb(monkeypatch):
 
     monkeypatch.setattr(Global, "enable_device_obs", True)
     monkeypatch.setattr(Global, "template_budget_mb", 1)
-    monkeypatch.setattr(Global, "table_capacity_min", 1 << 16)
     get_device_obs().reset()
     triples, g, _meta = _tri_world()
     a = int(triples[triples[:, 1] == 2][0, 0])
     eng = TemplateCompiledEngine(g)
+    # what stays on the device with a program is its start list: both
+    # templates settled, as if by an earlier store, at 2^18 rows (1 MB)
+    for pats in ([(a, 2, OUT, -1), (-1, 3, OUT, -2)],
+                 [(2, PREDICATE_ID, IN, -1), (-1, 2, OUT, -2)]):
+        spec = extract_template(handq(pats, [-1, -2]))[0]
+        eng._good_caps[(spec, eng._version())] = (1 << 18, 1 << 10)
     q1 = handq([(a, 2, OUT, -1), (-1, 3, OUT, -2)], [-1, -2])
     assert eng.try_execute(q1)
     assert eng.program_count() == 1
@@ -707,3 +749,224 @@ def test_dist_settle_device_concat_matches_host(monkeypatch):
     monkeypatch.setattr(Global, "template_device", "host")
     out_h = dj._settle(list(slices), 3)
     assert np.array_equal(out_h, host)
+
+
+# ---------------------------------------------------------------------------
+# the small end of the rule: light templates over the device walk (ISSUE 29)
+# ---------------------------------------------------------------------------
+
+UB = "http://swat.cse.lehigh.edu/onto/univ-bench.owl#"
+Q4 = PREFIX + """SELECT ?X ?Y1 ?Y2 ?Y3 WHERE {
+    ?X ub:worksFor %s . ?X rdf:type ub:FullProfessor .
+    ?X ub:name ?Y1 . ?X ub:emailAddress ?Y2 . ?X ub:telephone ?Y3 . }"""
+Q5 = PREFIX + """SELECT ?X WHERE {
+    ?X ub:subOrganizationOf %s . ?X rdf:type ub:ResearchGroup . }"""
+Q6 = PREFIX + """SELECT ?X ?Y WHERE {
+    ?Y ub:subOrganizationOf %s . ?Y rdf:type ub:Department .
+    ?X ub:worksFor ?Y . ?X rdf:type ub:FullProfessor . }"""
+LIGHTS = {"q4": (Q4, "Department"), "q5": (Q5, "Department"),
+          "q6": (Q6, "University")}
+
+
+def _device_walk_proxy(n_univ: int):
+    """A proxy built as the console builds it: the walk is the device
+    engine (here on the CPU backend), one jitted call a step."""
+    from wukong_tpu.engine.tpu import TPUEngine
+    from wukong_tpu.loader.lubm import VirtualLubmStrings, generate_lubm
+    from wukong_tpu.planner.optimizer import make_planner
+
+    triples, _lay = generate_lubm(n_univ, seed=42)
+    g = build_partition(triples, 0, 1)
+    ss = VirtualLubmStrings(n_univ, seed=42)
+    proxy = Proxy(g, ss, CPUEngine(g, ss), TPUEngine(g, ss))
+    proxy.planner = make_planner(triples, None)
+    proxy.tpu.stats = proxy.planner.stats
+    return proxy
+
+
+@pytest.fixture(scope="module")
+def light_proxy():
+    return _device_walk_proxy(1)
+
+
+@pytest.fixture(scope="module")
+def light_proxy20():
+    return _device_walk_proxy(20)
+
+
+def _instances(proxy, cls: str) -> list[str]:
+    tid = proxy.str_server.str2id(f"<{UB}{cls}>")
+    return [proxy.str_server.id2str(int(v))
+            for v in proxy.g.get_index(tid, IN)]
+
+
+def _fresh(proxy) -> None:
+    """Module-scoped proxies start each test with no program and no memo."""
+    proxy.template_engine().clear()
+    proxy._plan_cache.clear()
+
+
+def test_small_classes_route_device_and_keep_their_program(light_proxy):
+    """Every class of q5's program is under ``template_min_rows``: it takes
+    its program from the first draw, and a reply of some ten rows does not
+    latch it to the walk."""
+    proxy = light_proxy
+    _fresh(proxy)
+    depts = _instances(proxy, "Department")
+    for k, dept in enumerate(depts[:4]):
+        q = proxy.serve_query(Q5 % dept, blind=False)
+        assert q.template_route == "device" and q._template_compiled, k
+        assert 0 < q.result.nrows < 100
+        assert max(q._template_caps) < Global.template_min_rows
+        assert route_why(q._template_est_rows, q._template_plan_caps) \
+            == "small_classes"
+        assert demotion_report() == {}
+    assert proxy.template_engine().program_count() == 1
+
+
+def test_large_class_small_reply_is_latched_as_before(light_proxy):
+    """The middle of the rule: q4's estimated peak (some 36 rows) reaches a
+    ``template_min_rows`` of 16, so it takes its program by the estimate;
+    the program's classes (1,024 rows) are over it and the reply under it:
+    demoted to the walk, and its program goes."""
+    proxy = light_proxy
+    _fresh(proxy)
+    Global.template_min_rows = 16
+    depts = _instances(proxy, "Department")
+    q = proxy.serve_query(Q4 % depts[0], blind=False)
+    assert q.template_route == "device" and q._template_compiled
+    assert route_why(q._template_est_rows, q._template_plan_caps) \
+        == "estimate"
+    assert q.result.nrows < 16 <= min(q._template_caps)
+    assert list(demotion_report().values()) == ["small_measured"]
+    assert proxy.template_engine().program_count() == 0
+    q2 = proxy.serve_query(Q4 % depts[1], blind=False)
+    assert q2.template_route == "latched_host"
+    assert not getattr(q2, "_template_compiled", False)
+
+
+def test_estimate_over_threshold_routes_device_as_before(light_proxy):
+    """The large end: an index-origin chain estimated over
+    ``template_min_rows`` takes its program and, with a reply over it too,
+    keeps it."""
+    proxy = light_proxy
+    _fresh(proxy)
+    q = proxy.serve_query(Q_CHAIN, blind=False)
+    assert q.template_route == "device" and q._template_compiled
+    assert q._template_est_rows >= Global.template_min_rows
+    assert route_why(q._template_est_rows, q._template_plan_caps) \
+        == "estimate"
+    assert q.result.nrows >= Global.template_min_rows
+    assert demotion_report() == {}
+    assert proxy.serve_query(Q_CHAIN, blind=False).template_route == "device"
+
+
+def test_small_program_grown_past_the_threshold_is_judged_large(
+        light_proxy, monkeypatch):
+    """A small program whose retry grows a class to ``template_min_rows`` or
+    over is from then on a large one: the reply that grew it is judged
+    against the grown classes (and, small, latches the template), and the
+    next plan is routed by them."""
+    proxy = light_proxy
+    _fresh(proxy)
+    monkeypatch.setattr(Global, "table_capacity_min", 8)
+    Global.template_min_rows = 512
+    text = Q6 % _instances(proxy, "University")[0]
+    eng = proxy.template_engine()
+    q0 = proxy._prepare(text, None, False, None, "default")
+    start = q0._template_plan_caps[0]
+    assert start < 512  # a university's departments
+    # settled, as if by lighter draws, at an expansion class of 8 rows
+    with eng._lock:
+        eng._good_caps[(q0._tsig, eng._version())] = (start, 8)
+    q = proxy.serve_query(text, blind=False)
+    assert q.template_route == "device" and q._template_compiled
+    assert q._template_plan_caps == (start, 8)
+    assert route_why(q._template_est_rows, q._template_plan_caps) \
+        == "small_classes"
+    assert q._template_attempts >= 2  # overflowed, regrown, run again
+    assert max(q._template_caps) >= 512 > q.result.nrows > 0
+    assert eng._good_caps[(q._tsig, eng._version())] == q._template_caps
+    assert list(demotion_report().values()) == ["small_measured"]
+    assert proxy.serve_query(text, blind=False).template_route \
+        == "latched_host"
+    # re-armed, the template is routed by the classes it grew to
+    reset_demotions()
+    q3 = proxy._prepare(text, None, False, None, "default")
+    assert q3._template_plan_caps == q._template_caps
+    assert route_why(q3._template_est_rows, q3._template_plan_caps) \
+        != "small_classes"
+
+
+@pytest.mark.parametrize("name", sorted(LIGHTS))
+def test_light_program_equals_the_walk_over_drawn_constants(light_proxy20,
+                                                            name):
+    """q4, q5 and q6 through their programs equal the host walk row for
+    row over 20 drawn constants each, and one program, traced once, serves
+    every draw: constants are bound query by query."""
+    proxy = light_proxy20
+    text, cls = LIGHTS[name]
+    pool = _instances(proxy, cls)
+    rng = np.random.default_rng(29)
+    draws = [pool[i] for i in rng.choice(len(pool), size=20, replace=False)]
+    eng = proxy.template_engine()
+    fns, rows = set(), 0
+    for k, iri in enumerate(draws):
+        qc = proxy.serve_query(text % iri, blind=False)
+        assert qc.template_route == "device" and qc._template_compiled, k
+        assert qc._template_attempts == 1, k
+        qh = proxy.serve_query(text % iri, blind=False, device="cpu")
+        assert not getattr(qh, "_template_compiled", False)
+        assert_identical(qh, qc)
+        rows += qc.result.nrows
+        with eng._lock:
+            fns |= {id(p.fn) for key, p in eng._programs.items()
+                    if key[0] == qc._tsig}
+            (prog,) = [p for key, p in eng._programs.items()
+                       if key[0] == qc._tsig]
+        assert len(fns) == 1 and prog.fn._cache_size() == 1, k
+    assert rows > 0
+    assert demotion_report() == {}
+
+
+def test_member_lists_of_any_length_run_one_program(light_proxy):
+    """A constant's member list is padded to the class of the longest list
+    its segment holds, as a start list is: students taking 1 to 4 courses
+    (classes of 1, 2 and 4 rows, were each padded alone) run one trace."""
+    proxy = light_proxy
+    g, ss = proxy.g, proxy.str_server
+    takes = ss.str2id(f"<{UB}takesCourse>")
+    teacher = ss.str2id(f"<{UB}teacherOf>")
+    course = ss.str2id(f"<{UB}Course>")
+    seg = g.segments[(takes, OUT)]
+    by_len: dict[int, int] = {}
+    for vid in g.get_index(ss.str2id(f"<{UB}UndergraduateStudent>"), IN):
+        by_len.setdefault(len(g.get_triples(int(vid), takes, OUT)), int(vid))
+    assert seg.max_degree == 4 and sorted(by_len) == [1, 2, 3, 4]
+    old = Global.table_capacity_min
+    Global.table_capacity_min = 1  # a class to every power of two
+    eng = TemplateCompiledEngine(g)
+    try:
+        for n, student in sorted(by_len.items()):
+            # courses (by the type index) this student takes, and who
+            # teaches each
+            pats = [(course, TYPE_ID, IN, -1), (student, takes, OUT, -1),
+                    (-1, teacher, IN, -2)]
+
+            def build():
+                return handq(pats, [-1, -2])
+
+            spec = extract_template(build())[0]
+            assert [op[0] for op in spec] == ["index", "filter_member",
+                                              "expand"]
+            qc = build()
+            qc._tsig = "courses-of-a-student"  # as the proxy: no constants
+            assert eng.try_execute(qc)
+            qh = build()
+            CPUEngine(g).execute(qh)
+            assert qh.result.nrows == n
+            assert_identical(qh, qc)
+    finally:
+        Global.table_capacity_min = old
+    (prog,) = eng._programs.values()
+    assert prog.fn._cache_size() == 1

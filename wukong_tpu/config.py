@@ -514,27 +514,32 @@ class GlobalConfig:
     # device on an over-predicted estimate back to host.
     join_device_min_candidates: int = 65536
     # whole-plan compiled template execution route (engine/
-    # template_compile.py): host (the NumPy walk engine), device (force
-    # the fused XLA program on every eligible template), auto (route
-    # device when the planner's estimated peak rows reach
-    # template_min_rows, demoted when a served reply's live rows do
-    # not). Any compile or mid-flight dispatch failure degrades the query
-    # to the host walk byte-identically and latches a per-template
-    # demotion.
+    # template_compile.py): host (the walk engine), device (force the
+    # fused XLA program on every eligible template), auto (the rule
+    # under template_min_rows). Any compile or mid-flight dispatch
+    # failure degrades the query to the walk byte-identically and latches
+    # a per-template demotion.
     template_device: str = "auto"
-    # dispatch-amortization floor: under `auto`, a template routes to
-    # the compiled program only when the planner's estimated peak
-    # intermediate rows reach this many (one fused dispatch costs ~ms;
-    # small plans are cheaper on the host walk)
+    # the row count the `auto` rule turns on. Programs win at both ends
+    # and the walk keeps the middle. Few padded rows (every capacity
+    # class of the plan's program under this many, and the walk the
+    # device engine): the calls are the cost, and one program beats the
+    # walk's jitted call a step. Many live rows (the planner's estimated
+    # peak at or over this many): the device is the cost. Large padded
+    # classes with a small reply (a served program with a class at or
+    # over this many whose reply holds fewer live rows is demoted): the
+    # walk, which compacts between steps. Where the walk is NumPy on the
+    # host it makes no calls, and only the estimate routes to a program.
     template_min_rows: int = 4096
     # capacity-overflow retries: a compiled run whose padded table
     # overflows regrows its capacity classes (pad_pow2 of the measured
     # totals) and re-dispatches at most this many times before
     # degrading to the host walk
     template_capacity_retries: int = 3
-    # byte budget for cached compiled-template programs and their
-    # staged CSR operand estimates; cold programs past it are
-    # LRU-evicted (charged on the residency ledger, kind "template")
+    # byte budget for what cached compiled-template programs keep staged
+    # on the device (their start lists; a run's result buffer is not
+    # counted); cold programs past it are LRU-evicted (charged on the
+    # residency ledger, kind "template")
     template_budget_mb: int = 256
     # distributed generic join: max slice-range parts a cyclic query over
     # a sharded store fans out to on the heavy lane (hash-partitioning

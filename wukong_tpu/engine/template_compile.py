@@ -26,8 +26,14 @@ through ``maybe_device_dispatch`` (site ``template.plan``) so the
 compile ledger's variant-storm sentinel sees whole-plan variants too.
 
 Routing follows the JOIN_ROUTES/CONSUMED_INPUTS pattern: a
-``template_device`` knob + the :data:`TEMPLATE_ROUTES` literal registry,
-with demotion on evidence a reply carries (its live rows, a failure);
+``template_device`` knob + the :data:`TEMPLATE_ROUTES` literal registry.
+Under ``auto`` programs win at both ends and the walk keeps the middle
+(:func:`route_why`): few padded rows (every capacity class under
+``template_min_rows``) and the calls are the cost, so one beats the
+walk's k; many live rows (the estimated peak at or over it) and the
+device is the cost; large padded classes with a small reply go to the
+walk, which compacts between steps. Demotion is on evidence a reply
+carries (its live rows out of a large class, a failure);
 a measured signal the chooser reads has to come through
 ``read_device_input()`` against a declared ``DEVICE_INPUTS`` member (it
 reads none: a site-wide figure would judge one template by what the
@@ -64,7 +70,7 @@ from wukong_tpu.obs.device import (
 from wukong_tpu.obs.metrics import get_registry
 from wukong_tpu.obs.trace import span, traced_execute
 from wukong_tpu.runtime import faults
-from wukong_tpu.types import PREDICATE_ID, TYPE_ID, AttrType, IN
+from wukong_tpu.types import IN, OUT, PREDICATE_ID, TYPE_ID, AttrType
 from wukong_tpu.utils.timer import get_usec
 
 #: the dispatch site every whole-plan program charges (DEVICE_INPUTS
@@ -173,14 +179,33 @@ def _route_knobs() -> tuple:
             int(Global.template_min_rows))
 
 
+def route_why(est_rows: int | None, caps: tuple | None = None) -> str | None:
+    """Which half of the ``auto`` rule sends a plan to its program, or
+    None for the walk. ``small_classes``: every capacity class of the
+    program lies under ``template_min_rows``, so the device has next to
+    nothing to do and the calls are the cost: one program beats the walk's
+    chain of them (``caps`` is given only where the walk is the device
+    engine; a NumPy walk makes no calls). ``estimate``: the estimated peak
+    reaches ``template_min_rows``, the device is the cost. Between the two
+    (large padded classes, few live rows) the walk compacts step by step."""
+    floor = max(int(Global.template_min_rows), 1)
+    if caps and max(caps) < floor:
+        return "small_classes"
+    if est_rows is not None and est_rows >= floor:
+        return "estimate"
+    return None
+
+
 def choose_template_route(tsig, est_rows: int | None = None,
-                          version: int | None = None) -> str:
+                          version: int | None = None,
+                          caps: tuple | None = None) -> str:
     """Plan-time route for one template. The knob forces host/device;
-    under ``auto`` the planner's estimated peak rows must amortize the
-    dispatch (``template_min_rows``); a served reply whose live rows do
-    not, or a failure, latches the demotion that is read here. A measured
-    signal would come through :func:`read_device_input` against a
-    declared ``DEVICE_INPUTS`` member (the gate-held contract)."""
+    under ``auto`` :func:`route_why` decides from the planner's estimated
+    peak rows and the capacity classes the plan's program runs at; a
+    served reply whose live rows fill no large class, or a failure,
+    latches the demotion that is read here. A measured signal would come
+    through :func:`read_device_input` against a declared ``DEVICE_INPUTS``
+    member (the gate-held contract)."""
     knob = str(Global.template_device).strip().lower()
     if knob == "host":
         return "host"
@@ -190,9 +215,7 @@ def choose_template_route(tsig, est_rows: int | None = None,
         return "device"
     if knob != "auto":
         return "host"
-    if est_rows is None or est_rows < max(int(Global.template_min_rows), 1):
-        return "host"
-    return "device"
+    return "device" if route_why(est_rows, caps) else "host"
 
 
 # ---------------------------------------------------------------------------
@@ -432,6 +455,7 @@ class TemplateCompiledEngine:
         self.tables = JoinTableCache(gstore)
         self._programs: OrderedDict = OrderedDict()  # guarded by: _lock
         self._good_caps: dict = {}  # guarded by: _lock
+        self._first_caps: dict = {}  # guarded by: _lock
         self._lock = make_lock("template.programs")
         get_registry().gauge(
             "wukong_template_programs",
@@ -494,6 +518,7 @@ class TemplateCompiledEngine:
             dropped = sum(p.nbytes for p in self._programs.values())
             self._programs.clear()
             self._good_caps.clear()
+            self._first_caps.clear()
         if dropped:
             maybe_device_resident("invalidate", "template", dropped)
         self.tables.clear()
@@ -517,12 +542,10 @@ class TemplateCompiledEngine:
         args: list = []
         depths: list[int] = []
         id_bounds: list[int] = []
-        nbytes = 0
         if spec[0][0] == "index":  # the same list for every draw
             args += self._start_args(spec[0], caps[0])
         else:
             args += [None, None]
-        nbytes += caps[0] * 4
         for op in spec[1:]:
             kind = op[0]
             if kind in ("expand", "filter_pair", "filter_pair_const"):
@@ -538,13 +561,12 @@ class TemplateCompiledEngine:
                 args += [None, None]
         fn, forms = _build_program(spec, caps, tuple(depths),
                                    tuple(id_bounds), proj, blind)
-        if not blind:
-            # the result fetch buffer counts toward the residency
-            # estimate (blind programs fetch three scalars)
-            out_w = width if proj is None else len(proj)
-            nbytes += caps[-1] * (out_w + 1) * 4
+        # what a cached program keeps on the device is its start list. (A
+        # run's result buffer lives for that run: counted, one program of
+        # 2^24 rows was twice the budget alone, and it and whichever
+        # program ran beside it evicted each other on every request.)
         return _Program(fn, forms, args, caps, spec, v2c, proj, width,
-                        nbytes, _label(tsig), blind)
+                        caps[0] * 4, _label(tsig), blind)
 
     def _start_args(self, op, cap: int) -> list:
         vals = self._start_values(op)
@@ -555,8 +577,8 @@ class TemplateCompiledEngine:
     def _bind(self, prog: _Program, spec: tuple) -> list:
         """``prog.args`` with this query's constants in their slots: the
         constant's start list, constant objects, a constant's member list
-        (padded to a class of its own, so one constant's list length mints
-        no program)."""
+        (padded, as a start list is, to the class of the longest list its
+        segment holds, so no constant's list length mints a program)."""
         args = list(prog.args)
         if args[0] is None:
             args[0:2] = self._start_args(spec[0], prog.caps[0])
@@ -567,9 +589,10 @@ class TemplateCompiledEngine:
                                 dtype=np.int64)
                 if len(ml) > 1 and not bool((ml[1:] >= ml[:-1]).all()):
                     ml = np.sort(ml)
-                pml = np.full(pad_pow2(len(ml), floor=max(
-                    int(Global.table_capacity_min), 1)), _PAD_SENTINEL,
-                    dtype=np.int64)
+                pml = np.full(pad_pow2(
+                    max(len(ml), self.g.max_degree(op[2], op[3])),
+                    floor=max(int(Global.table_capacity_min), 1)),
+                    _PAD_SENTINEL, dtype=np.int64)
                 pml[:len(ml)] = ml
                 args[at:at + 2] = [to_device_i32(pml), np.int32(len(ml))]
                 at += 2
@@ -593,12 +616,50 @@ class TemplateCompiledEngine:
             n0 = max(n0, self.g.max_degree(op[2], op[3]))
         return n0
 
+    def plan_caps(self, q) -> tuple:
+        """The classes the program of ``q``'s plan runs at, for the route
+        rule: those its last sound run ended at or, before any run, those a
+        first attempt would take (from what the proxy stamped on ``q`` at
+        plan time; memoised per template and store version, nothing is
+        staged or built). ``()`` where the small end of the rule cannot
+        apply: a plan that cannot be compiled, or a template whose type has
+        peers (it is itself an instance of a class: WatDiv's S3 and S5), so
+        that requests may draw it: a program is kept under the signature,
+        which keeps the type, and would be built once a type."""
+        tsig, version = getattr(q, "_tsig", None), self._version()
+        if tsig is None:
+            return ()
+        with self._lock:
+            first = self._first_caps.get(tsig)
+            good = self._good_caps.get((tsig, version))
+        if first is None or first[0] != version:
+            ext = extract_template(q)
+            drawn = any(
+                p == TYPE_ID and isinstance(o, tuple) and o[0] == "k"
+                and len(self.g.get_triples(o[1], TYPE_ID, OUT))
+                for (_s, p, _d, o, _t) in tsig)
+            first = (version, () if ext is None or drawn else
+                     self._initial_caps(
+                         tsig, ext[0], getattr(q, "_template_est_rows", None),
+                         getattr(q, "_template_est_steps", None)))
+            with self._lock:
+                self._first_caps[tsig] = first
+        return first[1] and (good or first[1])
+
     def _initial_caps(self, tsig, spec, est_rows: int | None,
                       est_steps: list | None = None) -> tuple:
         """The classes a first attempt runs at: where the planner walked
         the chain, each expansion's own estimate with one class of room (a
         start from a constant scaled to the heaviest constant, as its start
-        list is); else four times the step before, at least the peak."""
+        list is), and never under its segment's longest edge list, with the
+        steps after lifted by the same factor (one row of the frontier may
+        be the heaviest key: ``TPUEngine._estimate_rows`` sizes the walk
+        so), so that a template's classes do not follow the draw; else four
+        times the step before, at least the peak. The floor
+        (``table_capacity_min``) is room for a small estimate's error:
+        where the data cannot fill it (``_fill_bound``) the class is the
+        bound's own, so a light plan's classes are a few dozen rows and its
+        program pushes no thousand padded rows through every lookup."""
         version = self._version()
         with self._lock:
             good = self._good_caps.get((tsig, version))
@@ -614,14 +675,32 @@ class TemplateCompiledEngine:
         for k, op in enumerate(spec):
             if op[0] == "expand":
                 if walked:
-                    guess = float(est_steps[k]) * scale * 2
+                    mean = max(float(est_steps[k]) * scale, 1.0)
+                    heavy = self.g.max_degree(op[1], op[2])
+                    guess = max(mean * 2, heavy)
+                    if heavy > mean:  # the room is for the mean, not the skew
+                        scale *= heavy / mean
                 else:
                     guess = caps[-1] * 4
                     if est_rows:
                         guess = max(guess, pad_pow2(est_rows, floor=floor))
                 caps.append(min(pad_pow2(int(guess), floor=floor),
                                 int(Global.table_capacity_max)))
-        return tuple(caps)
+        tight = [pad_pow2(b, floor=1) for b in self._fill_bound(spec, n0)]
+        return tuple(t if t < floor else c for c, t in zip(caps, tight))
+
+    def _fill_bound(self, spec, n0: int) -> list:
+        """The most rows each class can ever hold: the start list's length
+        (exact: ``_start_len``), and after an expansion the rows before it
+        times its segment's longest edge list. A class of this many rows
+        cannot overflow, so it needs no room."""
+        bound = [int(n0)]
+        for op in spec:
+            if op[0] == "expand":
+                bound.append(min(
+                    bound[-1] * self.g.max_degree(op[1], op[2]),
+                    int(Global.table_capacity_max)))
+        return bound
 
     @staticmethod
     def _grow_caps(caps: tuple, totals: np.ndarray,
@@ -651,7 +730,8 @@ class TemplateCompiledEngine:
         on compile/dispatch failure with ``q`` UNTOUCHED — the caller
         latches the per-template demotion and walks. Traced, the whole
         attempt is one ``template.execute`` span; ``template.stage``
-        (program lookup, staging on a miss), ``template.dispatch``,
+        (program lookup, staging on a miss, the draw's constants bound),
+        ``template.dispatch``,
         ``template.sync`` and ``template.commit`` lie inside it; it ends
         with how many of the program's key lookups took the direct form
         and how many the search (``direct_lookups``, ``search_lookups``)."""
@@ -687,11 +767,12 @@ class TemplateCompiledEngine:
                 if prog is None:
                     prog = self._cache_put(key, self._stage(
                         tsig, spec, caps, v2c, proj, width, blind))
-            tbl, val, live, totals, ovfs = self._dispatch(
-                prog, self._bind(prog, spec), q, tr)
+                args = self._bind(prog, spec)
+            tbl, val, live, totals, ovfs = self._dispatch(prog, args, q, tr)
             if not (ovfs.size and bool(ovfs.any())):
                 with self._lock:
                     self._good_caps[(tsig, version)] = caps
+                q._template_caps = caps  # what the reply's feedback judges
                 with span(tr, "template.commit"):
                     self._commit(q, prog, tbl, val, live)
                 q._template_compiled = True
@@ -730,6 +811,8 @@ class TemplateCompiledEngine:
         fetched (table, valid, live rows, per-step totals, per-step
         overflow flags); the caller regrows where a flag is set. Nothing is
         kept on the engine: clients dispatch side by side."""
+        import jax
+
         faults.site("template.dispatch")
         t0 = get_usec()
         with span(tr, "template.dispatch"):
@@ -737,18 +820,17 @@ class TemplateCompiledEngine:
                 tr.event("device.dispatch", kernel=prog.label)
             outs = prog.fn(*args)
         with span(tr, "template.sync"):
+            # one fetch of everything the program returned: the copies are
+            # started together and waited for once (the sync point)
+            outs = jax.device_get(outs)
             if prog.blind:
                 totals, ovfs, live = outs
                 tbl = val = None
-                live = int(live)  # blocks: the sync point
                 nbytes = 12
             else:
-                table, valid, totals, ovfs, live = outs
-                tbl = np.asarray(table)  # blocks: the sync point
-                val = np.asarray(valid)
-                live = int(live)
+                tbl, val, totals, ovfs, live = outs
                 nbytes = int(tbl.nbytes) + int(val.nbytes)
-            totals, ovfs = np.asarray(totals), np.asarray(ovfs)
+            live = int(live)
         wall = get_usec() - t0
         rec = maybe_device_dispatch(
             SITE, template=prog.label, live=live,

@@ -792,12 +792,13 @@ class Proxy:
     # ------------------------------------------------------------------
     def classify_template_route(self, q: SPARQLQuery) -> str:
         """Plan-time host/device route for a walk-strategy query through
-        the whole-plan compiled engine. Only the planner's per-step
-        ESTIMATES are memoized (per template signature + store version,
-        the ``lane`` pattern) — the route itself is chosen live by
-        ``choose_template_route`` so the per-template demotion latch
-        applies on the very next query, not at the next memo
-        invalidation."""
+        the whole-plan compiled engine. Only the rule's INPUTS are
+        memoized per template signature + store version (the planner's
+        per-step estimates, the ``lane`` pattern; the capacity classes of
+        the plan's program, by the template engine) — the route itself is
+        chosen live by ``choose_template_route`` so the per-template
+        demotion latch applies on the very next query, not at the next
+        memo invalidation."""
         from wukong_tpu.engine.template_compile import \
             choose_template_route
 
@@ -826,8 +827,17 @@ class Proxy:
             q._template_est_steps = steps
             est = int(max(steps)) if steps else None
         q._template_est_rows = est
+        caps = None
+        if self.tpu is not None and Global.enable_tpu \
+                and not Global.enable_batching:
+            # the walk would be the device engine, a jitted call a step:
+            # a plan whose program is small takes the program for the
+            # calls it saves. (A NumPy walk makes no calls, and the
+            # batcher already answers many replies with one.)
+            caps = self.template_engine().plan_caps(q)
+        q._template_plan_caps = caps
         return choose_template_route(self._template_family(sig), est,
-                                     getattr(self.g, "version", 0))
+                                     getattr(self.g, "version", 0), caps)
 
     def _template_family(self, sig):
         """``sig`` with each type constant replaced by its heaviest peer
@@ -872,10 +882,13 @@ class Proxy:
     def _record_template_feedback(self, q: SPARQLQuery) -> None:
         """Measured feedback for the compiled-template route: after a
         successful compiled execution under ``template_device auto``, a
-        measured live-row count below ``template_min_rows`` means the
-        estimate over-predicted and the fused dispatch was overhead on a
-        plan this small — latch the template back to the host walk (a
-        store mutation re-arms the estimate-driven decision)."""
+        measured live-row count below ``template_min_rows`` out of a
+        program with a capacity class at or over it means the estimate
+        over-predicted and the program pushed padding through every step
+        — latch the template back to the walk, which compacts between
+        steps (a store mutation re-arms the estimate-driven decision). A
+        program whose every class is under ``template_min_rows`` is kept
+        whatever its reply holds: it is there for the calls it saves."""
         if str(Global.template_device).strip().lower() != "auto":
             return
         recs = [r for r in (getattr(q, "device_steps", None) or [])
@@ -883,7 +896,9 @@ class Proxy:
         if not recs:
             return
         live = int(recs[-1].get("live", 0))
-        if live < max(int(Global.template_min_rows), 1):
+        floor = max(int(Global.template_min_rows), 1)
+        caps = getattr(q, "_template_caps", None)  # the classes it ran at
+        if live < floor and not (caps and max(caps) < floor):
             from wukong_tpu.engine.template_compile import latch_demotion
 
             latch_demotion(
@@ -1062,14 +1077,25 @@ class Proxy:
     @staticmethod
     def _note_route(q: SPARQLQuery, route: str) -> None:
         """Traced: one ``proxy.route`` event naming the route that answered
-        (``wcoj``, ``template`` or ``walk``) and what the plan-time choices
-        were; a demotion decided on this reply adds an event of its own
-        with the reason."""
+        (``wcoj``, ``template`` or ``walk``), what the plan-time choices
+        were and, for a template program, ``why``: which half of the rule
+        sent the reply to it (``small_classes`` or ``estimate``; ``knob``
+        where ``template_device`` forced it). A demotion decided on this
+        reply adds an event of its own with the reason."""
         tr = getattr(q, "trace", None)
-        if tr is not None:
-            tr.event("proxy.route", route=route,
-                     strategy=getattr(q, "join_strategy", "walk"),
-                     template_route=getattr(q, "template_route", "host"))
+        if tr is None:
+            return
+        attrs = {}
+        if route == "template":
+            from wukong_tpu.engine.template_compile import route_why
+
+            attrs["why"] = route_why(
+                getattr(q, "_template_est_rows", None),
+                getattr(q, "_template_plan_caps", None)) or "knob"
+        tr.event("proxy.route", route=route,
+                 strategy=getattr(q, "join_strategy", "walk"),
+                 template_route=getattr(q, "template_route", "host"),
+                 **attrs)
 
     def _serve_execute(self, q: SPARQLQuery, eng,
                        pinned: bool = False) -> SPARQLQuery:
