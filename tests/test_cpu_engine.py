@@ -186,12 +186,17 @@ def test_order_limit_offset(world):
     assert names[0] == '"FullProfessor1"'  # offset skipped FullProfessor0
 
 
+WRONG = "/root/reference/scripts/sparql_query/lubm/wrong"
+
+
+@pytest.mark.skipif(not os.path.isdir(WRONG),
+                    reason="S1: the reference's suite is not in the tree")
 def test_wrong_suite_engine_errors(world):
     """Reference 'wrong' suite: q2 without a plan must fail with a plan error."""
     from wukong_tpu.utils.errors import ErrorCode, WukongError
 
     triples, g, ss, idx = world
-    text = open("/root/reference/scripts/sparql_query/lubm/wrong/q2").read()
+    text = open(f"{WRONG}/q2").read()
     q = Parser(ss).parse(text)
     with pytest.raises(WukongError):
         heuristic_plan(q)

@@ -195,9 +195,17 @@ def test_fused_heavy_counts_match_sequential_and_oracle(world, monkeypatch):
 
     monkeypatch.setattr(Global, "enable_batching", True)
     monkeypatch.setattr(Global, "batch_window_us", 100_000)
+    # An arrival that finds the batcher idle is dispatched alone at once,
+    # and a lone heavy under the split threshold is not a fused dispatch:
+    # a group forms only from arrivals DURING a dispatch. On a loaded host
+    # threads started one after the other each find it idle, so the
+    # batcher is built and warm first and all five arrive together.
+    assert proxy.serve_query(text, blind=True).result.nrows == want
     before = _counter("wukong_batch_heavy_fused_total")
     out = [None] * 5
+    together = threading.Barrier(len(out))
     def go(i):
+        together.wait(timeout=30)
         out[i] = proxy.serve_query(text, blind=True)
     ths = [threading.Thread(target=go, args=(i,)) for i in range(len(out))]
     for t in ths:

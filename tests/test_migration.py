@@ -714,7 +714,7 @@ def test_rebalance_drill_executes_and_rebalances(world, proxy,
     """The hot-spot drill flipped from observe-only to executed: the
     actuator migrates the advisor's donor shard, every probe during the
     migration is byte-identical, and the post-move host imbalance lands
-    under placement_imbalance_x (bench.py --rebalance's contract)."""
+    under placement_imbalance_x."""
     monkeypatch.setattr(Global, "migration_enable", True)
     sstore = _sstore(world)
     emu = Emulator(proxy)
@@ -733,7 +733,7 @@ def test_rebalance_drill_executes_and_rebalances(world, proxy,
 
 def test_rebalance_drill_refuses_when_disarmed(world, proxy):
     """migration_enable off: the drill raises at run_plan — the
-    observe-only posture holds even through the bench entrypoint."""
+    observe-only posture holds even through the drill's entry point."""
     sstore = _sstore(world)
     emu = Emulator(proxy)
     with pytest.raises(WukongError, match="migration_enable is off"):

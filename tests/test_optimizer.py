@@ -152,7 +152,11 @@ def _peak_intermediate(g, ss, q):
     return peak
 
 
-@pytest.mark.parametrize("qn", ["lubm_q1", "lubm_q2", "lubm_q3", "lubm_q7"])
+@pytest.mark.parametrize("qn", [
+    pytest.param(qn, marks=pytest.mark.skipif(
+        not os.path.exists(f"{BASIC}/osdi16_plan/{qn}.fmt"),
+        reason="S1: the reference's hand-tuned plan is not in the tree"))
+    for qn in ["lubm_q1", "lubm_q2", "lubm_q3", "lubm_q7"]])
 def test_plan_quality_vs_osdi16(qn):
     """The cost-based plan's peak intermediate must be within 1.5x of the
     reference's hand-tuned osdi16 plan (planner.hpp joint type table)."""

@@ -8,15 +8,11 @@ covers >=90% of end-to-end wall time; batched members are attributed via
 their FusedGroup's dispatch span; heat counters account primary/failover/
 degraded fetch outcomes (chaos-marked); the Zipfian hot-spot scenario
 ranks the hot shard first with load-rate CDFs separating hot from cold;
-/top scrapes; the regression sentinel trips and auto-dumps through the
-flight recorder; and scripts/bench_report.py trends + checks the BENCH
-artifacts.
+/top scrapes; and the regression sentinel trips and auto-dumps through
+the flight recorder.
 """
 
 import json
-import os
-import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -397,7 +393,7 @@ def test_attribution_off_is_untouched(proxy, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# satellites: payload sizing, heat-telemetry gate, bench_report
+# satellites: payload sizing, heat-telemetry gate
 # ---------------------------------------------------------------------------
 
 def test_payload_size_shapes():
@@ -443,31 +439,6 @@ def test_heat_telemetry_gate_fixtures(tmp_path):
         "        self.shards = {}  # guarded by: _lock\n"
         "        self.lock = make_lock('heat.x')\n")})
     assert run_analysis(good, plugins=["heat-telemetry"]) == []
-
-
-def test_bench_report_trend_and_check(tmp_path):
-    script = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "scripts", "bench_report.py")
-    d = tmp_path / "b"
-    d.mkdir()
-    (d / "BENCH_X_r01.json").write_text(
-        json.dumps({"metric": "m", "value": 100.0, "unit": "us"}))
-    (d / "BENCH_X_r02.json").write_text(
-        json.dumps({"metric": "m", "value": 90.0, "unit": "us"}))
-    ok = subprocess.run([sys.executable, script, "--dir", str(d), "--check"],
-                        capture_output=True, text=True)
-    assert ok.returncode == 0, ok.stdout + ok.stderr
-    md = (d / "BENCH_TRAJECTORY.md").read_text()
-    assert "BENCH_X" in md and "r01:100.0" in md
-    js = json.loads((d / "BENCH_TRAJECTORY.json").read_text())
-    assert js["series"]["BENCH_X"]["direction"] == -1
-    # a >20% latency regression on the newest rung fails --check
-    (d / "BENCH_X_r03.json").write_text(
-        json.dumps({"metric": "m", "value": 130.0, "unit": "us"}))
-    bad = subprocess.run([sys.executable, script, "--dir", str(d),
-                          "--check"], capture_output=True, text=True)
-    assert bad.returncode == 1
-    assert "REGRESSION" in bad.stderr
 
 
 def test_monitor_heat_lines(world):

@@ -61,6 +61,8 @@ def test_suite_query_executes_or_fails_cleanly(world, qfile, monkeypatch):
     assert isinstance(q.result.status_code, ErrorCode), qfile
 
 
+@pytest.mark.skipif(not os.path.isdir(f"{SUITES}/union"),
+                    reason="S1: the reference's suite is not in the tree")
 def test_union_suite_counts(world):
     """union/q1: |Course ∪ University names| == |Course names| + |Univ names|."""
     g, ss = world

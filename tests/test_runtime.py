@@ -14,7 +14,7 @@ from wukong_tpu.runtime.proxy import Proxy
 from wukong_tpu.store.gstore import build_partition
 
 from wukong_tpu.utils.paths import LUBM_BASIC as BASIC
-EMU = "/root/reference/scripts/sparql_query/lubm/emulator"
+from wukong_tpu.utils.paths import LUBM_EMULATOR as EMU
 
 
 @pytest.fixture(scope="module")
@@ -137,9 +137,7 @@ def test_engine_pool_executes_and_steals(proxy):
 
 def test_emulator_heavy_mix(proxy, monkeypatch):
     monkeypatch.setattr(Global, "enable_tpu", False)
-    mix = load_mix_config(
-        "/root/reference/scripts/sparql_query/lubm/emulator/mix_config_heavy",
-        proxy.str_server)
+    mix = load_mix_config(f"{EMU}/mix_config_heavy", proxy.str_server)
     assert len(mix.heavies) == 4 and len(mix.templates) == 0
     out = Emulator(proxy).run(mix, duration_s=0.5, warmup_s=0.1)
     assert out["thpt_qps"] > 0
@@ -249,8 +247,7 @@ def test_emulator_templates_q7_to_q12(proxy):
 
     rng = np.random.default_rng(0)
     for qn in ("q7", "q8", "q9", "q10", "q11", "q12"):
-        text = open("/root/reference/scripts/sparql_query/lubm/emulator/"
-                    f"{qn}").read()
+        text = open(f"{EMU}/{qn}").read()
         t = Parser(proxy.str_server).parse_template(text)
         proxy.fill_template(t)
         q = t.instantiate(rng)
@@ -263,7 +260,7 @@ def test_emulator_templates_q7_to_q12(proxy):
 
     # %<fromPredicate> in an OBJECT slot draws the predicate's objects
     tq11 = Parser(proxy.str_server).parse_template(
-        open("/root/reference/scripts/sparql_query/lubm/emulator/q11").read())
+        open(f"{EMU}/q11").read())
     proxy.fill_template(tq11)
     (pi, fld), = tq11.pos
     pat = tq11.query.pattern_group.patterns[pi]

@@ -32,7 +32,7 @@ other locks are held adds edges to a process-wide directed graph:
 Zero-cost when off: with ``debug_locks`` false the factories return plain
 ``threading.Lock`` / ``RLock`` / ``Condition`` objects — not pass-through
 wrappers — so the serving hot path pays nothing (pinned by
-tests/test_analysis.py and the BENCH_SERVE.json ``debug_locks`` entry).
+tests/test_analysis.py).
 Module-level locks created at import time register through
 :func:`register_global_lock` and are rebuilt by :func:`install`, so the
 chaos/recovery/batch suites can flip the whole process into checked mode.

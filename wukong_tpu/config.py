@@ -198,8 +198,7 @@ class GlobalConfig:
     # tsdb_interval_s seconds into a bounded ring tsdb_retention_s deep,
     # answering windowed rate / percentile / range queries (/history, the
     # `history` verb, and the PlacementAdvisor's trend reads). Default ON:
-    # one snapshot per interval is far off any hot path (overhead guard in
-    # BENCH_SERVE.json detail.observatory).
+    # one snapshot per interval is far off any hot path.
     enable_tsdb: bool = True
     tsdb_interval_s: int = 5
     tsdb_retention_s: int = 900
@@ -253,9 +252,7 @@ class GlobalConfig:
     # counters/latency histograms, per-tenant in-flight + arrival-rate
     # EWMAs, and the overload signal bus item 4's admission controller
     # consumes. Default ON: the per-reply cost is a few leaf-lock counter
-    # updates (the PR 3/PR 7 zero-measurable-overhead posture; guarded by
-    # BENCH_SERVE.json detail.tenant_accounting). Off degrades every hook
-    # to one knob check.
+    # updates. Off degrades every hook to one knob check.
     enable_tenant_accounting: bool = True
     # bounded label cardinality: at most this many distinct tenant label
     # values; later tenants land in the "__overflow__" bucket (a hostile
@@ -288,8 +285,7 @@ class GlobalConfig:
     # shadow key ring (key = plan signature + consts + store version,
     # ROADMAP item 7's exact cache key) simulating hit/miss/evict/
     # invalidate WITHOUT storing results. Default ON: the per-reply cost
-    # is a few leaf-lock updates (BENCH_SERVE.json
-    # detail.reuse_observatory); off degrades every hook — including the
+    # is a few leaf-lock updates; off degrades every hook — including the
     # store-mutation invalidation notes — to one knob check.
     enable_reuse: bool = True
     # per-template arrival samples kept for the windowed rate
@@ -312,8 +308,8 @@ class GlobalConfig:
     # the compile ledger (cold/warm split, per-site shape variants), and
     # the device-residency ledger (bytes per kind vs the budget).
     # Default ON: the hot serving path carries no device dispatch, so
-    # the per-hook cost is one knob check (BENCH_SERVE.json
-    # detail.device_observatory); off degrades every seam to that check.
+    # the per-hook cost is one knob check; off degrades every seam to
+    # that check.
     enable_device_obs: bool = True
     # device-resident byte ceiling the residency ledger reports against
     # (telemetry only — DeviceStore's own LRU budget keeps enforcing;

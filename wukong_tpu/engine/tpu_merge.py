@@ -494,10 +494,10 @@ class MergeExecutor:
 
     def _lookup_factor(self) -> int:
         """Backend-aware crossover: the sort-vs-gather economics INVERT
-        across backends (bench.py --micro — TPU: sort 2-3 ns/elem vs
-        gather 9.5, not measured on the attached chip; CPU: sort ~80
-        ns/elem vs gather ~2.5), so the probe arm wins ~8x earlier on
-        the CPU backend. Forced settings
+        across backends (an earlier installation's micro, not measured on
+        the attached chip, ROADMAP S4: a TPU sorts cheaper than it
+        gathers, a CPU the other way round), so the probe arm wins ~8x
+        earlier on the CPU backend. Forced settings
         (factor 0 / huge in tests) scale through unchanged."""
         f = self.PROBE_LOOKUP_FACTOR
         if getattr(self.eng.dstore.device, "platform", "cpu") != "tpu":

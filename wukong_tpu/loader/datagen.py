@@ -167,7 +167,7 @@ def convert_dir(src_dir: str, dst_dir: str, timestamps: int = 0,
 # Each generator returns ([M,3] int64 triples, meta) where meta carries the
 # predicate/type id map and the cyclic query as a parsed-form pattern list
 # (vars negative, triple orientation) plus its projection vars — enough for
-# tests and bench.py --cyclic to build queries without a string server.
+# tests to build queries without a string server.
 #
 # The triangle/diamond worlds embed the AGM lower-bound instance (star +
 # co-star hubs: R(A,B) = {a*}xB ∪ Ax{b*}): every PAIRWISE join is Θ(m²)
@@ -342,7 +342,7 @@ def watdiv_cyclic_patterns() -> dict:
     """WatDiv-based cyclic query set (parsed-form patterns over the
     loader/watdiv.py id space): the social triangle (two friends liking
     the same product) and the follows/friendOf diamond. Run against
-    ``generate_watdiv`` worlds by bench.py --cyclic."""
+    ``generate_watdiv`` worlds. No caller in the tree (ROADMAP D10)."""
     from wukong_tpu.loader.watdiv import P
 
     u, v, w = -1, -2, -3

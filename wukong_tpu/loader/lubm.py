@@ -59,7 +59,8 @@ ATTR_TYPE = {A[name]: t for (name, t) in ATTR_NAMES}
 NUM_RESEARCH = 30  # researchInterest literal pool ("Research0".."Research29")
 
 # Bump when the synthesized dataset changes shape/ids — cache files
-# (bench.py .cache/) are keyed on it so stale stores are never reused.
+# (__graft_entry__.py's .cache/) are keyed on it so stale stores are
+# never reused.
 DATASET_VERSION = 2
 
 FACULTY_CLASSES = ["FullProfessor", "AssociateProfessor", "AssistantProfessor", "Lecturer"]

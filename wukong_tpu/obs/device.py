@@ -38,8 +38,7 @@ Surfaced as ``GET /device`` + ``/device.json`` on obs/httpd.py, the
 ``device`` console verb, a Monitor ``Device[...]`` rolling-report line,
 and tsdb trend windows. Everything gates on ``enable_device_obs``
 (default ON; the hot serving path carries no device dispatch, so the
-hook cost is one knob check — BENCH_SERVE.json
-``detail.device_observatory``).
+hook cost is one knob check).
 """
 
 from __future__ import annotations

@@ -9,8 +9,8 @@ with two exporters:
 - :meth:`MetricsRegistry.render_prometheus` — the Prometheus text
   exposition format (scrape-able once an HTTP endpoint fronts it; the
   golden test in tests/test_obs.py pins the format)
-- :meth:`MetricsRegistry.snapshot` — a plain-dict JSON view folded into
-  bench artifacts (bench.py, scripts/bench_stream.py)
+- :meth:`MetricsRegistry.snapshot` — a plain-dict JSON view (the tsdb
+  sampler, ``/metrics.json`` and the console's one-shot dump read it)
 
 Design constraints (the hot path runs per query/epoch, never per row):
 metric *creation* is get-or-create under one lock; *updates* on a bound

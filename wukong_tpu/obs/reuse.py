@@ -8,7 +8,7 @@ it proves, before a single byte is cached, what hit rate a version-keyed
 result cache would achieve and which mutation paths would invalidate it.
 
 Three planes, all observe-only (the store and the serving replies are
-never touched — ``bench.py --readmostly`` pins the content digest):
+never touched — tests/test_reuse.py pins the content digest):
 
 - :class:`TemplatePopularityLedger` — charged at the proxy reply point:
   per-template (plan-cache signature, constants abstracted) read counts,
@@ -39,7 +39,7 @@ the map honest and the mutation paths hooked). Surfaced as ``GET /cache``
 + ``/cache.json`` on obs/httpd.py, the ``cache`` console verb, and a
 Monitor ``Cache[...]`` rolling-report line. Everything is gated on
 ``enable_reuse`` (default ON; the per-reply cost is a few leaf-lock
-updates — BENCH_SERVE.json ``detail.reuse_observatory``); off degrades
+updates); off degrades
 every hook to one knob check. ``reuse_sample_every`` additionally samples
 the shadow probe (1 = every reply) if the probe ever outgrows the
 leaf-lock budget on a hotter box.

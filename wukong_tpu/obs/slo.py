@@ -32,7 +32,7 @@ Tenant label cardinality is bounded: past ``max_tenants`` distinct values
 every new tenant lands in the ``"__overflow__"`` bucket, so a hostile or
 buggy client can never mint unbounded metric series. Everything is gated
 on ``enable_tenant_accounting`` (default ON — the per-reply cost is a few
-leaf-lock updates, pinned by BENCH_SERVE.json detail.tenant_accounting);
+leaf-lock updates);
 off degrades every hook to one knob check.
 """
 

@@ -22,8 +22,7 @@ Surfaced as ``GET /history`` + ``/history.json`` on obs/httpd.py and the
 ``history`` console verb (:func:`render_history`). The sampler is a daemon
 thread (:func:`maybe_start_tsdb`, idempotent per process) gated on the
 ``enable_tsdb`` knob; one snapshot every ``tsdb_interval_s`` seconds is
-far off any hot path (the overhead guard rides BENCH_SERVE.json
-``detail.observatory``). Tests drive :meth:`sample_once` directly for
+far off any hot path. Tests drive :meth:`sample_once` directly for
 deterministic trend windows.
 """
 

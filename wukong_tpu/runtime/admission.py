@@ -50,8 +50,7 @@ event per tenant+cause per second, never a storm).
 
 Default OFF (``enable_admission``): every hook degrades to one knob
 check and the serving path is byte-unchanged (the ``migration_enable``
-actuator posture; BENCH_SERVE.json ``detail.overhead_guard`` pins the
-on/off p50 bands overlapping).
+actuator posture).
 """
 
 from __future__ import annotations

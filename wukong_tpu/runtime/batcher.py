@@ -123,8 +123,7 @@ _M_HEAVY_OCC = get_registry().histogram(
     "wukong_batch_heavy_occupancy", "Heavy group size at flush",
     buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024))
 # split-vs-no-split decisions per fused heavy dispatch: the observable
-# behind heavy_split_threshold tuning (bench.py --serve-mixed prints the
-# counts so the threshold can be re-tuned against real worlds)
+# behind heavy_split_threshold tuning
 _M_HEAVY_SPLIT = get_registry().counter(
     "wukong_batch_heavy_split_total",
     "Fused heavy dispatch split decisions", labels=("decision",))

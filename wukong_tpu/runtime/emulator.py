@@ -475,15 +475,14 @@ class Emulator:
         submit one query TEXT at a time through the proxy serving entry
         (parse cache -> plan cache -> batcher-or-direct -> engine) and
         wait for the reply — live traffic, not the compiled-batch emulator
-        path. Batching behavior follows ``Global.enable_batching``; the
-        before/after pair of this number is `bench.py --serve-batched`'s
-        headline. Starts the periodic metrics snapshotter when the
+        path. Batching behavior follows ``Global.enable_batching``.
+        Starts the periodic metrics snapshotter when the
         ``metrics_snapshot_s`` knob asks for one (long-soak observability).
 
         ``weights`` (aligned with ``texts``) draws a weighted mix instead
         of uniform; ``classes`` (aligned ints, e.g. 0=light 1=heavy) adds
-        a per-class qps/latency breakdown to the result — the mixed
-        light+heavy benchmark's surface (`bench.py --serve-mixed`).
+        a per-class qps/latency breakdown to the result (a mixed
+        light+heavy queue).
         """
         import threading
 
@@ -491,8 +490,7 @@ class Emulator:
         # batch lane when a pool is already running (stream/emulator
         # mixes) and dispatch inline on the batcher's flusher thread
         # otherwise. Since the idle relax deepened to a 20ms-capped
-        # exponential backoff (scheduler.IDLE_SNOOZE_MAX_US, ROADMAP
-        # follow-up i — before/after in BENCH_SERVE.json idle_backoff), a
+        # exponential backoff (scheduler.IDLE_SNOOZE_MAX_US), a
         # co-located idle pool no longer starves the fused dispatches, so
         # callers that keep the pool started are fine too.
         snap = maybe_start_snapshotter()
@@ -579,8 +577,7 @@ class Emulator:
         popular anchor — the retrieval-augmented access pattern, where a
         few hot entities anchor most similarity lookups, so the result
         cache and knn route memos see realistic skew instead of uniform
-        mush. Returns overall + per-kind q/s and latency percentiles
-        (`bench.py --graphrag`'s hybrid_qps headline)."""
+        mush. Returns overall + per-kind q/s and latency percentiles."""
         import threading
 
         snap = maybe_start_snapshotter()
@@ -740,7 +737,7 @@ class Emulator:
     def run_rebalance(self, n_ops: int = 1500, zipf_a: float = 1.6,
                       seed: int = 0, sstore=None) -> dict:
         """The hot-spot drill flipped from observe-only to EXECUTED
-        (``bench.py --rebalance``; ROADMAP item 3's elastic acceptance):
+        (tests/test_migration.py drives it):
         run :meth:`run_hotspot` to produce the Zipfian skew and the
         advisor's ``MigrationPlan``, then drive the plan through the live
         shard-migration actuator (``runtime/migration.py`` —

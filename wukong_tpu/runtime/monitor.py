@@ -200,7 +200,7 @@ class Monitor:
         return _cdf(self.stream.lag_us, points)
 
     def stream_stats(self) -> dict:
-        """Aggregate streaming view (bench_stream.py's artifact source)."""
+        """Aggregate streaming view."""
         return {
             "epochs": self.stream.epochs,
             "triples": self.stream.triples,

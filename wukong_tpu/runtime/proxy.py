@@ -921,8 +921,7 @@ class Proxy:
         memoized ``auto`` strategy to the walk when wcoj did NOT deliver
         its premise — intermediates bounded near the fragment. ``auto``
         routes wcoj on the ESTIMATED walk blowup, which over-predicts on
-        the small WatDiv cyclic shapes (BENCH_CYCLIC.json
-        ``auto_strategies`` lose 2-3x to the walk there): when the join's
+        the small WatDiv cyclic shapes: when the join's
         own materialized rows still blow past ``wcoj_ratio`` x final, it
         is doing walk-like materialization PLUS per-level intersection
         overhead, and the walk's simpler kernels win. Measured on the

@@ -136,9 +136,7 @@ class EnginePool:
     # permit and wakes one sleeper immediately), so a deep cap costs
     # nothing in pickup latency on the submit path; it only bounds the
     # poll cadence for work that arrives via stealing races (an item
-    # stranded in a busy non-neighbor's queue). Measured at 20ms: idle
-    # burn ~100% -> ~11% of a core, co-located p50 restored to ~baseline
-    # (BENCH_SERVE.json idle_backoff).
+    # stranded in a busy non-neighbor's queue).
     IDLE_SNOOZE_MIN_US = 10
     IDLE_SNOOZE_MAX_US = 20000
 
@@ -687,8 +685,7 @@ class EnginePool:
                 # submitted, so deep relax costs no submit-path latency;
                 # the doubling only thins the *poll* cadence (10us ->
                 # IDLE_SNOOZE_MAX_US) so an idle pool no longer starves
-                # co-located fused dispatches (ROADMAP follow-up i —
-                # before/after in BENCH_SERVE.json idle_backoff)
+                # co-located fused dispatches
                 got = self._pending.acquire(timeout=snooze_us / 1e6)
                 snooze_us = (self.IDLE_SNOOZE_MIN_US if got
                              else min(snooze_us * 2, self.IDLE_SNOOZE_MAX_US))

@@ -14,3 +14,4 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
 # (its scripts/sparql_query/): queries/lubm/basic/lubm_q1 ...
 QUERIES = os.path.join(REPO, "queries")
 LUBM_BASIC = os.path.join(QUERIES, "lubm", "basic")
+LUBM_EMULATOR = os.path.join(QUERIES, "lubm", "emulator")
