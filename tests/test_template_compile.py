@@ -20,6 +20,7 @@ from wukong_tpu.engine.cpu import CPUEngine
 from wukong_tpu.engine.template_compile import (
     TEMPLATE_ROUTES,
     TemplateCompiledEngine,
+    TemplateOverflow,
     choose_template_route,
     demotion_report,
     extract_template,
@@ -28,6 +29,7 @@ from wukong_tpu.engine.template_compile import (
     reset_demotions,
     route_why,
 )
+from wukong_tpu.join.kernels import capacity_class
 from wukong_tpu.loader.datagen import (
     CyclicStrings,
     cyclic_query_text,
@@ -970,3 +972,166 @@ def test_member_lists_of_any_length_run_one_program(light_proxy):
         Global.table_capacity_min = old
     (prog,) = eng._programs.values()
     assert prog.fn._cache_size() == 1
+
+
+# ---------------------------------------------------------------------------
+# capacity classes in eighths of an octave, never above the exact bound
+# (ISSUE 31)
+# ---------------------------------------------------------------------------
+
+class _Degrees:
+    """What ``_initial_caps`` asks of a store: each segment's longest edge
+    list, and the version its memo is kept under."""
+
+    version = 0
+
+    def __init__(self, by_pid: dict):
+        self.by_pid = by_pid
+
+    def max_degree(self, pid, _d) -> int:
+        return self.by_pid[pid]
+
+
+def _sized_engine(n0: int, degrees: tuple, monkeypatch):
+    """(engine, spec) of a chain that is nothing but sizes: an index start
+    of ``n0`` rows and one expansion a segment, the k-th over a segment
+    whose longest edge list is ``degrees[k]``."""
+    _triples, g, _meta = _tri_world()
+    eng = TemplateCompiledEngine(g)
+    eng.g = _Degrees({10 + k: d for k, d in enumerate(degrees)})
+    monkeypatch.setattr(eng, "_start_len", lambda spec: n0)
+    return eng, (("index", 1, IN),) + tuple(
+        ("expand", 10 + k, OUT, k) for k in range(len(degrees)))
+
+
+# name -> (start rows, each expansion's longest edge list, the planner's
+# estimate a step, the classes before, the classes now): PERF.md section 6
+INITIAL_CAPS = {
+    "watdiv_c3": (
+        400_120, (1, 1, 1, 1, 8, 4000),
+        (400_120, 400_120, 200_224, 120_152, 84_135, 94_467, 4_227_401),
+        (1 << 19, 1 << 20, 1 << 19, 1 << 18, 1 << 18, 1 << 18, 1 << 24),
+        (425_984, 425_984, 425_984, 245_760, 180_224, 196_608, 9_437_184)),
+    "lubm640_q7": (
+        110_020, (4, 25), (110_020, 330_294, 1_055_938),
+        (1 << 17, 1 << 20, 1 << 22), (114_688, 458_752, 2_359_296)),
+    "lubm640_q2": (
+        698_900, (1,), (698_900, 698_900),
+        (1 << 20, 1 << 21), (720_896, 720_896)),
+    "lubm160_q7": (
+        27_596, (4, 22), (27_596, 82_741, 264_310),
+        (1 << 15, 1 << 18, 1 << 20), (28_672, 114_688, 589_824)),
+    "lubm160_q2": (
+        174_455, (1,), (174_455, 174_455),
+        (1 << 18, 1 << 19), (180_224, 180_224)),
+    # light plans keep their classes: q6 (24 departments, 45 staff at
+    # most), q4 (a department's 36 professors through four columns)
+    "lubm640_q6": (24, (45,), (24, 540), (32, 2048), (32, 2048)),
+    "lubm640_q4": (36, (1, 1, 1), (36, 36, 36, 36), (64,) * 4, (64,) * 4),
+}
+
+
+@pytest.mark.parametrize("name", sorted(INITIAL_CAPS))
+def test_initial_caps_at_the_shapes_of_the_cells(name, monkeypatch):
+    """``_initial_caps`` from stubbed estimates at the shapes of the
+    benchmark's programs: twice the estimate, rounded up an eighth of an
+    octave, and never above the class of the exact bound. C3's last
+    expansion (estimated 4,227,401 rows, twice that 0.8 % past 2^23) runs
+    at 9 x 2^20 where it ran at 2^24; q2's one expansion over a segment of
+    out-degree 1 runs at the class of its 698,900 starts."""
+    n0, degrees, est_steps, before, now = INITIAL_CAPS[name]
+    eng, spec = _sized_engine(n0, degrees, monkeypatch)
+    caps = eng._initial_caps(name, spec, max(est_steps), list(est_steps))
+    assert caps == now
+    bound = eng._fill_bound(spec, n0)
+    for c, b, c0 in zip(caps, bound, before):
+        assert c <= capacity_class(b, floor=1) and c <= c0
+        assert c == capacity_class(c, floor=1)
+
+
+def test_initial_caps_without_the_planners_walk_round_the_same_way(
+        monkeypatch):
+    """No per-step estimates: four times the step before, at least the
+    estimated peak, each rounded up an eighth of an octave and held under
+    the exact bound."""
+    eng, spec = _sized_engine(9000, (3, 50), monkeypatch)
+    caps = eng._initial_caps("t", spec, 70_000, None)
+    # 9,000; the bound 9,000 x 3 under the peak's 73,728; 4 x 73,728
+    assert caps == (9216, 28_672, 294_912)
+
+
+Q_TAKES = PREFIX + """SELECT ?X ?Y ?Z WHERE {
+    ?X ub:takesCourse ?Y . ?Z ub:teacherOf ?Y . }"""
+
+
+def test_a_program_at_classes_that_are_no_power_of_two_equals_the_walk(
+        light_proxy):
+    """Index-origin chains at LUBM-1 whose classes are 15 x 1,024 and 10 x
+    4,096 rows: served through their programs they equal the CPU engine
+    row for row, in one attempt."""
+    proxy = light_proxy
+    _fresh(proxy)
+    texts = [Q_CHAIN, Q_TAKES]
+    odd = 0
+    for text in texts:
+        qc = proxy.serve_query(text, blind=False)
+        assert qc.template_route == "device" and qc._template_compiled
+        assert qc._template_attempts == 1
+        caps = qc._template_caps
+        assert all(c == capacity_class(c, floor=1) for c in caps)
+        odd += sum(1 for c in caps if c & (c - 1))
+        assert qc.result.nrows <= caps[-1] < 2.25 * qc.result.nrows + 1024
+        qh = proxy.serve_query(text, blind=False, device="cpu")
+        assert not getattr(qh, "_template_compiled", False)
+        assert_identical(qh, qc)
+    assert odd >= 2
+    assert demotion_report() == {}
+
+
+def test_an_overflow_regrows_to_twice_the_class_at_least(light_proxy):
+    """A class that is no power of two, set too small on purpose (9 x
+    1,024 rows for a reply of some 20,000): the program overflows, the
+    class regrows to a class of at least twice the rows and at least the
+    measured total, the reply equals the CPU engine's, and the classes
+    that fit are remembered."""
+    proxy = light_proxy
+    _fresh(proxy)
+    eng = proxy.template_engine()
+    q0 = proxy._prepare(Q_TAKES, None, False, None, "default")
+    first = q0._template_plan_caps
+    small = 9 * 1024
+    with eng._lock:
+        eng._good_caps[(q0._tsig, eng._version())] = first[:-1] + (small,)
+    qc = proxy.serve_query(Q_TAKES, blind=False)
+    assert qc._template_compiled and qc._template_attempts == 2
+    assert qc.result.nrows > small
+    grown = qc._template_caps
+    assert grown[:-1] == first[:-1]
+    assert grown[-1] >= max(2 * small, qc.result.nrows)
+    assert grown[-1] == capacity_class(grown[-1], floor=1)
+    assert eng._good_caps[(qc._tsig, eng._version())] == grown
+    qh = proxy.serve_query(Q_TAKES, blind=False, device="cpu")
+    assert_identical(qh, qc)
+    # remembered: the next reply runs once, at the grown classes
+    q2 = proxy.serve_query(Q_TAKES, blind=False)
+    assert q2._template_attempts == 1 and q2._template_caps == grown
+
+
+@pytest.mark.parametrize("caps,total,want", [
+    ((9216, 9216), 15_000, (9216, 18_432)),       # twice the class
+    ((9216, 9216), 40_000, (9216, 40_960)),       # the total's class
+    ((9216, 9216, 10_240), 15_000, (9216, 18_432, 18_432)),  # steps after
+    ((1024, 18 << 20), 20 << 20, (1024, 32 << 20)),  # up to the cap
+    ((1024, 1024), 0, (1024, 4096)),              # a total that wrapped
+])
+def test_grow_caps_keeps_its_room(caps, total, want):
+    totals = np.zeros(len(caps) - 1, dtype=np.int64)
+    ovfs = np.zeros(len(caps) - 1, dtype=bool)
+    totals[0], ovfs[0] = total, True  # the first expansion overflowed
+    assert TemplateCompiledEngine._grow_caps(caps, totals, ovfs) == want
+
+
+def test_grow_caps_past_the_cap_is_an_overflow():
+    with pytest.raises(TemplateOverflow):
+        TemplateCompiledEngine._grow_caps(
+            (1024, 1 << 25), np.asarray([0]), np.asarray([True]))

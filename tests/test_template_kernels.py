@@ -5,7 +5,9 @@ over the id range where ``direct_lookup_wins`` says so and by the search
 elsewhere. Every output slot is compared, padding included: a
 validity-compacted table has to stay byte-identical to the host expansion,
 and the capacity regrowth reads ``total`` and ``overflow``. Runs on the CPU
-backend; the chip's numbers are in PERF.md.
+backend; the chip's numbers are in PERF.md. And the class those programs
+are sized by (ISSUE 31): ``capacity_class``, eighths of an octave from
+8,192 rows, with both kernels at classes that are no power of two.
 """
 
 import jax
@@ -15,11 +17,14 @@ import pytest
 
 from wukong_tpu.engine.template_compile import _build_program
 from wukong_tpu.join.kernels import (
+    CLASS_FINE_FROM,
+    capacity_class,
     direct_lookup_wins,
     expand_padded,
     expand_padded_device,
     lookup_ranges,
     lookup_ranges_device,
+    pad_pow2,
     pair_member,
 )
 
@@ -221,3 +226,116 @@ def test_a_light_shaped_program_keeps_the_search():
                            (1 << 17,) * 3, 1 << 16, 1 << 18)
     assert forms == [False, False, False]
     assert "stablehlo.while" in text
+
+
+# ---------------------------------------------------------------------------
+# the template programs' capacity class (ISSUE 31)
+# ---------------------------------------------------------------------------
+
+# sizes around every boundary of the rule, the sizes PERF.md names (C3's
+# last expansion, q7's, q2's start list), and the cap
+CLASS_SIZES = [0, 1, 2, 3, 1000, 1023, 1024, 1025, 4097, 8191, 8192, 8193,
+               9215, 9216, 9217, 16383, 16384, 16385, 18432, 18433, 110_020,
+               400_120, 698_900, 2_111_876, 8_454_803, (1 << 23) + 1,
+               (1 << 24) - 1, (1 << 25) - 1, 1 << 25]
+
+
+@pytest.mark.parametrize("n", CLASS_SIZES)
+def test_capacity_class_holds_n_in_eighths_of_an_octave(n):
+    c = capacity_class(n, floor=1)
+    assert c >= max(n, 1)
+    if n <= CLASS_FINE_FROM:
+        assert c == pad_pow2(n, floor=1)  # a power of two, as before
+    else:
+        assert c % 1024 == 0
+        assert c - n < n / 8  # at most 12.5 % over, where it was 100 %
+        lower = pad_pow2(n, floor=1) // 2  # 2^k < n <= 2^(k+1)
+        assert c % (lower // 8) == 0 and lower < c <= 2 * lower
+        assert c <= pad_pow2(n, floor=1)
+    assert capacity_class(c, floor=1) == c  # a class is its own class
+    assert capacity_class(2 * c, floor=1) == 2 * c  # and so is its double
+
+
+@pytest.mark.parametrize("k", range(0, 26))
+def test_capacity_class_equals_pad_pow2_at_powers_of_two(k):
+    assert capacity_class(1 << k, floor=1) == 1 << k == pad_pow2(1 << k, 1)
+    assert capacity_class(1 << k) == pad_pow2(1 << k)  # the default floor
+
+
+@pytest.mark.parametrize("lo,hi", [(1, 3000), (8000, 9300), (16000, 20600),
+                                   ((1 << 20) - 40, (1 << 20) + 300_000)])
+def test_capacity_class_is_monotone(lo, hi):
+    step = max((hi - lo) // 3000, 1)
+    classes = [capacity_class(n, floor=1) for n in range(lo, hi, step)]
+    assert classes == sorted(classes)
+    if hi > CLASS_FINE_FROM:
+        assert len(set(classes)) > 2
+
+
+@pytest.mark.parametrize("n,floor,cap_max,want", [
+    (5, 1024, None, 1024),               # the floor, as pad_pow2's
+    (5, 64, None, 64),
+    (9000, 16384, None, 16384),
+    (9000, 10_000, None, 10_240),        # a floor is rounded up as a size is
+    (8_454_803, 1024, 1 << 25, 9 << 20),  # C3's last expansion at WatDiv
+    (8_454_803, 1024, 9_000_000, 9_000_000),  # never above the cap
+    ((1 << 25) + 1, 1024, 1 << 25, 1 << 25),
+    ((1 << 25) + 1, 1024, None, 36 << 20),
+    (100, 1024, 512, 512),
+])
+def test_capacity_class_floor_and_cap(n, floor, cap_max, want):
+    assert capacity_class(n, floor, cap_max) == want
+
+
+# (rows in, class out): no power of two, 9 x 1,024 and 11 x 2,048
+ODD_CLASSES = [(700, 9 * 1024), (2816, 11 * 2048)]
+
+
+@pytest.mark.parametrize("fill", ["under", "exact", "over"])
+@pytest.mark.parametrize("n,cap", ODD_CLASSES)
+def test_expand_device_equals_numpy_at_a_class_that_is_no_power_of_two(
+        n, cap, fill):
+    """Every slot of all five outputs at an ``out_cap`` of 9,216 and of
+    22,528 rows, the total under it, on it and over it: a class is only
+    padding, whatever its size."""
+    assert capacity_class(cap, floor=1) == cap and cap & (cap - 1)
+    rng = np.random.default_rng(cap)
+    deg = _i32(rng.integers(0, 2 * (cap // n) - 1, n))
+    want_total = {"under": int(deg.sum()), "exact": cap,
+                  "over": cap + 17}[fill]
+    deg[-1] += want_total - int(deg.sum())
+    assert deg[-1] >= 0 and (fill != "under" or want_total < cap)
+    edges = _i32(rng.integers(0, 1 << 20, 1 << 16))
+    start = _i32(rng.integers(0, len(edges) - int(deg.max()), n))
+    want = expand_padded(start, deg, edges, cap)
+    got = jax.jit(expand_padded_device, static_argnums=3)(
+        jnp.asarray(start), jnp.asarray(deg), jnp.asarray(edges), cap)
+    for w, g, what in zip(want, got, ("row", "values", "valid", "total",
+                                      "overflow")):
+        assert np.array_equal(np.asarray(w), np.asarray(g)), what
+    assert np.asarray(got[0]).shape == (cap,)
+    assert int(got[3]) == want_total and bool(got[4]) == (fill == "over")
+
+
+@pytest.mark.parametrize("form", ["direct", "search"])
+@pytest.mark.parametrize("rows", [c for _n, c in ODD_CLASSES])
+def test_lookup_device_equals_numpy_at_a_class_that_is_no_power_of_two(
+        rows, form):
+    """(start, degree) of every row of a frontier of 9,216 and of 22,528
+    rows, by the table and by the search."""
+    rng = np.random.default_rng(rows)
+    nkeys = 3000 if form == "direct" else 1 << 21
+    keys = np.unique(rng.integers(100, 8 * nkeys, nkeys)).astype(np.int32)
+    bound = int(keys[-1]) + 1
+    assert direct_lookup_wins(rows, len(keys), bound) == (form == "direct")
+    offsets = _i32(np.concatenate(
+        [[0], np.cumsum(rng.integers(0, 6, len(keys)))]))
+    vids = _i32(rng.integers(0, bound + 100, rows))
+    hit = rng.random(rows) < 0.5
+    vids[hit] = keys[rng.integers(0, len(keys), int(hit.sum()))]
+    want = lookup_ranges(keys, offsets, vids)
+    got = jax.jit(lookup_ranges_device, static_argnums=3)(
+        jnp.asarray(keys), jnp.asarray(offsets), jnp.asarray(vids), bound)
+    assert np.asarray(want[1]).any()
+    assert np.array_equal(np.asarray(want[0]), np.asarray(got[0]))
+    assert np.array_equal(np.asarray(want[1]), np.asarray(got[1]))

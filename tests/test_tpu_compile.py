@@ -153,3 +153,53 @@ def test_template_program_compiles(one_chip):
     # the graph's last id, which 2^25 bounds at LUBM-640
     fn, _forms = _build_program(spec, caps, (), (1 << 25,) * 2, (0, 1, 2))
     _compile(fn.lower(i(caps[0]), i(), *args), "template two-hop", False)
+
+
+# C3's last expansion at WatDiv scale factor 1000 as shapes: a frontier of
+# 262,144 rows and six columns, friendOf's 400,120 subjects and 44,750,179
+# edges, 14.1 M vertex ids (PERF.md section 5)
+C3_ROWS_IN, C3_COLS_IN = 1 << 18, 6
+FRIEND_KEYS, FRIEND_EDGES, ID_BOUND = 400_120, 44_750_179, 14_100_000
+
+
+def _c3_last_expansion(one_chip, cap_out: int):
+    """The tail of C3's program, as ``_build_program`` traces it: the key
+    lookup, the expansion to ``cap_out`` rows, every column carried through
+    it and the reply's table stacked."""
+    from wukong_tpu.join.kernels import (
+        direct_lookup_wins,
+        expand_padded_device,
+        lookup_ranges_device,
+    )
+
+    assert direct_lookup_wins(C3_ROWS_IN, FRIEND_KEYS, ID_BOUND)
+
+    def tail(cols, valid, keys, offsets, edges):
+        start, deg = lookup_ranges_device(keys, offsets, cols[0], ID_BOUND)
+        deg = jnp.where(valid, deg, 0)
+        rowc, newv, valid, total, ovf = expand_padded_device(
+            start, deg, edges, cap_out)
+        table = jnp.stack([c[rowc] for c in cols] + [newv], axis=1)
+        return table, valid, total, ovf
+
+    i = partial(_i32, one_chip)
+    live = jax.ShapeDtypeStruct((C3_ROWS_IN,), jnp.bool_, sharding=one_chip)
+    return _compile(jax.jit(tail).lower(
+        [i(C3_ROWS_IN)] * C3_COLS_IN, live, i(FRIEND_KEYS),
+        i(FRIEND_KEYS + 1), i(FRIEND_EDGES)),
+        f"C3 last expansion[{cap_out:,}]", False)
+
+
+def test_c3_last_expansion_compiles_smaller_at_its_finer_class(one_chip):
+    """At 9 x 2^20 rows (the class of twice the planner's 4,227,401) and
+    at 2^24 (the power of two above it): both compile, and the finer
+    class's temporaries and reply are smaller as the classes are."""
+    from wukong_tpu.join.kernels import capacity_class, pad_pow2
+
+    fine, coarse = capacity_class(2 * 4_227_401), pad_pow2(2 * 4_227_401)
+    assert (fine, coarse) == (9 << 20, 1 << 24)
+    mem = {cap: _c3_last_expansion(one_chip, cap).memory_analysis()
+           for cap in (fine, coarse)}
+    for what in ("output_size_in_bytes", "temp_size_in_bytes"):
+        small, large = getattr(mem[fine], what), getattr(mem[coarse], what)
+        assert 0 < small < 0.6 * large, what  # the classes: 0.5625

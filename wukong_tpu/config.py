@@ -528,9 +528,9 @@ class GlobalConfig:
     # host it makes no calls, and only the estimate routes to a program.
     template_min_rows: int = 4096
     # capacity-overflow retries: a compiled run whose padded table
-    # overflows regrows its capacity classes (pad_pow2 of the measured
-    # totals) and re-dispatches at most this many times before
-    # degrading to the host walk
+    # overflows regrows its capacity classes (the class of the measured
+    # total, at least twice the one that overflowed) and re-dispatches at
+    # most this many times before degrading to the host walk
     template_capacity_retries: int = 3
     # byte budget for what cached compiled-template programs keep staged
     # on the device (their start lists; a run's result buffer is not

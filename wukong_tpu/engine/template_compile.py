@@ -3,8 +3,12 @@
 Compile the template, not the step: instead of N host↔device round trips
 (one per BGP step), an eligible walk-strategy plan is fused — expand +
 intersect + filter + projection — into ONE jitted XLA program over
-padded CSR tensors (the pad_pow2 capacity-class posture from the WCOJ
-level probe). TrieJax runs the whole LFTJ dataflow as one pipelined
+padded CSR tensors (the capacity-class posture of the WCOJ level probe,
+in finer classes: a program's classes are its own, ``_program_key`` holds
+them, so they step in eighths of an octave, ``capacity_class``, and lie
+never above the class of the most rows the data can put there; the probe
+and the walk, whose kernels queries share by class, keep ``pad_pow2``).
+TrieJax runs the whole LFTJ dataflow as one pipelined
 hardware graph; "Column-Oriented Datalog on the GPU" shows eager
 device-resident buffers paying off exactly when iteration state never
 leaves the device — this module is the walk engine's equivalent.
@@ -53,6 +57,7 @@ from wukong_tpu.analysis.lockdep import declare_leaf, make_lock
 from wukong_tpu.config import Global
 from wukong_tpu.join.kernels import (
     DeviceRangeError,
+    capacity_class,
     direct_lookup_wins,
     expand_padded_device,
     lookup_ranges_device,
@@ -649,17 +654,25 @@ class TemplateCompiledEngine:
     def _initial_caps(self, tsig, spec, est_rows: int | None,
                       est_steps: list | None = None) -> tuple:
         """The classes a first attempt runs at: where the planner walked
-        the chain, each expansion's own estimate with one class of room (a
-        start from a constant scaled to the heaviest constant, as its start
-        list is), and never under its segment's longest edge list, with the
-        steps after lifted by the same factor (one row of the frontier may
-        be the heaviest key: ``TPUEngine._estimate_rows`` sizes the walk
-        so), so that a template's classes do not follow the draw; else four
-        times the step before, at least the peak. The floor
-        (``table_capacity_min``) is room for a small estimate's error:
-        where the data cannot fill it (``_fill_bound``) the class is the
-        bound's own, so a light plan's classes are a few dozen rows and its
-        program pushes no thousand padded rows through every lookup."""
+        the chain, each expansion's own estimate with one class of room,
+        that is twice the estimate (a start from a constant scaled to the
+        heaviest constant, as its start list is), and never under its
+        segment's longest edge list, with the steps after lifted by the
+        same factor (one row of the frontier may be the heaviest key:
+        ``TPUEngine._estimate_rows`` sizes the walk so), so that a
+        template's classes do not follow the draw; else four times the step
+        before, at least the peak. A size is rounded up by
+        ``capacity_class`` (eighths of an octave from 8,192 rows), so the
+        rounding adds at most an eighth to the room, where a power of two
+        made anything between two and four times the estimate of it (C3 of
+        WatDiv, estimated 4,227,401 rows, ran at 2^24). And a class is
+        never above the class of the most rows the data can put there
+        (``_fill_bound``, exact): such a class cannot overflow, so neither
+        the room nor the floor (``table_capacity_min``, room for a small
+        estimate's error) is added to it. A light plan's classes are a few
+        dozen rows, and an expansion over a segment of out-degree 1 runs at
+        the class of its start list."""
+        cap_max = int(Global.table_capacity_max)
         version = self._version()
         with self._lock:
             good = self._good_caps.get((tsig, version))
@@ -668,8 +681,8 @@ class TemplateCompiledEngine:
         if good is not None:
             if good[0] >= n0:
                 return good
-            return (pad_pow2(n0, floor=floor),) + tuple(good[1:])
-        caps = [pad_pow2(n0, floor=floor)]
+            return (capacity_class(n0, floor, cap_max),) + tuple(good[1:])
+        caps = [capacity_class(n0, floor, cap_max)]
         walked = est_steps is not None and len(est_steps) == len(spec)
         scale = max(n0 / max(float(est_steps[0]), 1.0), 1.0) if walked else 1.0
         for k, op in enumerate(spec):
@@ -683,11 +696,10 @@ class TemplateCompiledEngine:
                 else:
                     guess = caps[-1] * 4
                     if est_rows:
-                        guess = max(guess, pad_pow2(est_rows, floor=floor))
-                caps.append(min(pad_pow2(int(guess), floor=floor),
-                                int(Global.table_capacity_max)))
-        tight = [pad_pow2(b, floor=1) for b in self._fill_bound(spec, n0)]
-        return tuple(t if t < floor else c for c, t in zip(caps, tight))
+                        guess = max(guess, est_rows)
+                caps.append(capacity_class(int(guess), floor, cap_max))
+        return tuple(min(c, capacity_class(b, 1, cap_max))
+                     for c, b in zip(caps, self._fill_bound(spec, n0)))
 
     def _fill_bound(self, spec, n0: int) -> list:
         """The most rows each class can ever hold: the start list's length
@@ -710,7 +722,10 @@ class TemplateCompiledEngine:
         t = int(totals[k])
         cap_max = int(Global.table_capacity_max)
         if 0 < t <= cap_max:
-            caps[k + 1] = max(pad_pow2(t), caps[k + 1] * 2)
+            # twice the class that overflowed at least (a class doubled is
+            # a class), up to the cap, which holds ``t``
+            caps[k + 1] = min(max(capacity_class(t), caps[k + 1] * 2),
+                              cap_max)
         else:
             caps[k + 1] = caps[k + 1] * 4
         for j in range(k + 2, len(caps)):

@@ -165,9 +165,40 @@ def pad_pow2(n: int, floor: int = PAD_FLOOR) -> int:
     """The device path's capacity class: smallest power of two >=
     max(n, floor). Candidate tensors are padded to it so the jitted level
     probe compiles a bounded set of shape variants instead of one per
-    level size (the engine's table-capacity-class discipline)."""
+    level size (the engine's table-capacity-class discipline). Kernels
+    that queries share by class keep it; a whole-plan template program,
+    whose classes are its own, takes :func:`capacity_class`."""
     c = max(int(n), int(floor), 1)
     return 1 << (c - 1).bit_length()
+
+
+#: the size from which :func:`capacity_class` steps in eighths of an
+#: octave: a step under 1,024 rows would be finer than the chip's tile
+CLASS_FINE_FROM = 8192
+
+
+def capacity_class(n: int, floor: int = PAD_FLOOR,
+                   cap_max: int | None = None) -> int:
+    """A whole-plan template program's capacity class (the classes of
+    ``engine/template_compile.py`` only). Up to 8,192 rows it is
+    :func:`pad_pow2`; above, the smallest ``2^k * (1 + j/8)``, j = 1..8,
+    that holds ``n``, where ``2^k < n``: a multiple of 1,024 at most 12.5
+    % over ``n``, where the power of two is up to 100 % over it and every
+    gather and scan of the program is linear in the class. Never above
+    ``cap_max`` (``table_capacity_max``) where one is given.
+
+    Finer classes cost a template program no compile: its classes are
+    private to it (``_program_key`` holds them). The level probe, the
+    walk and the stream kernels are shared between queries by class, and
+    there the power of two bounds the set of compiles: they keep
+    :func:`pad_pow2`."""
+    c = max(int(n), int(floor), 1)
+    if c <= CLASS_FINE_FROM:
+        c = pad_pow2(c, floor=1)
+    else:
+        step = (1 << ((c - 1).bit_length() - 1)) >> 3
+        c = -(-c // step) * step
+    return c if cap_max is None else min(c, int(cap_max))
 
 
 class DeviceRangeError(ValueError):
