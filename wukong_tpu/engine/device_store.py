@@ -570,25 +570,29 @@ class DeviceStore:
         trace_event("device.stage", segment=str(key), bytes=seg.nbytes)
         self._enforce_budget()
 
-    def _enforce_budget(self) -> None:
+    def _enforce_budget(self, why: str = "budget") -> None:
         if self.budget is not None:
             while self.bytes_used > self.budget and self._evictable():
-                victim = self._evictable()[0]
-                self._evict(victim)
+                self._evict(self._evictable()[0], why)
 
     def _evictable(self):
         return [k for k in self._lru if k not in self._pinned
                 and (k in self._cache or k in self._index_cache)]
 
-    def _evict(self, key) -> None:
+    def _evict(self, key, why: str) -> None:
         if key in self._cache:
             nb = self._cache.pop(key).nbytes
-            self.bytes_used -= nb
-            maybe_device_resident("evict", "segment", nb)
+            kind = "segment"
         else:
             dev, _ = self._index_cache.pop(key)
-            self.bytes_used -= dev.size * 4
-            maybe_device_resident("evict", "index", dev.size * 4)
+            nb = dev.size * 4
+            kind = "index"
+        self.bytes_used -= nb
+        maybe_device_resident("evict", kind, nb)
+        # traced, inside a request: the staging this one undoes said
+        # ``device.stage``; ``why`` is ``budget`` (room for a new staging)
+        # or ``unpin`` (a chain let go of what had kept the store over it)
+        trace_event("device.evict", segment=str(key), bytes=nb, why=why)
         self._lru.remove(key)
 
     def _touch(self, key) -> None:
@@ -608,7 +612,7 @@ class DeviceStore:
     def unpin(self, keys) -> None:
         for k in keys:
             self._pinned.discard(self._pin_key(k))
-        self._enforce_budget()  # pins may have deferred evictions
+        self._enforce_budget("unpin")  # pins may have deferred evictions
 
     def prefetch(self, patterns) -> None:
         """Stage the segments of upcoming pattern steps (async via dispatch)."""

@@ -29,6 +29,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from wukong_tpu.store.gstore import AttrColumns
 from wukong_tpu.types import NORMAL_ID_START, PREDICATE_ID, TYPE_ID
 
 UB = "http://swat.cse.lehigh.edu/onto/univ-bench.owl#"
@@ -519,8 +520,10 @@ def _bins_ub_arr(n: np.ndarray, bins: np.ndarray) -> np.ndarray:
     return (m + 6.0 * np.sqrt(np.maximum(m, 1.0)) + 16).astype(np.int64)
 
 
-def generate_lubm_attrs(n_univ: int, seed: int = 0) -> list[tuple]:
-    """Attribute triples (s, aid, type_tag, value).
+def lubm_attr_columns(n_univ: int, seed: int = 0) -> AttrColumns:
+    """Attribute triples as parallel int64 columns (subject, attr id, value):
+    what ``generate_lubm_attrs`` lists row by row.
+    At LUBM-2560 the rows are 75 M, some 10 GB of tuples and 2 GB of columns.
 
     - every undergraduate gets an int `age`
     - every named entity gets an int `id` = the digits of its name literal —
@@ -532,13 +535,12 @@ def generate_lubm_attrs(n_univ: int, seed: int = 0) -> list[tuple]:
     D = c.D
     dept_of_ug = np.repeat(np.arange(D), c.n_ug)
     ug_id = lay.ug_base[dept_of_ug] + _seg_local_index(c.n_ug)
-    ages = rng.integers(17, 24, len(ug_id))
-    out = [(int(v), A["age"], 1, int(a)) for v, a in zip(ug_id, ages)]
-
-    aid = A["id"]
+    subj = [ug_id]
+    vals = [rng.integers(17, 24, len(ug_id))]
 
     def add(ids, ks):
-        out.extend((int(v), aid, 1, int(k)) for v, k in zip(ids, ks))
+        subj.append(np.asarray(ids, dtype=np.int64))
+        vals.append(np.asarray(ks, dtype=np.int64))
 
     add(lay.univ_base + np.arange(n_univ), np.arange(n_univ))
     add(lay.dept_id, _dept_local(c))
@@ -553,7 +555,20 @@ def generate_lubm_attrs(n_univ: int, seed: int = 0) -> list[tuple]:
                         (lay.pub_base, c.n_pub)):
         dept_of = np.repeat(np.arange(D), sizes)
         add(base[dept_of] + _seg_local_index(sizes), _seg_local_index(sizes))
-    return out
+    aids = np.full(sum(len(a) for a in subj), A["id"], dtype=np.int64)
+    aids[:len(ug_id)] = A["age"]
+    return AttrColumns(np.concatenate(subj).astype(np.int64, copy=False),
+                       aids,
+                       np.concatenate(vals).astype(np.int64, copy=False),
+                       dict(ATTR_TYPE))
+
+
+def generate_lubm_attrs(n_univ: int, seed: int = 0) -> list[tuple]:
+    """Attribute triples (s, aid, type_tag, value), the rows of
+    ``lubm_attr_columns``."""
+    cols = lubm_attr_columns(n_univ, seed)
+    return [(s, a, cols.types[a], v) for s, a, v in
+            zip(cols.subject.tolist(), cols.aid.tolist(), cols.value.tolist())]
 
 
 def _sample_courses(rng, student_id, dept_of_student, base, seg_size, lo, hi):
@@ -745,6 +760,24 @@ class VirtualLubmStrings:
 # ---------------------------------------------------------------------------
 
 
+def write_string_tables(outdir: str, n_univ: int, seed: int = 0,
+                        **counts) -> dict:
+    """The small files of a dataset directory, all a ``StringServer`` needs
+    of a synthesized LUBM: ``str_index``, ``str_attr_index`` and, last, the
+    ``str_normal_virtual`` marker, whose meta is returned."""
+    os.makedirs(outdir, exist_ok=True)
+    with open(os.path.join(outdir, "str_index"), "w") as f:
+        for s, i in index_strings():
+            f.write(f"{s}\t{i}\n")
+    with open(os.path.join(outdir, "str_attr_index"), "w") as f:
+        for s, i, t in attr_index_strings():
+            f.write(f"{s}\t{i}\t{t}\n")
+    meta = {"generator": "lubm", "n_univ": n_univ, "seed": seed, **counts}
+    with open(os.path.join(outdir, "str_normal_virtual"), "w") as f:
+        json.dump(meta, f)
+    return meta
+
+
 def write_dataset(outdir: str, n_univ: int, seed: int = 0,
                   fmt: str = "npy", write_str_normal: bool = False) -> dict:
     """Write an id-format LUBM dataset directory.
@@ -771,20 +804,13 @@ def write_dataset(outdir: str, n_univ: int, seed: int = 0,
                     f.write("\n")
     else:
         np.save(os.path.join(outdir, "id_triples.npy"), triples)
-    with open(os.path.join(outdir, "str_index"), "w") as f:
-        for s, i in index_strings():
-            f.write(f"{s}\t{i}\n")
     attrs = generate_lubm_attrs(n_univ, seed)
     with open(os.path.join(outdir, "attr_uni0.nt"), "w") as f:
         for (sv, aid, t, val) in attrs:
             f.write(f"{sv}\t{aid}\t{t}\t{val}\n")
-    with open(os.path.join(outdir, "str_attr_index"), "w") as f:
-        for s, i, t in attr_index_strings():
-            f.write(f"{s}\t{i}\t{t}\n")
-    meta = {"generator": "lubm", "n_univ": n_univ, "seed": seed,
-            "num_triples": int(len(triples)), "num_attrs": len(attrs)}
-    with open(os.path.join(outdir, "str_normal_virtual"), "w") as f:
-        json.dump(meta, f)
+    meta = write_string_tables(outdir, n_univ, seed,
+                               num_triples=int(len(triples)),
+                               num_attrs=len(attrs))
     if write_str_normal:
         vs = VirtualLubmStrings(n_univ, seed)
         ids = np.unique(np.concatenate([triples[:, 0], triples[:, 2]]))

@@ -601,32 +601,30 @@ def main(argv=None):
         get_binder().load_core_binding(args.bind)
     from wukong_tpu.engine.cpu import CPUEngine
     from wukong_tpu.engine.tpu import TPUEngine
-    from wukong_tpu.loader.base import load_dataset
-    from wukong_tpu.store.string_server import StringServer
+    from wukong_tpu.loader.hdfs import resolve_dataset_dir
+    from wukong_tpu.runtime.boot import boot_store, dataset_source
     from wukong_tpu.runtime.proxy import Proxy
 
     import os as _os
 
-    from wukong_tpu.loader.base import load_attr_triples, load_triples
-    from wukong_tpu.store.gstore import build_partition
-
-    from wukong_tpu.loader.hdfs import resolve_dataset_dir
-
     args.dataset = resolve_dataset_dir(args.dataset)  # hdfs:// -> staged dir
-    ss = StringServer(args.dataset)
-    # one read of the triple files serves the partitions, the host fallback
-    # store, and stats generation
-    triples = load_triples(args.dataset)
-    attrs = load_attr_triples(args.dataset)
-    g = build_partition(triples, 0, 1, attrs)
+    # the store and the planner's statistics come from the bundle the first
+    # start over this dataset left in its directory; only that first start
+    # (and --dist, for its shards) reads the triple files
+    source = dataset_source(args.dataset)
+    booted = boot_store(source, args.dataset)
+    g, ss = booted.store, booted.str_server
     if args.dist:
         import jax
 
         from wukong_tpu.parallel.dist_engine import DistEngine
         from wukong_tpu.parallel.mesh import make_mesh
+        from wukong_tpu.store.gstore import build_partition
 
         n = args.workers or len(jax.devices())
+        triples, attrs = source.load()
         stores = [build_partition(triples, i, n, attrs) for i in range(n)]
+        del triples, attrs
         dist = DistEngine(stores, ss, make_mesh(n))
         proxy = Proxy(g, ss, CPUEngine(g, ss),
                       TPUEngine(g, ss) if Global.enable_tpu else None, dist)
@@ -635,14 +633,9 @@ def main(argv=None):
                       TPUEngine(g, ss) if Global.enable_tpu else None)
 
     if Global.enable_planner:
-        from wukong_tpu.planner.optimizer import make_planner
-
-        statfile = _os.path.join(args.dataset, "statfile")
-        proxy.planner = make_planner(
-            None if _os.path.exists(statfile + ".npz") else triples, statfile)
+        proxy.planner = booted.planner
         if proxy.tpu is not None:
             proxy.tpu.stats = proxy.planner.stats  # capacity estimation
-    del triples
 
     console = Console(proxy, stats_path=_os.path.join(args.dataset, "statfile"))
     if args.command is not None:

@@ -24,6 +24,7 @@ format redesigned for TPU staging (see segment.py). Semantics preserved:
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from typing import NamedTuple
 
 import numpy as np
 
@@ -43,6 +44,17 @@ class AttrSegment:
         if i < len(self.keys) and self.keys[i] == vid:
             return self.values[i], True
         return None, False
+
+
+class AttrColumns(NamedTuple):
+    """Attribute triples as parallel columns, for a generator that makes
+    them so: tens of millions of (s, aid, type, value) rows are GBs of
+    tuples and a minute of Python in ``build_partition``."""
+
+    subject: np.ndarray
+    aid: np.ndarray
+    value: np.ndarray
+    types: dict  # attribute id -> AttrType tag of its values
 
 
 @dataclass
@@ -192,7 +204,8 @@ def _pred_runs(p_sorted: np.ndarray, k_sorted: np.ndarray, v_sorted: np.ndarray)
 def build_partition(triples: np.ndarray, sid: int, num_workers: int,
                     attr_triples=None, versatile: bool = True,
                     check_ids: bool = True) -> GStore:
-    """Build worker `sid`'s GStore from the full [M,3] triple array.
+    """Build worker `sid`'s GStore from the full [M,3] triple array and the
+    attribute triples, (s, aid, type, value) rows or ``AttrColumns``.
 
     The reference reaches the same state via the loader's RDMA shuffle + sorted
     insert (base_loader.hpp:165-219, static_gstore.hpp:383-454); here partition
@@ -259,7 +272,21 @@ def build_partition(triples: np.ndarray, sid: int, num_workers: int,
         g.p_set = np.union1d(p_out, pi)
 
     # ---- attributes ------------------------------------------------------
-    if attr_triples:
+    if isinstance(attr_triples, AttrColumns):
+        mine = hash_mod(attr_triples.subject, num_workers) == sid
+        ks, aids, vs = (attr_triples.subject[mine], attr_triples.aid[mine],
+                        attr_triples.value[mine])
+        del mine
+        order = np.lexsort((vs, ks, aids))
+        ks, aids, vs = ks[order], aids[order], vs[order]
+        del order
+        for aid, k, v in _pred_runs(aids, ks, vs):
+            at = attr_triples.types[aid]
+            g.attrs[aid] = AttrSegment(
+                keys=k.astype(np.int64),  # copies: a run is a view of all
+                values=v.astype(np.float64 if at in (2, 3) else np.int64),
+                type=at)
+    elif attr_triples:
         by_aid: dict[int, list] = {}
         for (asub, aid, at, av) in attr_triples:
             if hash_mod(asub, num_workers) == sid:

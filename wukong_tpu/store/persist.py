@@ -41,8 +41,11 @@ FORMAT_VERSION = (2, 1)  # (major, minor): newer-major bundles are refused
 # graph arrays and 2.0 bundles load here (no vstore attached)
 
 
-def _crc(arr: np.ndarray) -> int:
-    return zlib.crc32(np.ascontiguousarray(arr).tobytes())
+def _crc(arr: np.ndarray, crc: int = 0) -> int:
+    # over the array's own buffer: ``tobytes`` would copy it first, GBs at
+    # a time for the larger segments of a bundle
+    return zlib.crc32(np.ascontiguousarray(arr).reshape(-1).view(np.uint8),
+                      crc)
 
 
 def _collect_arrays(g: GStore) -> tuple[dict, dict]:
@@ -94,14 +97,18 @@ def gstore_digest(g: GStore) -> int:
     crc = 0
     _, arrays = _collect_arrays(g)
     for name in sorted(arrays):
-        crc = zlib.crc32(np.ascontiguousarray(arrays[name]).tobytes(), crc)
+        crc = _crc(arrays[name], crc)
     return crc
 
 
-def save_gstore(g: GStore, path) -> None:
+def save_gstore(g: GStore, path, key: dict | None = None) -> None:
     """Persist a partition to ``path`` (a filename or any file object —
-    the transport's wire codec saves into a BytesIO)."""
+    the transport's wire codec saves into a BytesIO). ``key`` names what
+    the partition was built from (``runtime/boot.py``); ``bundle_key``
+    reads it back without reading an array."""
     meta, arrays = _collect_arrays(g)
+    if key is not None:
+        meta["key"] = key
     meta["checksums"] = {name: _crc(a) for name, a in arrays.items()}
     arrays["_meta"] = np.frombuffer(json.dumps(meta).encode(), dtype=np.uint8)
     np.savez(path, **arrays)
@@ -133,7 +140,7 @@ class _Checked:
         return arr
 
 
-def load_gstore(path: str) -> GStore:
+def _open_bundle(path: str):
     path = path if path.endswith(".npz") else path + ".npz"
     try:
         z = np.load(path)
@@ -144,7 +151,18 @@ def load_gstore(path: str) -> GStore:
             json.JSONDecodeError) as e:
         raise CheckpointCorrupt(f"unreadable bundle: {e}",
                                 path=path) from None
-    return _decode_bundle(z, meta, path)
+    return z, meta, path
+
+
+def load_gstore(path: str) -> GStore:
+    return _decode_bundle(*_open_bundle(path))
+
+
+def bundle_key(path: str) -> dict | None:
+    """The ``key`` a bundle was saved under, from its ``_meta`` alone."""
+    z, meta, _ = _open_bundle(path)
+    z.close()
+    return meta.get("key")
 
 
 def _decode_bundle(z, meta: dict, path: str) -> GStore:
