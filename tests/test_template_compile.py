@@ -472,6 +472,12 @@ def test_store_version_invalidation_via_dynamic_insert(tri_proxy,
     Global.template_device = "device"
     base = proxy.run_single_query(text, blind=False)
     assert base._template_compiled
+    # the first sound run settled the template's classes; the second builds
+    # the program that stays, and the guessed one goes then (ISSUE 33)
+    eng = proxy.template_engine()
+    assert proxy.run_single_query(text, blind=False)._template_compiled
+    assert eng.program_count() == 1
+    assert {k[1] for k in eng._programs} == {eng._version()}
     a, b, c = (NORMAL_ID_START + 7001, NORMAL_ID_START + 7002,
                NORMAL_ID_START + 7003)
     insert_triples(proxy.g, np.asarray(
@@ -481,13 +487,15 @@ def test_store_version_invalidation_via_dynamic_insert(tri_proxy,
     rows = set(map(tuple, q.result.table.tolist()))
     base_rows = set(map(tuple, base.result.table.tolist()))
     assert rows - base_rows == {(a, b, c)}
+    q2 = proxy.run_single_query(text, blind=False)  # at the settled classes
+    assert q2._template_compiled
     Global.template_device = "host"
     qw = proxy.run_single_query(text, blind=False)
     assert_identical(qw, q)
+    assert_identical(qw, q2)
     # every cached program is keyed at the post-insert version
-    eng = proxy.template_engine()
     version = int(proxy.g.version)
-    assert eng.program_count() >= 1
+    assert eng.program_count() == 1
     assert all(k[1] == version for k in eng._programs)
 
 
@@ -567,11 +575,17 @@ def test_budget_eviction_under_template_budget_mb(monkeypatch):
     a = int(triples[triples[:, 1] == 2][0, 0])
     eng = TemplateCompiledEngine(g)
     # what stays on the device with a program is its start list: both
-    # templates settled, as if by an earlier store, at 2^18 rows (1 MB)
-    for pats in ([(a, 2, OUT, -1), (-1, 3, OUT, -2)],
-                 [(2, PREDICATE_ID, IN, -1), (-1, 2, OUT, -2)]):
+    # templates settled, as if by an earlier store, at 2^18 rows (1 MB);
+    # the index-origin one expands at the class of its total, where a
+    # program that no draw can change stays (ISSUE 33)
+    qw = handq([(2, PREDICATE_ID, IN, -1), (-1, 2, OUT, -2)], [-1, -2])
+    CPUEngine(g).execute(qw)
+    for pats, cap in (
+            ([(a, 2, OUT, -1), (-1, 3, OUT, -2)], 1 << 10),
+            ([(2, PREDICATE_ID, IN, -1), (-1, 2, OUT, -2)],
+             capacity_class(qw.result.nrows, floor=1))):
         spec = extract_template(handq(pats, [-1, -2]))[0]
-        eng._good_caps[(spec, eng._version())] = (1 << 18, 1 << 10)
+        eng._good_caps[(spec, eng._version())] = (1 << 18, cap)
     q1 = handq([(a, 2, OUT, -1), (-1, 3, OUT, -2)], [-1, -2])
     assert eng.try_execute(q1)
     assert eng.program_count() == 1
@@ -1088,15 +1102,19 @@ def test_a_program_at_classes_that_are_no_power_of_two_equals_the_walk(
     assert demotion_report() == {}
 
 
-def test_an_overflow_regrows_to_twice_the_class_at_least(light_proxy):
+def test_an_overflow_regrows_to_twice_the_class_at_least(light_proxy,
+                                                         monkeypatch):
     """A class that is no power of two, set too small on purpose (9 x
     1,024 rows for a reply of some 20,000): the program overflows, the
     class regrows to a class of at least twice the rows and at least the
-    measured total, the reply equals the CPU engine's, and the classes
-    that fit are remembered."""
+    measured total, the reply equals the CPU engine's, and what is
+    remembered comes from the run that fitted: the chain binds nothing
+    query by query, so each class settles on the total THAT run measured
+    (ISSUE 33), the overflowed run's totals are not used."""
     proxy = light_proxy
     _fresh(proxy)
     eng = proxy.template_engine()
+    _builds, runs = _spy(eng, monkeypatch)
     q0 = proxy._prepare(Q_TAKES, None, False, None, "default")
     first = q0._template_plan_caps
     small = 9 * 1024
@@ -1109,12 +1127,22 @@ def test_an_overflow_regrows_to_twice_the_class_at_least(light_proxy):
     assert grown[:-1] == first[:-1]
     assert grown[-1] >= max(2 * small, qc.result.nrows)
     assert grown[-1] == capacity_class(grown[-1], floor=1)
-    assert eng._good_caps[(qc._tsig, eng._version())] == grown
+    (_c0, _t0, ovf0), (c1, totals, ovf1) = runs
+    assert any(ovf0) and not any(ovf1) and c1 == grown
+    settled = grown[:1] + tuple(
+        min(c, t) for c, t in zip(grown[1:], _classes_of(totals)))
+    assert eng._good_caps[(qc._tsig, eng._version())] == settled
+    assert settled[-1] >= qc.result.nrows > small
+    # the overflowed program went; the one that fitted stays until the
+    # next request has built the settled one
+    assert [k[2] for k in eng._programs] == [grown]
     qh = proxy.serve_query(Q_TAKES, blind=False, device="cpu")
     assert_identical(qh, qc)
-    # remembered: the next reply runs once, at the grown classes
+    # remembered: the next reply runs once, at the settled classes
     q2 = proxy.serve_query(Q_TAKES, blind=False)
-    assert q2._template_attempts == 1 and q2._template_caps == grown
+    assert q2._template_attempts == 1 and q2._template_caps == settled
+    assert [k[2] for k in eng._programs] == [settled]
+    assert_identical(qh, q2)
 
 
 @pytest.mark.parametrize("caps,total,want", [
@@ -1135,3 +1163,248 @@ def test_grow_caps_past_the_cap_is_an_overflow():
     with pytest.raises(TemplateOverflow):
         TemplateCompiledEngine._grow_caps(
             (1024, 1 << 25), np.asarray([0]), np.asarray([True]))
+
+
+# ---------------------------------------------------------------------------
+# a program that no draw can change settles its classes on the totals its
+# first sound run measured (ISSUE 33)
+# ---------------------------------------------------------------------------
+
+def _spy(eng, monkeypatch):
+    """(builds, runs): every program ``eng`` stages from here on (its
+    classes, blind or not) and every dispatch's (classes, totals, overflow
+    flags), in order."""
+    builds, runs = [], []
+    stage, dispatch = eng._stage, eng._dispatch
+
+    def staged(tsig, spec, caps, v2c, proj, width, blind=False):
+        builds.append((tuple(caps), bool(blind)))
+        return stage(tsig, spec, caps, v2c, proj, width, blind)
+
+    def dispatched(prog, args, q, tr):
+        res = dispatch(prog, args, q, tr)
+        runs.append((tuple(prog.caps), [int(t) for t in res[3]],
+                     [bool(o) for o in res[4]]))
+        return res
+
+    monkeypatch.setattr(eng, "_stage", staged)
+    monkeypatch.setattr(eng, "_dispatch", dispatched)
+    return builds, runs
+
+
+def _settles() -> float:
+    from wukong_tpu.obs.metrics import get_registry
+
+    return get_registry().counter("wukong_template_settles_total").value()
+
+
+def _settle_events(q) -> list[dict]:
+    return [a for sp in q.trace.spans for _t, n, a in sp.events
+            if n == "capacity.settle"]
+
+
+def _classes_of(totals) -> tuple:
+    return tuple(capacity_class(t, 1, int(Global.table_capacity_max))
+                 for t in totals)
+
+
+Q7 = PREFIX + """SELECT ?X ?Y ?Z WHERE {
+    ?Y rdf:type ub:FullProfessor . ?Y ub:teacherOf ?Z .
+    ?Z rdf:type ub:Course . ?X ub:advisor ?Y .
+    ?X rdf:type ub:UndergraduateStudent . ?X ub:takesCourse ?Z . }"""
+
+
+@pytest.mark.parametrize("text", [Q_CHAIN, Q_TAKES, Q7],
+                         ids=["chain", "takes", "q7"])
+@pytest.mark.parametrize("blind", [False, True],
+                         ids=["materialising", "blind"])
+def test_an_unbound_template_settles_on_its_measured_totals(
+        light_proxy, monkeypatch, text, blind):
+    """An index-origin chain binds nothing query by query: its first sound
+    run leaves remembered, step by step, the class of the total the run
+    measured (never higher than it ran at, never above the class of the
+    exact bound, the start class as it is). The second reply builds exactly
+    one more program, the third none, one program stays resident, and all
+    three replies are the CPU walk's, byte for byte; traced, the first
+    reply holds one ``capacity.settle`` event a step that came down, and
+    ``wukong_template_settles_total`` counts the same. LUBM's q7 filters by
+    three types: a type is the template's own constant (its signature
+    keeps it), staged with the program, so q7 is such a chain too (its
+    estimate at LUBM-1 is under ``template_min_rows``: sent by the knob)."""
+    proxy = light_proxy
+    _fresh(proxy)
+    monkeypatch.setattr(Global, "enable_tracing", True)
+    if text is Q7:
+        monkeypatch.setattr(Global, "template_device", "device")
+    eng = proxy.template_engine()
+    builds, runs = _spy(eng, monkeypatch)
+    qh = proxy.serve_query(text, blind=blind, device="cpu")
+    assert not getattr(qh, "_template_compiled", False)
+    n_settled = _settles()
+    replies = [proxy.serve_query(text, blind=blind)]
+    # the guess's program stays until the settled one takes its place
+    assert [k[2] for k in eng._programs] == [replies[0]._template_caps]
+    replies += [proxy.serve_query(text, blind=blind) for _ in range(2)]
+    for k, qc in enumerate(replies):
+        assert qc.template_route == "device" and qc._template_compiled, k
+        assert qc._template_attempts == 1, k
+        if blind:
+            assert qc.result.nrows == qh.result.nrows
+        else:
+            assert_identical(qh, qc)
+    guess = replies[0]._template_caps
+    assert builds[:1] == [(guess, blind)]
+    (_c, totals, ovfs), = runs[:1]
+    assert not any(ovfs)
+    settled = guess[:1] + tuple(
+        min(c, t) for c, t in zip(guess[1:], _classes_of(totals)))
+    assert settled != guess  # twice the estimate is a class over the total
+    spec = extract_template(
+        proxy._prepare(text, None, blind, None, "default"))[0]
+    if text is Q7:
+        assert [op[0] for op in spec].count("filter_pair_const") >= 2
+        (prog,) = eng._programs.values()
+        assert prog.fixed
+    bound = eng._fill_bound(spec, eng._start_len(spec))
+    for c, g0, b in zip(settled, guess, bound):
+        assert 1 <= c <= g0 and c <= capacity_class(b, floor=1)
+    key = (replies[0]._tsig, eng._version())
+    assert eng._good_caps[key] == settled
+    assert [q._template_caps for q in replies] == [guess, settled, settled]
+    # one program more, then none; the guess's went when it was built
+    assert builds == [(guess, blind), (settled, blind)]
+    assert [k[2] for k in eng._programs if k[0] == key[0]] == [settled]
+    assert eng.program_count() == 1
+    # a program at settled classes measures the same totals: idempotent
+    assert [r[1] for r in runs] == [totals] * 3
+    came_down = [(k, c0, c1) for k, (c0, c1) in
+                 enumerate(zip(guess, settled)) if c0 != c1]
+    assert [(e["step"], e["cap_from"], e["cap_to"])
+            for e in _settle_events(replies[0])] == came_down
+    assert all(e["site"] == "template.plan"
+               for e in _settle_events(replies[0]))
+    assert not _settle_events(replies[1]) and not _settle_events(replies[2])
+    assert _settles() - n_settled == len(came_down)
+    # the route rule reads the settled classes
+    q4 = proxy._prepare(text, None, blind, None, "default")
+    assert q4._template_plan_caps == settled
+
+
+def test_the_blind_and_the_materialising_program_settle_alike(
+        light_proxy, monkeypatch):
+    """Both run the same expansions over the same operands: whichever runs
+    first settles the template, and the other is built at the settled
+    classes from its first request; the guess leaves no program behind."""
+    proxy = light_proxy
+    _fresh(proxy)
+    eng = proxy.template_engine()
+    builds, _runs = _spy(eng, monkeypatch)
+    first = proxy.serve_query(Q_CHAIN, blind=True)
+    settled = eng._good_caps[(first._tsig, eng._version())]
+    assert settled != first._template_caps
+    q = proxy.serve_query(Q_CHAIN, blind=False)
+    assert q._template_caps == settled and q.result.nrows == first.result.nrows
+    assert proxy.serve_query(Q_CHAIN, blind=True)._template_caps == settled
+    assert builds == [(first._template_caps, True), (settled, False),
+                      (settled, True)]
+    assert sorted(k[2:4] for k in eng._programs) == [(settled, False),
+                                                     (settled, True)]
+
+
+def _bound_draws(proxy, kind: str):
+    """(patterns of a draw, the draws) of a hand-ordered LUBM-1 template
+    whose program binds one operand query by query, with totals that
+    differ between the draws."""
+    g, ss = proxy.g, proxy.str_server
+
+    def pid(name):
+        return ss.str2id(f"<{UB}{name}>")
+
+    takes, teacher, works = pid("takesCourse"), pid("teacherOf"), \
+        pid("worksFor")
+    by_len: dict[int, int] = {}
+    for vid in g.get_index(pid("UndergraduateStudent"), IN):
+        by_len.setdefault(len(g.get_triples(int(vid), takes, OUT)), int(vid))
+    students = [by_len[n] for n in sorted(by_len)]
+    depts = [int(v) for v in g.get_index(pid("Department"), IN)][:4]
+    if kind == "const_list":  # a student's courses, and who teaches each
+        return (lambda c: [(c, takes, OUT, -1), (-1, teacher, IN, -2)],
+                students)
+    if kind == "filter_member":  # courses this student takes, by the index
+        return (lambda c: [(pid("Course"), TYPE_ID, IN, -1),
+                           (c, takes, OUT, -1), (-1, teacher, IN, -2)],
+                students)
+    # full professors of one department, and what each teaches
+    return (lambda c: [(pid("FullProfessor"), TYPE_ID, IN, -1),
+                       (-1, works, OUT, c), (-1, teacher, OUT, -2)],
+            depts)
+
+
+@pytest.mark.parametrize("kind", ["const_list", "filter_pair_const",
+                                  "filter_member"])
+def test_a_bound_template_keeps_its_classes_over_the_draws(
+        light_proxy, monkeypatch, kind):
+    """A constant's start list, a constant object or a constant's member
+    list is bound query by query: the totals are the draw's, not the
+    store's, so the template runs every draw at the classes of its first
+    attempt, in one program, and settles nothing."""
+    proxy = light_proxy
+    pats_of, draws = _bound_draws(proxy, kind)
+    eng = TemplateCompiledEngine(proxy.g)
+    builds, runs = _spy(eng, monkeypatch)
+    n_settled = _settles()
+    spec = extract_template(handq(pats_of(draws[0]), [-1, -2]))[0]
+    assert kind in [op[0] for op in spec]
+    first = eng._initial_caps(f"a-{kind}-template", spec, None, None)
+    for k, c in enumerate(draws):
+        qc = handq(pats_of(c), [-1, -2])
+        qc._tsig = f"a-{kind}-template"  # as the proxy: no vertex constant
+        assert eng.try_execute(qc)
+        qh = handq(pats_of(c), [-1, -2])
+        CPUEngine(proxy.g).execute(qh)
+        assert_identical(qh, qc)
+        assert qc._template_caps == first and qc._template_attempts == 1, k
+        assert eng._good_caps[(qc._tsig, eng._version())] == first, k
+    assert len({tuple(r[1]) for r in runs}) > 1  # the draws' totals differ
+    assert any(_classes_of(r[1]) != first[1:] for r in runs)
+    assert builds == [(first, False)] and eng.program_count() == 1
+    (prog,) = eng._programs.values()
+    assert not prog.fixed and prog.fn._cache_size() == 1
+    assert _settles() == n_settled
+
+
+def test_a_store_version_bump_forgets_the_settled_classes(monkeypatch):
+    """Settled classes are facts of the store at one version: after a
+    dynamic insert the template starts again from its guess, runs sound on
+    the mutated store, and settles on what that run measures."""
+    from wukong_tpu.store.dynamic import insert_triples
+    from wukong_tpu.types import NORMAL_ID_START
+
+    proxy, text = _mk_tri_proxy()
+    monkeypatch.setattr(Global, "join_strategy", "walk")
+    monkeypatch.setattr(Global, "template_device", "device")
+    eng = proxy.template_engine()
+    _builds, runs = _spy(eng, monkeypatch)
+    q0 = proxy.run_single_query(text, blind=False)
+    v0 = eng._version()
+    settled0 = eng._good_caps[(q0._tsig, v0)]
+    assert settled0 != q0._template_caps
+    assert proxy.run_single_query(text, blind=False)._template_caps \
+        == settled0
+    a, b, c = (NORMAL_ID_START + 7001, NORMAL_ID_START + 7002,
+               NORMAL_ID_START + 7003)
+    insert_triples(proxy.g, np.asarray(
+        [[a, 2, b], [b, 3, c], [a, 4, c]], dtype=np.int64))
+    v1 = eng._version()
+    assert v1 != v0 and (q0._tsig, v1) not in eng._good_caps
+    q1 = proxy.run_single_query(text, blind=False)
+    assert q1._template_compiled
+    assert q1._template_attempts == q0._template_attempts
+    assert q1.result.nrows == q0.result.nrows + 1
+    # from the guess again, not from the classes of the store before
+    assert q1._template_caps == q0._template_caps
+    assert runs[-1][1] != runs[0][1]  # the totals are the new store's
+    assert eng._good_caps[(q1._tsig, v1)] == q1._template_caps[:1] + tuple(
+        min(c0, t) for c0, t in zip(q1._template_caps[1:],
+                                    _classes_of(runs[-1][1])))
+    assert all(k[1] == v1 for k in eng._programs)

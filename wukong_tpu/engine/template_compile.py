@@ -8,6 +8,18 @@ in finer classes: a program's classes are its own, ``_program_key`` holds
 them, so they step in eighths of an octave, ``capacity_class``, and lie
 never above the class of the most rows the data can put there; the probe
 and the walk, whose kernels queries share by class, keep ``pad_pow2``).
+One sizing rule, whose room is what the totals can differ by between
+requests: a first attempt takes twice the planner's estimate, and a
+program that binds an operand query by query (a constant's start list, a
+vertex as a constant object, a constant's member list) keeps those
+classes, so that they do not follow the draw; a program that no draw can
+change (every operand staged once, a type constant among them: the
+signature keeps it; the store read-only at a version that is in the key)
+settles, after its first sound run, on the classes of the totals that run
+measured, which cannot overflow and take no room
+(``_settled_caps``; ``wukong_template_settles_total``, the trace event
+``capacity.settle``); its next request builds that program and the
+guess's goes (``_cache_put``: one program a template stays resident).
 TrieJax runs the whole LFTJ dataflow as one pipelined
 hardware graph; "Column-Oriented Datalog on the GPU" shows eager
 device-resident buffers paying off exactly when iteration state never
@@ -75,7 +87,15 @@ from wukong_tpu.obs.device import (
 from wukong_tpu.obs.metrics import get_registry
 from wukong_tpu.obs.trace import span, traced_execute
 from wukong_tpu.runtime import faults
-from wukong_tpu.types import IN, OUT, PREDICATE_ID, TYPE_ID, AttrType
+from wukong_tpu.types import (
+    IN,
+    NORMAL_ID_START,
+    OUT,
+    PREDICATE_ID,
+    TYPE_ID,
+    AttrType,
+)
+from wukong_tpu.utils.logger import log_info
 from wukong_tpu.utils.timer import get_usec
 
 #: the dispatch site every whole-plan program charges (DEVICE_INPUTS
@@ -112,6 +132,10 @@ _M_DEMOTED = get_registry().counter(
     "wukong_template_demotions_total",
     "Per-template compiled-route demotion latches by reason",
     labels=("reason",))
+_M_SETTLED = get_registry().counter(
+    "wukong_template_settles_total",
+    "Capacity classes of a whole-plan program that no draw can change, "
+    "lowered to the class of the total its first sound run measured")
 
 
 class TemplateUnsupported(Exception):
@@ -400,14 +424,18 @@ def _build_program(spec: tuple, caps: tuple, depths: tuple,
 class _Program:
     """One cached compiled template: the jitted fn plus the device
     operands every draw of the template shares (an index start list, the
-    CSR triplets). What a draw's own constants decide (a constant's start
-    list, a constant object, a constant's member list) is ``None`` in
+    CSR triplets, a constant object the signature keeps: a type). What a
+    draw's own constants decide (a constant's start list, a constant
+    object that is a vertex, a constant's member list) is ``None`` in
     ``args`` and bound query by query (``_bind``): the program is cached
-    under the template's signature, which leaves vertex constants out.
+    under the template's signature, which leaves vertex constants out and
+    keeps the ids under ``NORMAL_ID_START``.
+    ``fixed``: no operand is a draw's, so every request runs the program
+    over the same values (``_settled_caps`` reads it).
     Steady-state execution is ``fn(*bound args)`` and one result fetch."""
 
     __slots__ = ("fn", "forms", "args", "caps", "spec", "v2c", "proj",
-                 "width", "nbytes", "label", "blind")
+                 "width", "nbytes", "label", "blind", "fixed")
 
     def __init__(self, fn, forms, args, caps, spec, v2c, proj, width,
                  nbytes, label, blind=False):
@@ -422,6 +450,7 @@ class _Program:
         self.nbytes = nbytes
         self.label = label
         self.blind = blind
+        self.fixed = all(a is not None for a in args)
 
 
 def _program_key(tsig, store_version: int, caps: tuple,
@@ -489,6 +518,13 @@ class TemplateCompiledEngine:
             stale_bytes = sum(self._programs.pop(k).nbytes for k in stale)
             self._programs[key] = prog
             self._programs.move_to_end(key)
+            # one program a template: the classes a settled template left
+            # go when the program that takes their place is here, not
+            # before, so that what is resident moves as little as it can
+            # (a program's time follows where its buffers lie)
+            for k in [k for k in self._programs
+                      if k[0] == key[0] and k[2] != key[2]]:
+                evicted.append(self._programs.pop(k))
             budget = _budget_bytes()
             total = sum(p.nbytes for p in self._programs.values())
             while total > budget and len(self._programs) > 1:
@@ -513,10 +549,9 @@ class TemplateCompiledEngine:
         device with them (some MiB each): nothing runs them again before a
         store mutation re-arms the template, and that makes them stale."""
         with self._lock:
-            gone = [self._programs.pop(k)
-                    for k in [k for k in self._programs if k[0] == tsig]]
-        for prog in gone:
-            maybe_device_resident("evict", "template", prog.nbytes)
+            keys = [k for k in self._programs if k[0] == tsig]
+        for key in keys:
+            self._drop_program(key)
 
     def clear(self) -> None:
         with self._lock:
@@ -561,7 +596,10 @@ class TemplateCompiledEngine:
                 if kind != "expand":
                     depths.append(int(depth))
                 if kind == "filter_pair_const":
-                    args.append(None)
+                    # a type is the template's own (its signature keeps
+                    # it), a vertex the draw's
+                    args.append(np.int32(op[4])
+                                if op[4] < NORMAL_ID_START else None)
             else:  # filter_member
                 args += [None, None]
         fn, forms = _build_program(spec, caps, tuple(depths),
@@ -581,7 +619,8 @@ class TemplateCompiledEngine:
 
     def _bind(self, prog: _Program, spec: tuple) -> list:
         """``prog.args`` with this query's constants in their slots: the
-        constant's start list, constant objects, a constant's member list
+        constant's start list, vertices as constant objects (a type is
+        staged with the program), a constant's member list
         (padded, as a start list is, to the class of the longest list its
         segment holds, so no constant's list length mints a program)."""
         args = list(prog.args)
@@ -604,10 +643,11 @@ class TemplateCompiledEngine:
                 continue
             at += 3
             if op[0] == "filter_pair_const":
-                if not (0 <= op[4] < (1 << 31)):
-                    raise DeviceRangeError(
-                        f"const object {op[4]} exceeds int32")
-                args[at] = np.int32(op[4])
+                if args[at] is None:
+                    if not (0 <= op[4] < (1 << 31)):
+                        raise DeviceRangeError(
+                            f"const object {op[4]} exceeds int32")
+                    args[at] = np.int32(op[4])
                 at += 1
         return args
 
@@ -623,7 +663,9 @@ class TemplateCompiledEngine:
 
     def plan_caps(self, q) -> tuple:
         """The classes the program of ``q``'s plan runs at, for the route
-        rule: those its last sound run ended at or, before any run, those a
+        rule: those its last sound run left remembered (the classes it ran
+        at or, where no draw can change the program, the classes of the
+        totals it measured: ``_settled_caps``) or, before any run, those a
         first attempt would take (from what the proxy stamped on ``q`` at
         plan time; memoised per template and store version, nothing is
         staged or built). ``()`` where the small end of the rule cannot
@@ -653,8 +695,11 @@ class TemplateCompiledEngine:
 
     def _initial_caps(self, tsig, spec, est_rows: int | None,
                       est_steps: list | None = None) -> tuple:
-        """The classes a first attempt runs at: where the planner walked
-        the chain, each expansion's own estimate with one class of room,
+        """The classes the next attempt runs at: those the template's last
+        sound run at this store version left remembered (``_settled_caps``:
+        for a program that no draw can change the classes of its measured
+        totals, without the room), else a first attempt's: where the planner
+        walked the chain, each expansion's own estimate with one class of room,
         that is twice the estimate (a start from a constant scaled to the
         heaviest constant, as its start list is), and never under its
         segment's longest edge list, with the steps after lifted by the
@@ -737,6 +782,49 @@ class TemplateCompiledEngine:
                 f"capacity class past table_capacity_max ({cap_max})")
         return tuple(caps)
 
+    @staticmethod
+    def _settled_caps(prog: _Program, totals: np.ndarray) -> tuple:
+        """The classes a sound run of ``prog`` leaves remembered for its
+        template. Where a draw's constants decide an operand, the classes it
+        ran at: the totals are that draw's, and a template's classes do not
+        follow the draw. Where every operand is the same for every request
+        (``prog.fixed``) the store is read-only at a version and the version
+        is in the key, so each expansion's total is a fact of the store: the
+        class of the total cannot overflow and takes neither the room nor
+        ``table_capacity_min``, as the class of ``_fill_bound`` does not.
+        Never above the class it ran at (and so never above the bound's),
+        never under one row; the start class is exact already. A program at
+        settled classes measures the same totals and settles to itself."""
+        if not prog.fixed:
+            return prog.caps
+        cap_max = int(Global.table_capacity_max)
+        return prog.caps[:1] + tuple(
+            min(c, capacity_class(int(t), 1, cap_max))
+            for c, t in zip(prog.caps[1:], totals))
+
+    def _drop_program(self, key) -> None:
+        """A program that is not come back to goes (classes that overflowed,
+        a demoted template's), and with it its few MiB of program text on
+        the device."""
+        with self._lock:
+            dropped = self._programs.pop(key, None)
+        if dropped is not None:
+            maybe_device_resident("evict", "template", dropped.nbytes)
+
+    @staticmethod
+    def _note_settled(prog: _Program, settled: tuple, tr) -> None:
+        """Counted, logged and, traced, one ``capacity.settle`` event a step
+        that came down. The guess's program stays until the template's next
+        request has built the settled one (``_cache_put``)."""
+        for k, (c0, c1) in enumerate(zip(prog.caps, settled)):
+            if c1 != c0:
+                _M_SETTLED.inc()
+                if tr is not None:
+                    tr.event("capacity.settle", site=SITE, step=k,
+                             cap_from=c0, cap_to=c1)
+        log_info(f"compiled template {prog.label} settled on its measured "
+                 f"totals: classes {prog.caps} -> {settled}")
+
     # -- execution -----------------------------------------------------
     def try_execute(self, q) -> bool:
         """Serve ``q`` through the compiled program. Returns True when
@@ -785,8 +873,11 @@ class TemplateCompiledEngine:
                 args = self._bind(prog, spec)
             tbl, val, live, totals, ovfs = self._dispatch(prog, args, q, tr)
             if not (ovfs.size and bool(ovfs.any())):
+                settled = self._settled_caps(prog, totals)
                 with self._lock:
-                    self._good_caps[(tsig, version)] = caps
+                    self._good_caps[(tsig, version)] = settled
+                if settled != caps:
+                    self._note_settled(prog, settled, tr)
                 q._template_caps = caps  # what the reply's feedback judges
                 with span(tr, "template.commit"):
                     self._commit(q, prog, tbl, val, live)
@@ -804,13 +895,9 @@ class TemplateCompiledEngine:
                 return True
             grown = self._grow_caps(caps, totals, ovfs)
             # the classes that overflowed are not come back to (the ones
-            # that fit are remembered): their program goes, and with it its
-            # few MiB of program text on the device
-            with self._lock:
-                dropped = self._programs.pop(key, None)
-            if dropped is not None:
-                maybe_device_resident("evict", "template", dropped.nbytes)
-            del prog, dropped
+            # that fit are remembered)
+            self._drop_program(key)
+            del prog
             if tr is not None:
                 for k, (c0, c1) in enumerate(zip(caps, grown)):
                     if c1 != c0:
