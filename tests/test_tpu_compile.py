@@ -203,3 +203,30 @@ def test_c3_last_expansion_compiles_smaller_at_its_finer_class(one_chip):
     for what in ("output_size_in_bytes", "temp_size_in_bytes"):
         small, large = getattr(mem[fine], what), getattr(mem[coarse], what)
         assert 0 < small < 0.6 * large, what  # the classes: 0.5625
+
+
+@pytest.mark.parametrize("case", ["knows", "hasCreator"])
+def test_level_probe_compiles_at_lsqb_sizes(one_chip, case):
+    """One slice of the join's level probe (``kernels.LEVEL_SLICE`` = 2^22
+    candidates) over LSQB's tables at scale factor 10: q3's widest level
+    probes ``knows`` (73,000 keys, 3.9 M edges, a table over 204,000 ids for
+    the anchors) and ``isPartOf``; q2's probes ``hasCreator`` keyed by 29.3 M
+    messages (a table over 30.2 M ids) beside the list of 21.9 M comments.
+    Each is a few seconds of compiling and under 0.5 GiB of temporaries,
+    where the level as one ``pad_pow2`` tensor was 2^27 slots."""
+    from wukong_tpu.join import kernels
+
+    i = partial(_i32, one_chip)
+    S = kernels.LEVEL_SLICE
+    valid = jax.ShapeDtypeStruct((S,), jnp.bool_, sharding=one_chip)
+    if case == "knows":
+        fn = kernels.jit_level_probe((11, 8), False, (204_100, 205_500))
+        args = [i(1), i(73_000), i(73_001), i(3_950_000), i(S),
+                i(1_343), i(1_344), i(1_343), i(S)]
+    else:
+        fn = kernels.jit_level_probe((2,), True, (30_200_000,))
+        args = [i(21_900_000), i(29_300_000), i(29_300_001), i(29_300_000),
+                i(S)]
+    compiled = _compile(fn.lower(valid, i(S), *args), f"wk_level_probe[{case}]",
+                        False)
+    assert compiled.memory_analysis().temp_size_in_bytes < 512 << 20

@@ -706,14 +706,16 @@ def test_wcoj_device_route_byte_identical(world):
     assert all(lv["route"] == "host" for lv in qh.join_stats), name
 
 
-def test_wcoj_device_failure_degrades_to_host(world, monkeypatch):
-    """Any device-path failure degrades the level (and latches the rest
-    of the query) to the host kernels — correct rows, never an error."""
+@pytest.mark.parametrize("half", ["_probe_start", "_probe_finish"])
+def test_wcoj_device_failure_degrades_to_host(world, monkeypatch, half):
+    """Any device-path failure, where the probe is dispatched or where its
+    mask is fetched, degrades the level (and latches the rest of the
+    query) to the host kernels — correct rows, never an error."""
     name, _t, g, stats, meta = world
     Global.join_device = "device"
     wc = WCOJExecutor(g, stats=stats)
     monkeypatch.setattr(
-        WCOJExecutor, "_probe_device",
+        WCOJExecutor, half,
         lambda self, *a, **k: (_ for _ in ()).throw(RuntimeError("boom")))
     q = mkq(meta)
     heuristic_plan(q)
@@ -785,7 +787,7 @@ def test_proxy_route_demoted_after_device_failure(tri_proxy, monkeypatch):
     monkeypatch.setattr(_P, "choose_join_route",
                         lambda self, pats: "device")
     monkeypatch.setattr(
-        WCOJExecutor, "_probe_device",
+        WCOJExecutor, "_probe_start",
         lambda self, *a, **k: (_ for _ in ()).throw(RuntimeError("boom")))
     q = proxy.run_single_query(text, blind=False)
     assert q.result.status_code == ErrorCode.SUCCESS

@@ -355,7 +355,11 @@ class TPUEngine:
         from wukong_tpu.runtime.resilience import charge_query, check_query
 
         grown = False
-        for _attempt in range(8):
+        # every attempt puts right at least the first step that overflowed,
+        # so a chain of k steps is sound after k + 1 at most; eight was the
+        # limit whatever the length, and LSQB's q3 (16 steps whose later
+        # estimates are under one row) met it with steps still to grow
+        for _attempt in range(max(8, device_steps + 2)):
             if chain_span is not None:
                 chain_span.attrs["attempts"] = _attempt + 1
             check_query(q, f"tpu.chain attempt {_attempt}")

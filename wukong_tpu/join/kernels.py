@@ -227,13 +227,37 @@ def to_device_i32(arr):
     return jnp.asarray(a.astype(np.int32, copy=False))
 
 
-# jitted level-probe variants keyed on (per-adjacency depths, has_glob):
-# the candidate tensor shape is handled by pad_pow2 bucketing, so the
-# cache stays small
+#: the padded size a level taken in runs of rows is probed at
+#: (``join/wcoj.py``: a level of more than ``LEVEL_CHUNK_SLICES`` of these,
+#: 2^24 candidates): a generator group is cut into slices of this one size,
+#: its last slice at the class of what is left. At LSQB's scale factor 10 a
+#: level of the triangle holds 8.0 x 10^7 candidates, which as one
+#: ``pad_pow2`` tensor is 2^27 slots with their anchors, 2 GiB shipped and
+#: probed before the host may enumerate again. A smaller level is one
+#: dispatch a group at ``pad_pow2`` of its candidates, as it always was.
+LEVEL_SLICE = 1 << 22
+
+
+def level_slices(n: int) -> list:
+    """``[(lo, hi, padded slots)]`` of a generator group of ``n``
+    candidates in a level taken in runs: whole slices of ``LEVEL_SLICE``,
+    then what is left at its own :func:`pad_pow2` class."""
+    size = max(int(LEVEL_SLICE), 1)
+    out = [(lo, lo + size, size) for lo in range(0, n - size + 1, size)]
+    lo = len(out) * size
+    if lo < n or not out:
+        out.append((lo, n, pad_pow2(n - lo)))
+    return out
+
+
+# jitted level-probe variants keyed on (per-adjacency depths, has_glob,
+# per-adjacency id bounds): the candidate tensor shape is handled by
+# pad_pow2 bucketing and LEVEL_SLICE, so the cache stays small
 _LEVEL_PROBE_CACHE: dict = {}
 
 
-def jit_level_probe(adj_depths: tuple, has_glob: bool):
+def jit_level_probe(adj_depths: tuple, has_glob: bool,
+                    id_bounds: tuple | None = None):
     """The fused XLA probe for one WCOJ generator group: a padded flat
     candidate tensor is masked by every LISTED constraint in one compiled
     call — global sorted-list membership plus one ragged pair probe per
@@ -243,33 +267,44 @@ def jit_level_probe(adj_depths: tuple, has_glob: bool):
     actually needs (a generator's self-probe is true by construction and
     is elided), and ``adj_depths[j]`` is adjacency j's binary-search
     iteration bound (log2(max_degree)+1, cached with its device table).
+    ``id_bounds[j]`` (the segment's last key + 1, cached beside it) lets
+    the anchors' key lookup address a table over the id range where
+    :func:`direct_lookup_wins` says so: at 2^22 candidates over the 73,000
+    keys of LSQB's ``knows`` the search is 17 rounds of a gather a
+    candidate, the table one.
 
     Signature of the returned fn:
         fn(valid, cand, glob, k0, o0, e0, a0, k1, o1, e1, a1, ...) -> mask
     where ``valid``/``cand`` are the padded candidate tensor and its
     validity mask, ``glob`` the intersected global candidate list (ignored
     when has_glob is False — pass a 1-element dummy), and each adjacency
-    contributes (keys, offsets, edges, anchors)."""
+    contributes (keys, offsets, edges, anchors). The compiled program is
+    named ``wk_level_probe`` (the function and a ``jax.named_scope``), so a
+    profile that carries scopes can tell it from the template programs."""
     import jax
     import jax.numpy as jnp
 
-    key = (tuple(int(d) for d in adj_depths), bool(has_glob))
+    depths = tuple(int(d) for d in adj_depths)
+    bounds = (None,) * len(depths) if id_bounds is None \
+        else tuple(None if b is None else int(b) for b in id_bounds)
+    key = (depths, bool(has_glob), bounds)
     fn = _LEVEL_PROBE_CACHE.get(key)
     if fn is not None:
         return fn
-    depths = key[0]
 
-    def probe(valid, cand, glob, *adj):
-        mask = valid
-        if has_glob:
-            mask = mask & member_sorted(glob, cand, xp=jnp)
-        for j, depth in enumerate(depths):
-            keys, offsets, edges, anchors = adj[4 * j: 4 * j + 4]
-            mask = mask & pair_member(keys, offsets, edges, anchors, cand,
-                                      xp=jnp, depth=depth)
-        return mask
+    def wk_level_probe(valid, cand, glob, *adj):
+        with jax.named_scope("wk_level_probe"):
+            mask = valid
+            if has_glob:
+                mask = mask & member_sorted(glob, cand, xp=jnp)
+            for j, depth in enumerate(depths):
+                keys, offsets, edges, anchors = adj[4 * j: 4 * j + 4]
+                mask = mask & pair_member(keys, offsets, edges, anchors,
+                                          cand, xp=jnp, depth=depth,
+                                          id_bound=bounds[j])
+            return mask
 
-    fn = jax.jit(probe)
+    fn = jax.jit(wk_level_probe)
     _LEVEL_PROBE_CACHE[key] = fn
     return fn
 

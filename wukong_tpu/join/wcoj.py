@@ -40,11 +40,13 @@ import numpy as np
 
 from wukong_tpu.analysis.lockdep import declare_leaf, make_lock
 from wukong_tpu.config import Global
+from wukong_tpu.join import kernels
 from wukong_tpu.join.kernels import (
     DeviceRangeError,
     expand_ragged,
     intersect_many,
     jit_level_probe,
+    level_slices,
     lookup_ranges,
     member_sorted,
     pad_pow2,
@@ -54,7 +56,7 @@ from wukong_tpu.join.kernels import (
 from wukong_tpu.join.qgraph import U_CONST, U_PINDEX, U_TYPE, analyze
 from wukong_tpu.obs.device import maybe_device_dispatch, maybe_device_resident
 from wukong_tpu.obs.metrics import get_registry
-from wukong_tpu.obs.trace import trace_event, traced_execute
+from wukong_tpu.obs.trace import span, trace_event, traced_execute
 from wukong_tpu.runtime import faults
 from wukong_tpu.runtime.resilience import (
     charge_query,
@@ -89,6 +91,40 @@ _M_DEVICE_CAND = get_registry().histogram(
     "Candidates per device-probed level",
     buckets=(1 << 10, 1 << 12, 1 << 14, 1 << 16, 1 << 18, 1 << 20,
              1 << 22, 1 << 24))
+
+# what a level cost whichever route probed it: the candidates it enumerated
+# and the slots it probed (a device level pads its candidates to capacity
+# classes; a host level probes them as they are)
+_M_LEVEL_CAND = get_registry().counter(
+    "wukong_join_level_candidates_total",
+    "Candidates enumerated by WCOJ levels", labels=("route",))
+_M_LEVEL_SLOTS = get_registry().counter(
+    "wukong_join_level_slots_total",
+    "Slots probed by WCOJ levels (padded on the device route)",
+    labels=("route",))
+
+#: a level of more candidates than this many slices of ``LEVEL_SLICE`` is
+#: enumerated and probed a run of prefix rows at a time
+LEVEL_CHUNK_SLICES = 4
+
+
+def _row_chunks(counts: np.ndarray) -> list:
+    """``[(lo, hi)]`` runs of prefix rows whose candidates (``counts`` a
+    row) stay within ``LEVEL_CHUNK_SLICES`` slices each; a row is never
+    split, so one with more is a run of its own."""
+    limit = max(int(kernels.LEVEL_SLICE), 1) * LEVEL_CHUNK_SLICES
+    n = len(counts)
+    cum = np.cumsum(counts)
+    if n == 0 or int(cum[-1]) <= limit:
+        return [(0, n)]
+    out, lo, base = [], 0, 0
+    while lo < n:
+        hi = int(np.searchsorted(cum, base + limit, side="right"))
+        hi = min(max(hi, lo + 1), n)
+        out.append((lo, hi))
+        lo, base = hi, int(cum[hi - 1])
+    return out
+
 
 # the cache lock guards pure dict moves (materialization happens outside
 # it); nothing is ever acquired under it
@@ -387,8 +423,10 @@ class WCOJExecutor:
                 check_query(q, f"wcoj.level {k}")
                 t0 = get_usec()
                 rows_in = len(prefix)
-                prefix, rec = self._level(qg, v, k, prefix, cols,
-                                          unary_lists[v], route, q)
+                with span(getattr(q, "trace", None), "wcoj.level",
+                          level=k, var=int(v)):
+                    prefix, rec = self._level(qg, v, k, prefix, cols,
+                                              unary_lists[v], route, q)
                 cols[v] = k
                 rec.update(level=k, var=v, rows_in=rows_in,
                            rows_out=len(prefix),
@@ -440,7 +478,15 @@ class WCOJExecutor:
         constraint then filters all candidates (the generating list's
         self-probe is redundant but always true). Returns the new prefix
         and the level's intersection stats.
+
+        A level of more than ``LEVEL_CHUNK_SLICES`` slices of candidates
+        (2^24) is taken a run of prefix rows at a time (``_row_chunks``):
+        the host holds one run's candidates, not the level's, and on the
+        device route it enumerates the next run while the chip probes this
+        one (the probe is dispatched, the mask fetched a run later). A
+        smaller level is one run, probed as ``_probe_start`` says.
         """
+        tr = getattr(q, "trace", None)
         adj = []  # (anchor col, pid, dir, segment) — other endpoint bound
         glob = list(unary)  # global sorted candidate lists
         for e in qg.edges_of(v):
@@ -460,27 +506,120 @@ class WCOJExecutor:
                               f"wcoj: variable {v} has no constraint to "
                               "generate candidates from")
 
-        # per-row generator: argmin over each adjacency's degree and the
-        # global list's (constant) length
-        ranges = [lookup_ranges(seg.keys, seg.offsets, prefix[:, c])
-                  for c, _pid, _d, seg in adj]
-        deg_stack = [d for (_s, d) in ranges]
-        if G is not None:
-            deg_stack.append(np.full(n, len(G), dtype=np.int64))
-        degs = np.stack(deg_stack) if n else \
-            np.empty((len(deg_stack), 0), dtype=np.int64)
-        choice = np.argmin(degs, axis=0) if n else \
-            np.empty(0, dtype=np.int64)
+        with span(tr, "wcoj.enumerate"):
+            # per-row generator: argmin over each adjacency's degree and
+            # the global list's (constant) length
+            ranges = [lookup_ranges(seg.keys, seg.offsets, prefix[:, c])
+                      for c, _pid, _d, seg in adj]
+            deg_stack = [d for (_s, d) in ranges]
+            if G is not None:
+                deg_stack.append(np.full(n, len(G), dtype=np.int64))
+            degs = np.stack(deg_stack) if n else \
+                np.empty((len(deg_stack), 0), dtype=np.int64)
+            choice = np.argmin(degs, axis=0) if n else \
+                np.empty(0, dtype=np.int64)
+            chunks = _row_chunks(degs.min(axis=0)) if n else [(0, 0)]
+            del degs, deg_stack
 
+        device = route == "device"
+        probes = len(adj) + (1 if G is not None else 0)
+        state = {"candidates": 0, "slots": 0, "route": "host"}
+        kept_rows, kept_vals = [], []
+
+        def host_mask(row_idx, newcol):
+            mask = np.ones(len(newcol), dtype=bool)
+            if G is not None:
+                mask &= member_sorted(G, newcol)
+            for c, _pid, _d, seg in adj:
+                mask &= pair_member(seg.keys, seg.offsets, seg.edges,
+                                    prefix[row_idx, c], newcol)
+            return mask
+
+        def device_failed(e):
+            # degrade THIS query's remaining levels to host (the
+            # wcoj->walk posture, one layer down); the host probe
+            # serves this chunk
+            reason = (type(e).__name__ if not isinstance(
+                e, DeviceRangeError) else "int32_range")
+            _M_DEVICE_FALLBACK.labels(reason=reason).inc()
+            if q is not None:
+                q._join_device_broken = True
+
+        def finish(pending):
+            """The mask of a chunk whose probe was dispatched (or not),
+            and its surviving candidates kept, in chunk order."""
+            row_idx, newcol, job = pending
+            mask = None
+            if job is not None:
+                try:
+                    mask = self._probe_finish(job, len(newcol), q, k)
+                    state["slots"] += job["slots"]
+                    state["route"] = "device"
+                except Exception as e:
+                    device_failed(e)
+            if mask is None:
+                mask = host_mask(row_idx, newcol)
+                state["slots"] += len(newcol)
+            kept_rows.append(row_idx[mask])
+            kept_vals.append(newcol[mask])
+
+        pending = None
+        for lo, hi in chunks:
+            with span(tr, "wcoj.enumerate"):
+                row_idx, newcol, gid = self._enumerate(
+                    adj, G, ranges, choice, lo, hi, k, device)
+            state["candidates"] += len(newcol)
+            job = None
+            if len(newcol) and device \
+                    and (len(chunks) > 1
+                         or len(newcol) >= self._device_floor()) \
+                    and not (q is not None
+                             and getattr(q, "_join_device_broken", False)):
+                try:
+                    job = self._probe_start(G, adj, prefix, row_idx,
+                                            newcol, gid, len(chunks) == 1,
+                                            tr)
+                except Exception as e:
+                    device_failed(e)
+            if pending is not None:
+                finish(pending)
+            pending = (row_idx, newcol, job) if len(newcol) else None
+        if pending is not None:
+            finish(pending)
+
+        lvl_route = state["route"]
+        _M_DEVICE_LEVELS.labels(route=lvl_route).inc()
+        _M_LEVEL_CAND.labels(route=lvl_route).inc(state["candidates"])
+        _M_LEVEL_SLOTS.labels(route=lvl_route).inc(state["slots"])
+        row_idx = np.concatenate(kept_rows) if kept_rows else \
+            np.empty(0, dtype=np.int64)
+        newcol = np.concatenate(kept_vals) if kept_vals else \
+            np.empty(0, dtype=np.int64)
+        new_prefix = np.column_stack(
+            [prefix[row_idx], newcol]).astype(np.int64, copy=False)
+        if tr is not None:
+            tr.event("join.level", var=int(v),
+                     candidates=state["candidates"], slots=state["slots"],
+                     rows_out=len(new_prefix), route=lvl_route)
+        return new_prefix, {"candidates": state["candidates"],
+                            "slots": state["slots"], "probes": probes,
+                            "route": lvl_route}
+
+    def _enumerate(self, adj, G, ranges, choice, lo: int, hi: int, k: int,
+                   want_gid: bool):
+        """The candidates of prefix rows ``[lo, hi)``: (row index into the
+        whole prefix, candidate value, generator id or None), generator
+        group by generator group."""
+        ch = choice[lo:hi]
         parts = []  # (generator id, row_idx, newcol) per generator group
         for j, (start, deg) in enumerate(ranges):
-            rows = np.nonzero(choice == j)[0]
+            rows = np.nonzero(ch == j)[0] + lo
             if len(rows) == 0:
                 continue
             row_idx, pos = expand_ragged(start[rows], deg[rows])
             parts.append((j, rows[row_idx], adj[j][3].edges[pos]))
         if G is not None:
-            rows = np.nonzero(choice == len(ranges))[0]
+            rows = np.nonzero(ch == len(ranges))[0] + lo
             if len(rows):
                 parts.append((len(adj), np.repeat(rows, len(G)),
                               np.tile(G, len(rows))))
@@ -495,11 +634,11 @@ class WCOJExecutor:
             # device route consumes it — the host route skips the alloc
             gid = (np.concatenate([np.full(len(p[1]), p[0],
                                            dtype=np.int16) for p in parts])
-                   if route == "device" else None)
+                   if want_gid else None)
         else:
             row_idx = np.empty(0, dtype=np.int64)
             newcol = np.empty(0, dtype=np.int64)
-            gid = np.empty(0, dtype=np.int16) if route == "device" else None
+            gid = np.empty(0, dtype=np.int16) if want_gid else None
 
         if self.part is not None and k == 0 and len(newcol):
             # distributed generic join: this slice keeps only its hash
@@ -512,124 +651,140 @@ class WCOJExecutor:
             row_idx, newcol = row_idx[pm], newcol[pm]
             if gid is not None:
                 gid = gid[pm]
-
-        candidates = len(newcol)
-        probes = len(adj) + (1 if G is not None else 0)
-        lvl_route = "host"
-        if len(newcol):
-            mask = None
-            if route == "device" and candidates >= self._device_floor() \
-                    and not (q is not None
-                             and getattr(q, "_join_device_broken", False)):
-                try:
-                    mask = self._probe_device(G, adj, prefix, row_idx,
-                                              newcol, gid, q=q, level=k)
-                    lvl_route = "device"
-                except Exception as e:
-                    # degrade THIS query's remaining levels to host (the
-                    # wcoj->walk posture, one layer down); the host probe
-                    # below serves this level
-                    reason = (type(e).__name__ if not isinstance(
-                        e, DeviceRangeError) else "int32_range")
-                    _M_DEVICE_FALLBACK.labels(reason=reason).inc()
-                    if q is not None:
-                        q._join_device_broken = True
-            if mask is None:
-                mask = np.ones(len(newcol), dtype=bool)
-                if G is not None:
-                    mask &= member_sorted(G, newcol)
-                for c, _pid, _d, seg in adj:
-                    anchors = prefix[row_idx, c]
-                    mask &= pair_member(seg.keys, seg.offsets, seg.edges,
-                                        anchors, newcol)
-            row_idx, newcol = row_idx[mask], newcol[mask]
-        _M_DEVICE_LEVELS.labels(route=lvl_route).inc()
-        new_prefix = np.column_stack(
-            [prefix[row_idx], newcol]).astype(np.int64, copy=False)
-        return new_prefix, {"candidates": candidates, "probes": probes,
-                            "route": lvl_route}
+        return row_idx, newcol, gid
 
     # ------------------------------------------------------------------
-    def _probe_device(self, G, adj, prefix: np.ndarray, row_idx: np.ndarray,
-                      newcol: np.ndarray, gid: np.ndarray, q=None,
-                      level: int = 0) -> np.ndarray:
-        """The level's probe phase as one fused XLA dispatch per generator
-        group: each group's padded flat candidate tensor is masked by
-        every constraint EXCEPT its own generator (whose self-probe is
-        true by construction — candidates were drawn from that list), the
-        adjacencies ship as cached device-resident tables with their
-        binary-search depth bounds, and the global list ships per level
-        (it is an intersection result, not a cacheable table). Candidate
-        tensors are padded to power-of-two capacity classes so the jit
-        variants stay bounded. Returns the host boolean mask over the
-        unpadded candidates — identical semantics to the host probes.
+    def _probe_start(self, G, adj, prefix: np.ndarray, row_idx: np.ndarray,
+                     newcol: np.ndarray, gid: np.ndarray, whole: bool,
+                     tr=None) -> dict:
+        """Dispatch the probe phase of a level, or of one run of its rows:
+        one fused XLA call per generator group, masking each padded flat
+        candidate tensor by every constraint EXCEPT its own generator
+        (whose self-probe is true by construction — candidates were drawn
+        from that list); the adjacencies ship as cached device-resident
+        tables with their binary-search depth bounds, the global list
+        ships per call of this (it is an intersection result, not a
+        cacheable table). -> the job ``_probe_finish`` takes.
+
+        ``whole`` (the level is one run: up to ``LEVEL_CHUNK_SLICES``
+        slices of candidates, 2^24, the largest level any cell ran before
+        LSQB's) probes as levels always were: a group is ONE call at the
+        ``pad_pow2`` class of its candidates, by the program that searches
+        its keys, and its mask is fetched before the next group's tensors
+        are built. What a request allocates on the device, and when, is
+        then what it always was: a LUBM heavy's first request takes this
+        route, and q1's time there follows where later buffers come to
+        lie (PERF.md Open question 1). A level in runs cuts a group into
+        slices of ``LEVEL_SLICE`` (``kernels.level_slices``), looks an
+        anchor's key up in a table over the id range where that wins
+        (``id_bounds``), and fetches nothing here, so the host may
+        enumerate the next run meanwhile.
         """
         import jax.numpy as jnp
 
-        _M_DEVICE_CAND.observe(len(newcol))
-        dev = [self.tables.device_tables(pid, d)
-               for (_c, pid, d, _s) in adj]
-        glob_dev = to_device_i32(G) if G is not None else None
-        dummy = jnp.zeros(1, dtype=jnp.int32)
-        mask = np.zeros(len(newcol), dtype=bool)
-        # gid is non-decreasing by construction: one diff pass finds the
-        # group boundaries (no sort over millions of candidates)
-        bounds = np.flatnonzero(np.diff(gid)) + 1
-        starts = np.concatenate([[0], bounds])
-        ends = np.concatenate([bounds, [len(gid)]])
-        for lo, hi in zip(starts.tolist(), ends.tolist()):
-            g = int(gid[lo])
-            C = hi - lo
+        with span(tr, "wcoj.probe.stage"):
+            _M_DEVICE_CAND.observe(len(newcol))
+            dev = [self.tables.device_tables(pid, d)
+                   for (_c, pid, d, _s) in adj]
+            # a level in runs ships the list when its first group needs it
+            glob_dev = to_device_i32(G) if whole and G is not None else None
+            dummy = jnp.zeros(1, dtype=jnp.int32)
+            # gid is non-decreasing by construction: one diff pass finds
+            # the group boundaries (no sort over millions of candidates)
+            bounds = np.flatnonzero(np.diff(gid)) + 1
+            starts = np.concatenate([[0], bounds]).tolist()
+            ends = np.concatenate([bounds, [len(gid)]]).tolist()
+        calls = []  # one a call: its place, its slots, what it gave
+        passes = []  # (lo, hi): only the self-constraint, all pass
+        for glo, ghi in zip(starts, ends):
+            g = int(gid[glo])
             use_glob = G is not None and g != len(adj)
             adj_ids = [j for j in range(len(adj)) if j != g]
             if not adj_ids and not use_glob:
-                mask[lo:hi] = True  # only the self-constraint: all pass
+                passes.append((glo, ghi))
                 continue
-            Cp = pad_pow2(C)
-            valid = np.zeros(Cp, dtype=bool)
-            valid[:C] = True
-            cand = np.zeros(Cp, dtype=np.int32)
-            cand[:C] = newcol[lo:hi]  # ids < 2^31 (tables range-checked)
-            args = [jnp.asarray(valid), jnp.asarray(cand),
-                    glob_dev if use_glob else dummy]
-            depths = []
-            for j in adj_ids:
-                keys, offsets, edges, depth, _id_bound = dev[j]
-                avals = prefix[row_idx[lo:hi], adj[j][0]]
-                if len(avals):
-                    # anchors come from the PREFIX, which host-route
-                    # levels may have bound from never-range-checked host
-                    # tables — an unchecked int32 fill would silently
-                    # wrap ids past 2^31 and alias real keys (the
-                    # degrade-don't-truncate contract, like the tables)
-                    alo, ahi = int(avals.min()), int(avals.max())
-                    if alo < -(1 << 31) or ahi >= (1 << 31):
-                        raise DeviceRangeError(
-                            f"anchor values [{alo}, {ahi}] exceed int32 "
-                            "— host route required")
-                anchors = np.zeros(Cp, dtype=np.int32)
-                anchors[:C] = avals
-                args.extend([keys, offsets, edges, jnp.asarray(anchors)])
-                depths.append(depth)
-            fn = jit_level_probe(tuple(depths), use_glob)
-            t0 = get_usec()
-            mask[lo:hi] = np.asarray(fn(*args))[:C]  # blocking D2H sync
-            # candidate/anchor uploads + the mask back (device tables are
-            # cached residents and don't re-ship)
-            moved = Cp * (1 + 4 + 4 * len(adj_ids)) + C \
-                + (int(G.nbytes) if use_glob else 0)
-            rec = maybe_device_dispatch(
-                "wcoj.probe",
-                template="p" + "".join(map(str, depths))
-                + ("g" if use_glob else ""),
-                live=C, capacity=Cp, wall_us=get_usec() - t0,
-                nbytes=moved)
-            if rec is not None and q is not None:
-                rec["step"] = int(level)
-                dsteps = getattr(q, "device_steps", None)
-                if dsteps is None:
-                    dsteps = q.device_steps = []
-                dsteps.append(rec)
+            if use_glob and glob_dev is None:
+                glob_dev = to_device_i32(G)
+            depths = tuple(dev[j][3] for j in adj_ids)
+            fn = jit_level_probe(depths, use_glob, None if whole else tuple(
+                dev[j][4] for j in adj_ids))
+            n = ghi - glo
+            for slo, shi, Cp in ([(0, n, pad_pow2(n))] if whole
+                                 else level_slices(n)):
+                lo, hi = glo + slo, glo + shi
+                C = hi - lo
+                with span(tr, "wcoj.probe.stage"):
+                    valid = np.zeros(Cp, dtype=bool)
+                    valid[:C] = True
+                    cand = np.zeros(Cp, dtype=np.int32)
+                    cand[:C] = newcol[lo:hi]  # ids < 2^31 (range-checked)
+                    args = [jnp.asarray(valid), jnp.asarray(cand),
+                            glob_dev if use_glob else dummy]
+                    for j in adj_ids:
+                        keys, offsets, edges, _depth, _id_bound = dev[j]
+                        avals = prefix[row_idx[lo:hi], adj[j][0]]
+                        if len(avals):
+                            # anchors come from the PREFIX, which
+                            # host-route levels may have bound from
+                            # never-range-checked host tables — an
+                            # unchecked int32 fill would silently wrap
+                            # ids past 2^31 and alias real keys (the
+                            # degrade-don't-truncate contract, like the
+                            # tables)
+                            alo, ahi = int(avals.min()), int(avals.max())
+                            if alo < -(1 << 31) or ahi >= (1 << 31):
+                                raise DeviceRangeError(
+                                    f"anchor values [{alo}, {ahi}] exceed "
+                                    "int32 — host route required")
+                        anchors = np.zeros(Cp, dtype=np.int32)
+                        anchors[:C] = avals
+                        args.extend([keys, offsets, edges,
+                                     jnp.asarray(anchors)])
+                t0 = get_usec()
+                with span(tr, "wcoj.probe.dispatch"):
+                    if tr is not None:
+                        tr.event("device.dispatch", kernel="wk_level_probe")
+                    mask = fn(*args)
+                if whole:
+                    with span(tr, "wcoj.probe.sync"):
+                        mask = np.asarray(mask)  # blocking D2H sync
+                del args
+                # candidate/anchor uploads + the mask back (device tables
+                # are cached residents and don't re-ship)
+                calls.append({
+                    "lo": lo, "hi": hi, "slots": Cp, "mask": mask, "t0": t0,
+                    "wall_us": get_usec() - t0,
+                    "template": "p" + "".join(map(str, depths))
+                    + ("g" if use_glob else ""),
+                    "nbytes": Cp * (1 + 4 + 4 * len(adj_ids)) + C
+                    + (int(G.nbytes) if use_glob else 0)})
+        return {"calls": calls, "passes": passes, "whole": whole, "tr": tr,
+                "slots": sum(c["slots"] for c in calls)
+                + sum(hi - lo for lo, hi in passes)}
+
+    def _probe_finish(self, job: dict, n: int, q=None,
+                      level: int = 0) -> np.ndarray:
+        """The host boolean mask over the ``n`` unpadded candidates of what
+        ``_probe_start`` dispatched, its masks fetched where they were not
+        yet — identical semantics to the host probes."""
+        mask = np.zeros(n, dtype=bool)
+        for lo, hi in job["passes"]:
+            mask[lo:hi] = True
+        with span(None if job["whole"] else job["tr"], "wcoj.probe.sync"):
+            for c in job["calls"]:
+                C = c["hi"] - c["lo"]
+                mask[c["lo"]:c["hi"]] = np.asarray(c["mask"])[:C]
+                rec = maybe_device_dispatch(
+                    "wcoj.probe", template=c["template"], live=C,
+                    capacity=c["slots"], nbytes=c["nbytes"],
+                    wall_us=c["wall_us"] if job["whole"]
+                    else get_usec() - c["t0"])
+                if rec is not None and q is not None:
+                    rec["step"] = int(level)
+                    dsteps = getattr(q, "device_steps", None)
+                    if dsteps is None:
+                        dsteps = q.device_steps = []
+                    dsteps.append(rec)
         return mask
 
     # ------------------------------------------------------------------

@@ -98,6 +98,22 @@ def lubm_source(n_univ: int, seed: int, strings_dir: str) -> TripleSource:
                  lubm.lubm_attr_columns(n_univ, seed)))
 
 
+def snb_source(scale_factor: float, seed: int,
+               strings_dir: str) -> TripleSource:
+    """LDBC SNB as LSQB reads it (``loader/snb.py``), synthesized in memory
+    from ``seed``; the key holds a digest of the generator, because the
+    data are whatever that file makes of its parameters."""
+    from wukong_tpu.loader import snb
+
+    snb.write_string_tables(strings_dir, scale_factor, seed)
+    with open(snb.__file__, "rb") as f:
+        made_by = hashlib.sha256(f.read()).hexdigest()[:12]
+    return TripleSource(
+        {"generator": "snb", "scale_factor": scale_factor, "seed": seed,
+         "made_by": made_by}, strings_dir,
+        lambda: (snb.generate_snb(scale_factor, seed)[0], None))
+
+
 def layout_digest() -> str:
     pkg = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     h = hashlib.sha256()

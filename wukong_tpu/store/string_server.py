@@ -54,6 +54,13 @@ class StringServer:
                 self._virtual = VirtualWatdivStrings(meta["scale"], meta["seed"])
                 log_info(f"string server: virtual WatDiv backend "
                          f"(scale={meta['scale']}, seed={meta['seed']})")
+            elif meta.get("generator") == "snb":
+                from wukong_tpu.loader.snb import VirtualSnbStrings
+
+                self._virtual = VirtualSnbStrings(meta["scale_factor"],
+                                                  meta["seed"])
+                log_info(f"string server: virtual SNB backend (scale factor "
+                         f"{meta['scale_factor']}, seed={meta['seed']})")
             else:
                 raise ValueError(f"unknown virtual string backend: {meta}")
 
