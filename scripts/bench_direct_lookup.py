@@ -2,10 +2,11 @@
 """ns an element for what ``join/kernels.py:direct_lookup_wins`` weighs, on
 the device JAX finds: gather, scatter, fill and scan at 2^23, then both forms
 of the key lookup over a 13.9 M-key segment by frontier size (the shapes of
-LUBM-640's type segment). One JSON line an operation. The constants of
-``DIRECT_NS`` are this script's readings on a TPU v5 lite (PERF.md, PR 27);
-run it again through the chip tool before moving them. A CPU run times the
-CPU backend and is no source for them.
+LUBM-640's type segment), then both forms of a list's membership at the
+shapes of LSQB's level probe (PR 35). One JSON line an operation. The
+constants of ``DIRECT_NS`` are this script's readings on a TPU v5 lite
+(PERF.md, PR 27); run it again through the chip tool before moving them. A
+CPU run times the CPU backend and is no source for them.
 
     python scripts/bench_direct_lookup.py
 """
@@ -87,6 +88,26 @@ def main():
         K.direct_lookup_wins = rule
         print(json.dumps({"rows": rows, "rule_says_direct":
                           rule(rows, NK, BOUND)}), flush=True)
+    # a list's membership (``member_sorted_device``): the persons' and the
+    # comments' lists of LSQB at scale factor 3, under its vertex bound
+    vbound = 11_245_376
+    for n, lg in ((27_000, 21), (27_000, 23), (8_103_888, 23)):
+        rows = 1 << lg
+        lst_np = np.sort(rng.choice(vbound, n, replace=False)
+                         .astype(np.int32))
+        lst = jnp.asarray(lst_np)
+        vals = jnp.asarray(np.where(
+            rng.random(rows) < 0.6, lst_np[rng.integers(0, n, rows)],
+            rng.integers(0, vbound, rows)).astype(np.int32))
+        for form in (False, True):
+            K.direct_lookup_wins = lambda *_a, _f=form: _f
+            timeit(f"member {'direct' if form else 'search'} n={n} "
+                   f"rows=2^{lg}",
+                   lambda a, v: K.member_sorted_device(a, v, vbound),
+                   lst, vals, elems=n if form else rows * n.bit_length())
+        K.direct_lookup_wins = rule
+        print(json.dumps({"rows": rows, "list": n, "rule_says_direct":
+                          rule(rows, n, vbound)}), flush=True)
 
 
 if __name__ == "__main__":

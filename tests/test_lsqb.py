@@ -4,7 +4,10 @@ reference: the walk, the worst-case-optimal join with host and with device
 levels, a whole-plan template program, and the program's own choice; a level
 forced through more than one slice and more than one run of prefix rows; the
 spans, the ``join.level`` event and the counters of a traced reply; and the
-faults the scale-factor-10 deployment found, one test each."""
+faults the scale-factor-10 deployment found, one test each; and since PR 35
+the probe's lookups by a table over the id range in every level: the jitted
+probe beside ``level_probe_host`` on the patterns' own levels, both sides of
+``direct_lookup_wins`` reached and counted, one program for any list."""
 
 import os
 import sys
@@ -21,6 +24,7 @@ from wukong_tpu.join import kernels  # noqa: E402
 from wukong_tpu.join import wcoj as wcoj_mod  # noqa: E402
 from wukong_tpu.loader import snb  # noqa: E402
 from wukong_tpu.obs.metrics import get_registry  # noqa: E402
+from wukong_tpu.types import IN  # noqa: E402
 
 SF, SEED = 0.05, 1
 KNOBS = ("join_strategy", "join_device", "template_device",
@@ -161,37 +165,46 @@ def test_a_level_in_slices_and_runs_of_rows(world, name, monkeypatch):
 
 def test_a_level_of_one_run_probes_as_levels_always_did(world, monkeypatch):
     """Up to ``LEVEL_CHUNK_SLICES`` slices of candidates a level is one call
-    a generator group at the ``pad_pow2`` class of its candidates, by the
-    program that searches its keys (no ``id_bounds``), each mask fetched
-    before the next group is staged: what a LUBM heavy's first request
-    allocates on the device, and when, is what it was before LSQB (on the
-    chip q1 of ``lubm640-heavy`` read 2,166 ms for 1,924 with the sliced,
-    table-addressing, deferred form here). A level in runs takes that
-    form."""
+    a generator group at the ``pad_pow2`` class of its candidates, each mask
+    fetched before the next group is staged, as before LSQB; a level in runs
+    is cut into slices and fetched late. Since PR 35 either passes the
+    cached ``id_bound`` of every adjacency it probes, and the store's vertex
+    bound for its list: the form of a lookup follows the call's shapes
+    (``direct_lookup_wins``), not the level's size."""
     proxy, owed = world
     seen = []
     real = wcoj_mod.jit_level_probe
 
-    def spy(depths, has_glob, id_bounds=None):
-        seen.append(id_bounds)
-        return real(depths, has_glob, id_bounds)
+    def spy(depths, has_glob, id_bounds=None, list_bound=None):
+        seen.append((depths, has_glob, id_bounds, list_bound))
+        return real(depths, has_glob, id_bounds, list_bound)
 
     monkeypatch.setattr(wcoj_mod, "jit_level_probe", spy)
     Global.enable_tracing = True
+    vbound = wcoj_mod.store_vertex_bound(proxy.g)
+
+    def passes_its_bounds():
+        assert seen and not getattr(q, "_join_device_broken", False)
+        for depths, has_glob, bounds, list_bound in seen:
+            assert isinstance(bounds, tuple) and len(bounds) == len(depths)
+            assert all(isinstance(b, int) and 0 < b <= vbound for b in bounds)
+            assert list_bound == (vbound if has_glob else None)
+
     q = serve(proxy, "q3", "wcoj-device")
-    assert seen and all(b is None for b in seen)
+    passes_its_bounds()
     calls = [d for d in q.device_steps if d.get("site") == "wcoj.probe"]
     assert calls and all(d["capacity"] == kernels.pad_pow2(d["live"])
                          for d in calls)
     names = [sp.name for sp in q.trace.spans]
     at = [i for i, n in enumerate(names) if n == "wcoj.probe.dispatch"]
     assert at and all(names[i + 1] == "wcoj.probe.sync" for i in at)
+    whole = list(seen)
     del seen[:]
     monkeypatch.setattr(kernels, "LEVEL_SLICE", 2048)
     q = serve(proxy, "q3", "wcoj-device")
     assert np.array_equal(rows_of(q), owed["q3"])
-    assert any(isinstance(b, tuple) and None not in b for b in seen)
-    assert any(b is None for b in seen)  # its small levels still whole
+    passes_its_bounds()
+    assert set(seen) == set(whole)  # the same programs, at other classes
 
 
 @pytest.mark.parametrize("n,want", [
@@ -232,9 +245,9 @@ def test_row_chunks(counts, limit_slices, want, monkeypatch):
     assert got[0][0] == 0 and got[-1][1] == len(counts)
 
 
-def _family(name):
+def _family(name, label="route"):
     snap = get_registry().snapshot()
-    return {s["labels"]["route"]: s["value"]
+    return {s["labels"][label]: s["value"]
             for s in (snap.get(name) or {}).get("series", [])}
 
 
@@ -264,10 +277,14 @@ def test_spans_event_and_counters_of_a_traced_reply(world, name):
     assert len(events) == len(levels)
     routes = set()
     for (_n, a), lv, sp in zip(events, q.join_stats, levels):
-        assert set(a) == {"var", "candidates", "slots", "rows_out", "route"}
+        assert set(a) == {"var", "candidates", "slots", "rows_out", "route",
+                          "direct", "searched"}
         assert (a["var"], a["candidates"], a["slots"], a["rows_out"],
-                a["route"]) == (lv["var"], lv["candidates"], lv["slots"],
-                                lv["rows_out"], lv["route"])
+                a["route"], a["direct"], a["searched"]) == (
+                    lv["var"], lv["candidates"], lv["slots"], lv["rows_out"],
+                    lv["route"], lv["direct"], lv["searched"])
+        if a["route"] == "host":
+            assert a["direct"] == a["searched"] == 0
         inside = [s.name for s in spans if s.parent == sp.index]
         assert inside[0] == "wcoj.enumerate"
         # a device level stages; it dispatches and syncs unless its one
@@ -331,3 +348,102 @@ def test_a_probe_that_addresses_its_keys_equals_one_that_searches(rows):
         got = np.asarray(fn(jnp.asarray(valid), *dev))
         assert np.array_equal(got, want), bounds
     assert want.any() and not want.all()
+
+
+def _lookup_forms():
+    got = _family("wukong_join_probe_lookups_total", "form")
+    return got.get("direct", 0), got.get("search", 0)
+
+
+@pytest.mark.parametrize("name", ["q2", "q3"])
+def test_the_probe_with_tables_equals_the_host_probe_level_by_level(
+        world, name, monkeypatch):
+    """Every call of the jitted probe that a pattern's levels make, with the
+    bounds the executor passes, equals ``level_probe_host`` on the same
+    padded tensors; the forms the executor counts are the forms the programs
+    were traced with (one scatter a table)."""
+    import jax
+
+    proxy, owed = world
+    real = wcoj_mod.jit_level_probe
+    calls, scatters = [], []
+
+    def spy(depths, has_glob, id_bounds=None, list_bound=None):
+        fn = real(depths, has_glob, id_bounds, list_bound)
+
+        def probe(*args):
+            got = fn(*args)
+            host = [np.asarray(a) for a in args]
+            want = kernels.level_probe_host(
+                host[0], host[1], host[2] if has_glob else None, *host[3:])
+            assert np.array_equal(np.asarray(got), want), (depths, id_bounds)
+            calls.append(len(depths) + has_glob)
+            scatters.append(str(jax.make_jaxpr(fn)(*args)).count(" scatter["))
+            return got
+
+        return probe
+
+    monkeypatch.setattr(wcoj_mod, "jit_level_probe", spy)
+    before = _lookup_forms()
+    q = serve(proxy, name, "wcoj-device")
+    assert np.array_equal(rows_of(q), owed[name])
+    assert not getattr(q, "_join_device_broken", False)
+    direct = sum(lv["direct"] for lv in q.join_stats)
+    searched = sum(lv["searched"] for lv in q.join_stats)
+    assert len(calls) >= len(q.join_stats) - 1
+    assert (direct + searched, direct) == (sum(calls), sum(scatters))
+    now = _lookup_forms()
+    assert (now[0] - before[0], now[1] - before[1]) == (direct, searched)
+    # at these sizes every lookup of every level addresses a table
+    assert direct > 0 and searched == 0
+
+
+def test_both_sides_of_the_rule_are_reached_and_counted(world, monkeypatch):
+    """q2 taken whole addresses a table in every lookup; cut into slices of
+    2,048 candidates, a slice over the list of some 1.6 x 10^5 comments
+    searches it (the scatter of the list would cost more than 18 rounds over
+    2,048 slots) while the 900 keys of ``knows`` stay a table: both forms
+    in one reply, the rows the same."""
+    proxy, owed = world
+    comments = len(proxy.g.get_index(snb.T["Comment"], IN))
+    vbound = wcoj_mod.store_vertex_bound(proxy.g)
+    assert kernels.direct_lookup_wins(1 << 18, comments, vbound)
+    assert not kernels.direct_lookup_wins(2048, comments, vbound)
+    before = _lookup_forms()
+    whole = serve(proxy, "q2", "wcoj-device")
+    mid = _lookup_forms()
+    assert mid[0] > before[0] and mid[1] == before[1]
+    monkeypatch.setattr(kernels, "LEVEL_SLICE", 2048)
+    cut = serve(proxy, "q2", "wcoj-device")
+    assert np.array_equal(rows_of(cut), rows_of(whole))
+    after = _lookup_forms()
+    direct = sum(lv["direct"] for lv in cut.join_stats)
+    searched = sum(lv["searched"] for lv in cut.join_stats)
+    assert direct > 0 and searched > 0
+    assert (after[0] - mid[0], after[1] - mid[1]) == (direct, searched)
+
+
+def test_one_probe_program_for_any_list_of_a_length():
+    """The table's bound is the store's, not the list's: two lists of one
+    length and different last ids run one compiled program (the length
+    specialises it, as it always did; the values must not)."""
+    import jax.numpy as jnp
+
+    bound, rows = 5_000, 4096
+    rng = np.random.default_rng(35)
+    low = np.sort(rng.choice(1_000, 300, replace=False))
+    high = np.sort(rng.choice(np.arange(2_000, bound), 300, replace=False))
+    assert low[-1] != high[-1]
+    assert kernels.direct_lookup_wins(rows, 300, bound)
+    fn = kernels.jit_level_probe((), True, (), bound)
+    assert fn is kernels.jit_level_probe((), True, None, bound)
+    was = fn._cache_size()
+    cand = rng.integers(-10, bound + 10, rows)
+    valid = rng.random(rows) < 0.9
+    for lst in (low, high):
+        got = np.asarray(fn(jnp.asarray(valid),
+                            jnp.asarray(cand.astype(np.int32)),
+                            jnp.asarray(lst.astype(np.int32))))
+        assert np.array_equal(got, kernels.level_probe_host(valid, cand, lst))
+        assert got.any()
+    assert fn._cache_size() == was + 1

@@ -230,3 +230,32 @@ def test_level_probe_compiles_at_lsqb_sizes(one_chip, case):
     compiled = _compile(fn.lower(valid, i(S), *args), f"wk_level_probe[{case}]",
                         False)
     assert compiled.memory_analysis().temp_size_in_bytes < 512 << 20
+
+
+@pytest.mark.parametrize("case", ["q2_last_level", "q2_comments"])
+def test_level_probe_of_one_run_addresses_tables_at_lsqb_sizes(one_chip, case):
+    """A level of one run at LSQB's scale factor 3 (2^23 slots): q2's last
+    level looks the post's author up among ``knows``' 27,000 keys and the
+    candidate in the persons' list of 27,000; its third level looks the
+    candidate up in the comments' list of 8,103,888. Since PR 35 each is a
+    table over the id range (``direct_lookup_wins`` at these shapes), so the
+    program holds no ``while`` (``searchsorted``'s loop: 15 and 23 rounds of
+    a gather a slot), and its temporaries stay under 0.3 GiB."""
+    from wukong_tpu.join import kernels
+
+    i = partial(_i32, one_chip)
+    S, vbound = 1 << 23, 11_245_376
+    valid = jax.ShapeDtypeStruct((S,), jnp.bool_, sharding=one_chip)
+    if case == "q2_last_level":
+        assert kernels.direct_lookup_wins(S, 27_000, 158_072)
+        assert kernels.direct_lookup_wins(S, 27_000, vbound)
+        fn = kernels.jit_level_probe((11,), True, (158_072,), vbound)
+        args = [i(27_000), i(27_000), i(27_001), i(1_090_334), i(S)]
+    else:
+        assert kernels.direct_lookup_wins(S, 8_103_888, vbound)
+        fn = kernels.jit_level_probe((), True, (), vbound)
+        args = [i(8_103_888)]
+    compiled = _compile(fn.lower(valid, i(S), *args),
+                        f"wk_level_probe[{case}]", False)
+    assert "while" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 300 << 20

@@ -33,7 +33,7 @@ from wukong_tpu.join.kernels import (
     pair_member,
 )
 from wukong_tpu.join.qgraph import analyze
-from wukong_tpu.join.wcoj import WCOJExecutor
+from wukong_tpu.join.wcoj import WCOJExecutor, store_vertex_bound
 from wukong_tpu.loader.datagen import (
     CyclicStrings,
     cyclic_query_text,
@@ -480,6 +480,48 @@ def test_table_cache_invalidates_on_store_version_bump(tri_proxy):
     assert rows_of(q) - rows_of(base) == {(a, b, c)}
 
 
+def test_vertex_bound_follows_the_store_version_not_a_list(tri_proxy):
+    """The bound of the table a candidate list is marked in is the store's
+    (one past its largest keyed or indexed vertex), cached per store version
+    beside the tables: the same for every list, new after a write that adds
+    a larger vertex; a sharded view's is the largest of its shards'."""
+    from wukong_tpu.join.dist import ShardedJoinView
+    from wukong_tpu.store.dynamic import insert_triples
+    from wukong_tpu.types import NORMAL_ID_START
+
+    proxy, _text = tri_proxy
+    g, tables = proxy.g, proxy.wcoj().tables
+    top = max(int(seg.keys[-1]) for seg in g.segments.values())
+    assert tables.vertex_bound() == store_vertex_bound(g) == top + 1
+    entries = tables.stats()["entries"]
+    assert tables.vertex_bound() == top + 1  # a hit: nothing is added
+    assert tables.stats()["entries"] == entries
+    a = NORMAL_ID_START + 70_001
+    assert a > top
+    insert_triples(g, np.asarray([[a, 2, a + 1]], dtype=np.int64))
+    assert tables.vertex_bound() == store_vertex_bound(g) == a + 2
+    assert ShardedJoinView([g, g]).vertex_bound() == a + 2
+    assert ShardedJoinView([]).vertex_bound() == 0
+
+
+@pytest.mark.parametrize("lst,fits", [
+    ([], True),
+    ([0, 3, 9], True),
+    ([3, 3, 9], True),  # a repeated id marks its flag twice
+    ([-1, 3], False),
+    ([3, 10], False),  # past the store's bound: it would be dropped
+    ([9], True),
+])
+def test_a_list_the_table_cannot_hold_is_searched(lst, fits):
+    class Tables:
+        def vertex_bound(self):
+            return 10
+
+    ex = WCOJExecutor(None, tables=Tables())
+    got = ex._list_bound(np.asarray(lst, dtype=np.int64))
+    assert got == (10 if fits else None)
+
+
 # ---------------------------------------------------------------------------
 # the join-strategy analysis gate
 # ---------------------------------------------------------------------------
@@ -603,6 +645,60 @@ def test_kernels_level_probe_all_padding_and_singletons():
         assert np.array_equal(got, want), C
         if C == 0:
             assert not got.any()  # all-padding: nothing may pass
+
+
+# the list's membership on the device: (the sorted list, the candidates, the
+# id bound, whether ``direct_lookup_wins`` gives the table at these shapes)
+_RNG = np.random.default_rng(35)
+_LIST = np.unique(_RNG.integers(0, 200, 60))
+MEMBER_CASES = {
+    "empty_list": (np.empty(0, np.int64), _RNG.integers(0, 200, 64), 200,
+                   None),
+    "one_id": (np.array([17]), np.array([16, 17, 18, 17, 0, 199]), 200, True),
+    "ids_at_0_and_at_the_bound": (
+        np.array([0, 5, 199]), np.array([0, 1, 5, 198, 199, 200, 201, -1]),
+        200, True),
+    "candidates_outside_the_range": (
+        _LIST, _RNG.integers(-50, 300, 256), 200, True),
+    "negative_candidates": (_LIST, -_RNG.integers(1, 1 << 30, 64), 200, True),
+    "all_padding": (_LIST, np.zeros(128, np.int64), 200, True),
+    "list_longer_than_the_candidates": (
+        np.arange(0, 300, 2), np.array([0, 1, 298, 299]), 300, False),
+    "no_bound": (_LIST, _RNG.integers(0, 200, 64), None, False),
+    "a_repeated_id": (np.array([3, 3, 9, 9, 9, 150]),
+                      _RNG.integers(0, 200, 64), 200, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(MEMBER_CASES))
+def test_kernels_member_on_the_device_equals_member_sorted(case):
+    """``member_sorted_device``: a table over the id range where the rule
+    gives it (one scatter in the traced program), the search where it does
+    not (none); either equals ``member_sorted`` slot for slot."""
+    import jax
+
+    from wukong_tpu.join.kernels import (
+        direct_lookup_wins,
+        member_sorted_device,
+        to_device_i32,
+    )
+
+    lst, cand, bound, direct = MEMBER_CASES[case]
+    want = member_sorted(lst.astype(np.int64), cand.astype(np.int64))
+    if direct is not None and bound is not None:
+        assert direct_lookup_wins(len(cand), len(lst), bound) == direct
+
+    def fn(a, v):
+        return member_sorted_device(a, v, bound)
+
+    args = to_device_i32(lst), to_device_i32(cand)
+    got = np.asarray(jax.jit(fn)(*args))
+    assert got.dtype == bool and np.array_equal(got, want)
+    assert str(jax.make_jaxpr(fn)(*args)).count(" scatter[") == bool(direct)
+    if case in ("ids_at_0_and_at_the_bound", "one_id"):
+        assert want.any() and not want.all()
+    if case in ("negative_candidates", "empty_list"):
+        assert not want.any()
 
 
 def test_kernels_depth_bounded_pair_member_parity():

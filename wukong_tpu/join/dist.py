@@ -34,7 +34,7 @@ import numpy as np
 
 from wukong_tpu.analysis.lockdep import declare_leaf, make_lock
 from wukong_tpu.config import Global
-from wukong_tpu.join.wcoj import WCOJExecutor
+from wukong_tpu.join.wcoj import WCOJExecutor, store_vertex_bound
 from wukong_tpu.obs.metrics import get_registry
 from wukong_tpu.runtime import faults
 from wukong_tpu.runtime.resilience import check_query
@@ -177,6 +177,11 @@ class ShardedJoinView:
             merged = CSRSegment.from_sorted_pairs(k2[keep], e2[keep])
             self._memo[key] = merged
             return merged
+
+    def vertex_bound(self) -> int:
+        """One past the largest vertex id any shard keys or indexes (every
+        vertex is a key on its owner)."""
+        return max((store_vertex_bound(st) for st in self.stores), default=0)
 
     def get_index(self, tpid: int, d: int) -> np.ndarray:
         """Global index list: each member lives on exactly one shard, so

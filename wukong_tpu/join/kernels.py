@@ -8,10 +8,13 @@ gather). The host path runs them as plain NumPy; the device path wraps them
 in ``jax.jit``. That they trace does not make them fast on the chip: a
 sorted search is ``log2(N)`` dependent gather rounds a row, and a gather is
 the slowest thing a TPU does an element (the readings are beside
-``DIRECT_NS``). Where the whole-plan template program searched a dense
-integer domain it addresses the domain instead: :func:`expand_padded_device`
-and :func:`lookup_ranges_device`, with the NumPy forms as their parity
-oracle.
+``DIRECT_NS``). Where a device program searched a dense integer domain it
+addresses the domain instead, by one rule on static shapes
+(:func:`direct_lookup_wins`): the whole-plan template programs
+(:func:`expand_padded_device`, :func:`lookup_ranges_device`) and, in every
+level, the join's level probe (:func:`jit_level_probe`: the anchors' keys by
+:func:`lookup_ranges_device`, the class list by
+:func:`member_sorted_device`), with the NumPy forms as their parity oracle.
 
 Data model: adjacency is the store's CSR triplet (sorted unique ``keys``,
 ``offsets``, ``edges`` sorted within each key run); candidate sets are
@@ -251,13 +254,14 @@ def level_slices(n: int) -> list:
 
 
 # jitted level-probe variants keyed on (per-adjacency depths, has_glob,
-# per-adjacency id bounds): the candidate tensor shape is handled by
-# pad_pow2 bucketing and LEVEL_SLICE, so the cache stays small
+# per-adjacency id bounds, the list's id bound): the candidate tensor shape
+# is handled by pad_pow2 bucketing and LEVEL_SLICE, so the cache stays small
 _LEVEL_PROBE_CACHE: dict = {}
 
 
 def jit_level_probe(adj_depths: tuple, has_glob: bool,
-                    id_bounds: tuple | None = None):
+                    id_bounds: tuple | None = None,
+                    list_bound: int | None = None):
     """The fused XLA probe for one WCOJ generator group: a padded flat
     candidate tensor is masked by every LISTED constraint in one compiled
     call — global sorted-list membership plus one ragged pair probe per
@@ -267,11 +271,19 @@ def jit_level_probe(adj_depths: tuple, has_glob: bool,
     actually needs (a generator's self-probe is true by construction and
     is elided), and ``adj_depths[j]`` is adjacency j's binary-search
     iteration bound (log2(max_degree)+1, cached with its device table).
-    ``id_bounds[j]`` (the segment's last key + 1, cached beside it) lets
-    the anchors' key lookup address a table over the id range where
-    :func:`direct_lookup_wins` says so: at 2^22 candidates over the 73,000
-    keys of LSQB's ``knows`` the search is 17 rounds of a gather a
-    candidate, the table one.
+
+    Every lookup of the probe takes the form :func:`direct_lookup_wins`
+    picks from the static shapes, in every level: ``id_bounds[j]`` (the
+    segment's last key + 1, cached beside its tables) lets the anchors'
+    key lookup address a table over the id range
+    (:func:`lookup_ranges_device`), ``list_bound`` (the store's vertex id
+    bound, ``JoinTableCache.vertex_bound``) lets the list's membership
+    mark the list in one (:func:`member_sorted_device`). At 2^23
+    candidates the key of LSQB's ``knows`` among 27,000 and the persons'
+    list of 27,000 were 15 rounds of a gather a candidate each, the
+    comments' list of 8.1 M 23; a table is one. Both tables are
+    temporaries of the call. Without a bound (``None``) a lookup searches;
+    a small level over a big segment searches by the rule.
 
     Signature of the returned fn:
         fn(valid, cand, glob, k0, o0, e0, a0, k1, o1, e1, a1, ...) -> mask
@@ -287,7 +299,9 @@ def jit_level_probe(adj_depths: tuple, has_glob: bool,
     depths = tuple(int(d) for d in adj_depths)
     bounds = (None,) * len(depths) if id_bounds is None \
         else tuple(None if b is None else int(b) for b in id_bounds)
-    key = (depths, bool(has_glob), bounds)
+    list_bound = None if list_bound is None or not has_glob \
+        else int(list_bound)
+    key = (depths, bool(has_glob), bounds, list_bound)
     fn = _LEVEL_PROBE_CACHE.get(key)
     if fn is not None:
         return fn
@@ -296,7 +310,7 @@ def jit_level_probe(adj_depths: tuple, has_glob: bool,
         with jax.named_scope("wk_level_probe"):
             mask = valid
             if has_glob:
-                mask = mask & member_sorted(glob, cand, xp=jnp)
+                mask = mask & member_sorted_device(glob, cand, list_bound)
             for j, depth in enumerate(depths):
                 keys, offsets, edges, anchors = adj[4 * j: 4 * j + 4]
                 mask = mask & pair_member(keys, offsets, edges, anchors,
@@ -501,6 +515,45 @@ def lookup_ranges_device(keys, offsets, vids, id_bound: int):
     start = jnp.where(found, offsets[slot], 0)
     deg = jnp.where(found, offsets[slot + 1] - offsets[slot], 0)
     return start, deg
+
+
+def member_sorted_device(sorted_arr, vals, id_bound: int | None):
+    """:func:`member_sorted` for the chip (``jax.numpy`` only): the same
+    mask a slot, by the form :func:`direct_lookup_wins` picks at trace time
+    from ``(len(vals), len(sorted_arr), id_bound)``.
+
+    Direct form: ``sorted_arr`` (non-decreasing ids inside ``[0,
+    id_bound)``: the caller's to see to, ``WCOJExecutor._list_bound``) is
+    marked in a table of ``id_bound`` flags and a value answered with one
+    gather; a value outside ``[0, id_bound)`` is absent. The scatter is
+    told its indices are sorted and not that they are unique: a store
+    written without dedup may repeat an id, and on the chip the promise
+    bought nothing (68.7 ms either way for 27,000 ids, 122.0 for 8.1 M,
+    at 2^23 values; the search took 978 and 1,746: my chip run, PR 35).
+    The flags are ``bool``: as ``int32`` the same calls took 61.1 and 108.8
+    ms, for a temporary four times the size.
+    ``id_bound`` is static and must not follow the list's values (the
+    store's vertex id bound, the same for every list of a store version:
+    ``sorted_arr[-1] + 1`` would be a compile a draw); ``None`` searches.
+    As in :func:`lookup_ranges_device` the table is a temporary of the
+    program, tied to its operands so that it is not built before they
+    exist.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    n = int(sorted_arr.shape[0])
+    rows = int(vals.shape[0])
+    if n == 0:
+        return jnp.zeros(rows, dtype=bool)
+    if id_bound is None or not direct_lookup_wins(rows, n, int(id_bound)):
+        return member_sorted(sorted_arr, vals, xp=jnp)
+    id_bound = int(id_bound)
+    sorted_arr, vals = jax.lax.optimization_barrier((sorted_arr, vals))
+    table = jnp.zeros(id_bound, dtype=bool).at[sorted_arr].set(
+        True, mode="drop", indices_are_sorted=True)
+    return (vals >= 0) & (vals < id_bound) \
+        & table[jnp.clip(vals, 0, id_bound - 1)]
 
 
 def unique_rows_padded(ca, cb, valid, xp=np):

@@ -43,6 +43,7 @@ from wukong_tpu.config import Global
 from wukong_tpu.join import kernels
 from wukong_tpu.join.kernels import (
     DeviceRangeError,
+    direct_lookup_wins,
     expand_ragged,
     intersect_many,
     jit_level_probe,
@@ -103,6 +104,14 @@ _M_LEVEL_SLOTS = get_registry().counter(
     "Slots probed by WCOJ levels (padded on the device route)",
     labels=("route",))
 
+# which form each lookup of a device level's probe calls took (a call looks
+# up the keys of each adjacency it probes and the list, where it has one):
+# a table over the id range or the sorted search, by
+# ``kernels.direct_lookup_wins`` on the shapes the program was traced at
+_M_PROBE_LOOKUPS = get_registry().counter(
+    "wukong_join_probe_lookups_total",
+    "Lookups of the WCOJ level probe's calls by form", labels=("form",))
+
 #: a level of more candidates than this many slices of ``LEVEL_SLICE`` is
 #: enumerated and probed a run of prefix rows at a time
 LEVEL_CHUNK_SLICES = 4
@@ -154,6 +163,18 @@ def _sorted_index(arr) -> np.ndarray:
     if len(a) > 1 and not bool((a[1:] >= a[:-1]).all()):
         a = np.unique(a)
     return a
+
+
+def store_vertex_bound(g) -> int:
+    """One past the largest vertex id that is a key of a segment or a member
+    of an index list of the partition ``g``: every subject, and every object
+    that is not a type. It moves only with a write. (Here and not a method
+    of ``GStore``: ``store/gstore.py`` is a layout source of the key of
+    every saved store bundle, ``runtime/boot.py``.)"""
+    last = [int(a[-1]) for a in (
+        *(seg.keys for seg in g.segments.values()), *g.index.values(),
+        g.v_set) if len(a)]
+    return max(last, default=-1) + 1
 
 
 class JoinTableCache:
@@ -282,6 +303,21 @@ class JoinTableCache:
                                to_device_i32(seg.edges),
                                max(max_deg, 1).bit_length() + 1,
                                int(seg.keys[-1]) + 1 if len(seg.keys) else 0))
+
+    def vertex_bound(self) -> int:
+        """The store's vertex id bound (:func:`store_vertex_bound`; a
+        sharded view answers for its shards), cached beside the tables per
+        store version: the static size of the table
+        ``kernels.member_sorted_device`` marks a candidate list in. The
+        same for every list, so a probe program specialises on it once (a
+        list's own last id would be a compile a draw)."""
+        key = (self._version(), "vbound")
+        hit = self._get(key)
+        if hit is not None:
+            return hit
+        of_view = getattr(self.g, "vertex_bound", None)
+        return self._put(key, int(of_view() if of_view is not None
+                                  else store_vertex_bound(self.g)))
 
     def clear(self) -> None:
         with self._lock:
@@ -523,7 +559,8 @@ class WCOJExecutor:
 
         device = route == "device"
         probes = len(adj) + (1 if G is not None else 0)
-        state = {"candidates": 0, "slots": 0, "route": "host"}
+        state = {"candidates": 0, "slots": 0, "route": "host",
+                 "direct": 0, "searched": 0}
         kept_rows, kept_vals = [], []
 
         def host_mask(row_idx, newcol):
@@ -554,6 +591,8 @@ class WCOJExecutor:
                 try:
                     mask = self._probe_finish(job, len(newcol), q, k)
                     state["slots"] += job["slots"]
+                    state["direct"] += job["direct"]
+                    state["searched"] += job["searched"]
                     state["route"] = "device"
                 except Exception as e:
                     device_failed(e)
@@ -591,6 +630,9 @@ class WCOJExecutor:
         _M_DEVICE_LEVELS.labels(route=lvl_route).inc()
         _M_LEVEL_CAND.labels(route=lvl_route).inc(state["candidates"])
         _M_LEVEL_SLOTS.labels(route=lvl_route).inc(state["slots"])
+        if lvl_route == "device":
+            _M_PROBE_LOOKUPS.labels(form="direct").inc(state["direct"])
+            _M_PROBE_LOOKUPS.labels(form="search").inc(state["searched"])
         row_idx = np.concatenate(kept_rows) if kept_rows else \
             np.empty(0, dtype=np.int64)
         newcol = np.concatenate(kept_vals) if kept_vals else \
@@ -600,10 +642,12 @@ class WCOJExecutor:
         if tr is not None:
             tr.event("join.level", var=int(v),
                      candidates=state["candidates"], slots=state["slots"],
-                     rows_out=len(new_prefix), route=lvl_route)
+                     rows_out=len(new_prefix), route=lvl_route,
+                     direct=state["direct"], searched=state["searched"])
         return new_prefix, {"candidates": state["candidates"],
                             "slots": state["slots"], "probes": probes,
-                            "route": lvl_route}
+                            "route": lvl_route, "direct": state["direct"],
+                            "searched": state["searched"]}
 
     def _enumerate(self, adj, G, ranges, choice, lo: int, hi: int, k: int,
                    want_gid: bool):
@@ -669,16 +713,16 @@ class WCOJExecutor:
         ``whole`` (the level is one run: up to ``LEVEL_CHUNK_SLICES``
         slices of candidates, 2^24, the largest level any cell ran before
         LSQB's) probes as levels always were: a group is ONE call at the
-        ``pad_pow2`` class of its candidates, by the program that searches
-        its keys, and its mask is fetched before the next group's tensors
-        are built. What a request allocates on the device, and when, is
-        then what it always was: a LUBM heavy's first request takes this
-        route, and q1's time there follows where later buffers come to
-        lie (PERF.md Open question 1). A level in runs cuts a group into
-        slices of ``LEVEL_SLICE`` (``kernels.level_slices``), looks an
-        anchor's key up in a table over the id range where that wins
-        (``id_bounds``), and fetches nothing here, so the host may
-        enumerate the next run meanwhile.
+        ``pad_pow2`` class of its candidates, and its mask is fetched
+        before the next group's tensors are built. A level in runs cuts a
+        group into slices of ``LEVEL_SLICE`` (``kernels.level_slices``) and
+        fetches nothing here, so the host may enumerate the next run
+        meanwhile. In either, every lookup of a call (an anchor's key, the
+        list) takes the form ``kernels.direct_lookup_wins`` picks from the
+        call's shapes: the cached ``id_bound`` of each adjacency and the
+        list's bound (``_list_bound``) go to ``jit_level_probe`` whatever
+        the level's size, and the job counts the forms (``direct``,
+        ``searched``) by the same rule on the same shapes.
         """
         import jax.numpy as jnp
 
@@ -687,7 +731,9 @@ class WCOJExecutor:
             dev = [self.tables.device_tables(pid, d)
                    for (_c, pid, d, _s) in adj]
             # a level in runs ships the list when its first group needs it
-            glob_dev = to_device_i32(G) if whole and G is not None else None
+            glob_dev = list_bound = None
+            if whole and G is not None:
+                glob_dev, list_bound = to_device_i32(G), self._list_bound(G)
             dummy = jnp.zeros(1, dtype=jnp.int32)
             # gid is non-decreasing by construction: one diff pass finds
             # the group boundaries (no sort over millions of candidates)
@@ -695,6 +741,7 @@ class WCOJExecutor:
             starts = np.concatenate([[0], bounds]).tolist()
             ends = np.concatenate([bounds, [len(gid)]]).tolist()
         calls = []  # one a call: its place, its slots, what it gave
+        direct = lookups = 0  # the calls' lookups, and those by a table
         passes = []  # (lo, hi): only the self-constraint, all pass
         for glo, ghi in zip(starts, ends):
             g = int(gid[glo])
@@ -704,10 +751,11 @@ class WCOJExecutor:
                 passes.append((glo, ghi))
                 continue
             if use_glob and glob_dev is None:
-                glob_dev = to_device_i32(G)
+                glob_dev, list_bound = to_device_i32(G), self._list_bound(G)
             depths = tuple(dev[j][3] for j in adj_ids)
-            fn = jit_level_probe(depths, use_glob, None if whole else tuple(
-                dev[j][4] for j in adj_ids))
+            fn = jit_level_probe(depths, use_glob,
+                                 tuple(dev[j][4] for j in adj_ids),
+                                 list_bound)
             n = ghi - glo
             for slo, shi, Cp in ([(0, n, pad_pow2(n))] if whole
                                  else level_slices(n)):
@@ -749,6 +797,12 @@ class WCOJExecutor:
                     with span(tr, "wcoj.probe.sync"):
                         mask = np.asarray(mask)  # blocking D2H sync
                 del args
+                # the forms the program took, by the rule it was traced by
+                lookups += len(adj_ids) + use_glob
+                direct += sum(direct_lookup_wins(Cp, int(dev[j][0].shape[0]),
+                                                 dev[j][4]) for j in adj_ids)
+                if use_glob and list_bound is not None:
+                    direct += direct_lookup_wins(Cp, len(G), list_bound)
                 # candidate/anchor uploads + the mask back (device tables
                 # are cached residents and don't re-ship)
                 calls.append({
@@ -759,8 +813,21 @@ class WCOJExecutor:
                     "nbytes": Cp * (1 + 4 + 4 * len(adj_ids)) + C
                     + (int(G.nbytes) if use_glob else 0)})
         return {"calls": calls, "passes": passes, "whole": whole, "tr": tr,
+                "direct": int(direct), "searched": int(lookups - direct),
                 "slots": sum(c["slots"] for c in calls)
                 + sum(hi - lo for lo, hi in passes)}
+
+    def _list_bound(self, G: np.ndarray) -> int | None:
+        """The id bound under which the probe may mark the sorted candidate
+        list ``G`` in a table over the id range
+        (``kernels.member_sorted_device``): the store's vertex bound, where
+        ``G`` lies inside ``[0, bound)``; else ``None``, and the probe
+        searches the list as the host does (an edge to a vertex that is
+        nobody's key here would be dropped from the table)."""
+        bound = self.tables.vertex_bound()
+        if len(G) and (int(G[0]) < 0 or int(G[-1]) >= bound):
+            return None
+        return bound
 
     def _probe_finish(self, job: dict, n: int, q=None,
                       level: int = 0) -> np.ndarray:
