@@ -425,11 +425,11 @@ def kernels_phase(smoke: Smoke, eng, g, on_tpu: bool) -> None:
             jnp.int32(n), jnp.ones(cap_in, bool))
     mark = smoke.compile_mark()
     t0 = time.perf_counter()
-    got = jax.device_get(tpu_stream.stream_expand(
+    got = jax.device_get(tpu_stream.wk_walk_merge_stream_expand(
         *args, cap_out=cap_out, interpret=not on_tpu,
         mhot=tpu_stream.mhot_enabled(), mdup=tpu_stream.stream_mdup()))
     stream_s = time.perf_counter() - t0
-    want = jax.device_get(K.merge_expand(*args, cap_out=cap_out))
+    want = jax.device_get(K.wk_walk_merge_expand(*args, cap_out=cap_out))
     same = smoke.check(
         int(got[3]) == total and all(np.array_equal(a, b)
                                      for a, b in zip(got, want)),

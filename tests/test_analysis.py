@@ -806,15 +806,96 @@ def test_device_gate_fixtures(tmp_path):
         # definition-only module, justified in the allowlist
         "engine/kernels.py": (
             "import jax\n"
-            "compact = jax.jit(lambda x: x)\n"),
+            "def wk_walk_compact(x):\n"
+            "    return x\n"
+            "compact = jax.jit(wk_walk_compact)\n"),
         # invoking module charges the seam itself
         "engine/run.py": (
             "import jax\n"
-            "def run(fn, x):\n"
-            "    out = jax.jit(fn)(x)\n"
+            "def run(wk_walk_fn, x):\n"
+            "    out = jax.jit(wk_walk_fn)(x)\n"
             "    maybe_device_dispatch('engine.run', live=1)\n"
             "    return out\n")})
     assert run_analysis(good, plugins=["device-telemetry"]) == []
+
+
+_NAMED_OK = (
+    "import functools\n"
+    "import jax\n"
+    "from jax import shard_map\n"
+    "@jax.jit\n"
+    "def wk_walk_a(x):\n"
+    "    return x\n"
+    "@functools.partial(jax.jit, static_argnames=('n',))\n"
+    "def wk_walk_b(x, n):\n"
+    "    return x\n"
+    "def mint(mesh):\n"
+    "    def wk_dist_chain(x):\n"
+    "        return x\n"
+    "    c = jax.jit(jax.vmap(wk_dist_chain))\n"
+    "    d = functools.partial(jax.jit, static_argnames=())(wk_dist_chain)\n"
+    "    return c, d, jax.jit(shard_map(wk_dist_chain, mesh=mesh))\n"
+    "def wk_template(x):\n"
+    "    return x\n"
+    "wk_template.__name__ = 'wk_template_t0123abcd'\n"
+    "program = jax.jit(wk_template)\n")
+
+
+@pytest.mark.parametrize("unnamed,who", [
+    ("f = jax.jit(lambda x: x)\n", "'<lambda>'"),
+    ("def run(x):\n    return x\nf = jax.jit(run)\n", "'run'"),
+    ("@jax.jit\ndef expand(x):\n    return x\n", "'expand'"),
+    ("@functools.partial(jax.jit, static_argnames=('n',))\n"
+     "def scan(x, n):\n    return x\n", "'scan'"),
+    ("def one(x):\n    return x\nf = jax.jit(jax.vmap(one))\n", "'one'"),
+])
+def test_device_gate_names_every_jitted_function(tmp_path, unnamed, who):
+    """A function handed to jax.jit under engine/join/vector/stream/
+    parallel carries a wk_ name, whatever form hands it: one unnamed
+    function in a module of named ones is one violation, at its line."""
+    from wukong_tpu.analysis import run_analysis
+
+    allow = ("DEVICE_DISPATCH_ALLOWLIST = {'stream/k.py': 'charged at the "
+             "sync seam of stream/run.py'}\n")
+    tree = write_tree(tmp_path / "t", {
+        "obs/device.py": _DEV_OK.replace("DEVICE_DISPATCH_ALLOWLIST = {}\n",
+                                         allow),
+        "stream/k.py": _NAMED_OK + unnamed})
+    out = run_analysis(tree, plugins=["device-telemetry"])
+    assert len(out) == 1 and who in str(out[0]), out
+    assert out[0].path == "stream/k.py"
+    assert out[0].line > _NAMED_OK.count("\n")
+    tree = write_tree(tmp_path / "ok", {
+        "obs/device.py": _DEV_OK.replace("DEVICE_DISPATCH_ALLOWLIST = {}\n",
+                                         allow),
+        "stream/k.py": _NAMED_OK})
+    assert run_analysis(tree, plugins=["device-telemetry"]) == []
+
+
+def test_every_jitted_function_of_the_package_is_named():
+    """The tree itself: no jitted function without its wk_ name, and the
+    gate finds the programs the profile shows."""
+    import ast
+
+    from wukong_tpu.analysis import run_analysis
+    from wukong_tpu.analysis.devicegate import NAMED_PREFIXES, _jitted_names
+
+    import wukong_tpu
+
+    pkg = os.path.dirname(os.path.abspath(wukong_tpu.__file__))
+    assert run_analysis(pkg, plugins=["device-telemetry"]) == []
+    names = set()
+    for rel in NAMED_PREFIXES:
+        for dirpath, _dirs, files in os.walk(os.path.join(pkg, rel)):
+            for f in files:
+                if f.endswith(".py"):
+                    with open(os.path.join(dirpath, f)) as fh:
+                        names |= {n for _l, n in
+                                  _jitted_names(ast.parse(fh.read()))}
+    assert {"wk_walk_expand", "wk_walk_merge_expand", "wk_template",
+            "wk_level_probe", "wk_dist_chain", "wk_knn_scan",
+            "wk_stream_seed_masks"} <= names
+    assert all(n.startswith("wk_") for n in names)
 
 
 def test_device_gate_skips_trees_without_device_plane(tmp_path):

@@ -224,6 +224,16 @@ def test_storm_trips_once_per_cooldown():
     assert led.note("s", "t0", 1024) == (False, None)
 
 
+def test_storm_first_trip_on_a_fresh_clock(monkeypatch):
+    """A host up for less than the cooldown still sees its first storm."""
+    import wukong_tpu.obs.device as device_mod
+
+    monkeypatch.setattr(device_mod, "get_usec", lambda: 1_000)
+    led = CompileLedger(limit=3, cooldown_s=60.0)
+    storms = [led.note("s", f"t{i}", 1024)[1] for i in range(5)]
+    assert [s for s in storms if s is not None] == [4]
+
+
 def test_storm_journals_event_once(monkeypatch):
     """Through the facade: a storm journals ONE device.variant_storm
     ClusterEvent (and survives an empty FlightRecorder ring)."""

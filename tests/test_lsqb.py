@@ -270,7 +270,8 @@ def test_spans_event_and_counters_of_a_traced_reply(world, name):
     assert all(sp.parent == execute.index for sp in levels)
     for sp in spans:  # a level's parts lie inside a level
         if sp.name in ("wcoj.enumerate", "wcoj.probe.stage",
-                       "wcoj.probe.dispatch", "wcoj.probe.sync"):
+                       "wcoj.probe.dispatch", "wcoj.probe.sync",
+                       "wcoj.compact", "wcoj.probe.host"):
             assert spans[sp.parent].name == "wcoj.level", sp.name
     events = [(n, a) for sp in spans for _t, n, a in sp.events
               if n == "join.level"]
@@ -306,6 +307,38 @@ def test_spans_event_and_counters_of_a_traced_reply(world, name):
             assert rose == sum(lv[key] for lv in q.join_stats
                                if lv["route"] == route), (metric, route)
     assert "wk:" not in "".join(names)  # the annotation is the profile's
+
+
+@pytest.mark.parametrize("route", ["wcoj-host", "wcoj-device"])
+@pytest.mark.parametrize("name", ["q2", "q3"])
+def test_the_host_compaction_has_its_span(world, name, route, monkeypatch):
+    """A level's host work is covered by its children: ``wcoj.compact``
+    once a run of rows with candidates (its survivors kept) and once for
+    the level (the concatenation into the next prefix), ``wcoj.probe.host``
+    once a run a host-route level probes. ``LEVEL_SLICE`` at 2,048 takes
+    the wider levels in runs."""
+    proxy, owed = world
+    Global.enable_tracing = True
+    monkeypatch.setattr(kernels, "LEVEL_SLICE", 2048)
+    q = serve(proxy, name, route)
+    assert np.array_equal(rows_of(q), owed[name])
+    spans = q.trace.spans
+    levels = [sp for sp in spans if sp.name == "wcoj.level"]
+    assert len(levels) == len(q.join_stats)
+    most_runs = 0
+    for sp, lv in zip(levels, q.join_stats):
+        inside = [s.name for s in spans if s.parent == sp.index]
+        runs = inside.count("wcoj.enumerate") - 1  # less the choice
+        with_candidates = runs if lv["candidates"] else 0
+        assert inside.count("wcoj.compact") == with_candidates + 1
+        assert inside.count("wcoj.probe.host") == (
+            with_candidates if lv["route"] == "host" else 0)
+        assert inside[-1] == "wcoj.compact"  # the concatenation, last
+        most_runs = max(most_runs, runs)
+    assert most_runs > 1
+    for sp in spans:
+        if sp.name in ("wcoj.compact", "wcoj.probe.host"):
+            assert spans[sp.parent].name == "wcoj.level"
 
 
 def test_the_level_probe_has_a_stable_name():

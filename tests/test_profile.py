@@ -359,6 +359,22 @@ def test_regression_sentinel_p95_trip_dumps_trace(monkeypatch):
         labels=("template",)).value(template="T") >= 1
 
 
+def test_regression_sentinel_first_trip_on_a_fresh_clock(monkeypatch):
+    """A host up for less than the cooldown still sees its first
+    regression."""
+    import wukong_tpu.obs.profile as profile_mod
+
+    monkeypatch.setattr(profile_mod, "get_usec", lambda: 2_000_000)
+    monkeypatch.setattr(Global, "attribution_cooldown_s", 3600)
+    monkeypatch.setattr(Global, "attribution_min_samples", 8)
+    monkeypatch.setattr(Global, "attribution_p95_drift_pct", 100)
+    att = LatencyAttributor(window=64)
+    for _ in range(10):
+        assert att.observe(_fake_trace(1000, 100, 850), "T") is None
+    v = att.observe(_fake_trace(5000, 120, 4800), "T")
+    assert v is not None and v["reason"] == "P95_DRIFT"
+
+
 def test_regression_sentinel_component_shift(monkeypatch):
     monkeypatch.setattr(Global, "attribution_min_samples", 8)
     monkeypatch.setattr(Global, "attribution_share_drift_pct", 25)

@@ -283,6 +283,20 @@ def test_burn_sentinel_trips_counts_and_dumps(monkeypatch):
     assert len(dumps) == 1 and dumps[0][1].tenant == "gold"
 
 
+def test_burn_sentinel_first_trip_on_a_fresh_clock(monkeypatch):
+    """``get_usec`` counts from the machine's boot: a host up for less than
+    the cooldown still pages on its first burn."""
+    import wukong_tpu.obs.slo as slo_mod
+
+    monkeypatch.setattr(Global, "slo_dump_cooldown_s", 3600)
+    monkeypatch.setattr(slo_mod, "get_usec", lambda: 5_000_000)
+    t = SLOTracker(window=128)
+    t.register(SLOSpec("gold", 0.95, 0.0, 0.999))
+    trips = [v for v in (t.observe("gold", 1000, ok=False)
+                         for _ in range(40)) if v is not None]
+    assert len(trips) == 1
+
+
 def test_burn_sentinel_min_samples_floor():
     t = SLOTracker(window=64)
     t.register(SLOSpec("a", 0.95, 0.0, 0.999))

@@ -22,8 +22,12 @@ import pytest
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
-from wukong_tpu.engine.tpu_kernels import INT32_MAX, merge_expand  # noqa: E402
-from wukong_tpu.engine.tpu_stream import MDUP, TILE, stream_expand  # noqa: E402
+from wukong_tpu.engine.tpu_kernels import INT32_MAX  # noqa: E402
+from wukong_tpu.engine.tpu_kernels import \
+    wk_walk_merge_expand as merge_expand  # noqa: E402
+from wukong_tpu.engine.tpu_stream import MDUP, TILE  # noqa: E402
+from wukong_tpu.engine.tpu_stream import \
+    wk_walk_merge_stream_expand as stream_expand  # noqa: E402
 
 
 def _segment(keys, degs, edge_fn=None, rng=None):

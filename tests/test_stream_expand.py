@@ -12,8 +12,12 @@ import pytest
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
-from wukong_tpu.engine.tpu_kernels import INT32_MAX, merge_expand  # noqa: E402
-from wukong_tpu.engine.tpu_stream import TILE, stream_expand  # noqa: E402
+from wukong_tpu.engine.tpu_kernels import INT32_MAX  # noqa: E402
+from wukong_tpu.engine.tpu_kernels import \
+    wk_walk_merge_expand as merge_expand  # noqa: E402
+from wukong_tpu.engine.tpu_stream import TILE  # noqa: E402
+from wukong_tpu.engine.tpu_stream import \
+    wk_walk_merge_stream_expand as stream_expand  # noqa: E402
 
 
 def _mk_segment(rng, nkeys, max_deg):
@@ -93,7 +97,8 @@ def test_stream_duplicate_anchors_mhot():
 
 def test_stream_duplicate_anchors_mhot_off_bitwise():
     """mhot=False restores the XLA fallback: bit-identical on duplicates."""
-    from wukong_tpu.engine.tpu_stream import stream_expand as se
+    from wukong_tpu.engine.tpu_stream import \
+        wk_walk_merge_stream_expand as se
 
     rng = np.random.default_rng(7)
     sk, ss, sd, e, keys, offs = _mk_segment(rng, nkeys=64, max_deg=5)

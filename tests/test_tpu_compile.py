@@ -87,7 +87,7 @@ def test_stream_expand_compiles(one_chip, shapes640, size):
     tries first (MXU compaction + the m-hot duplicate-anchor arm)."""
     S, E, C, cap_out = SMALL if size == "small" else shapes640
     assert tpu_stream.FIRST_CHOICE == "mxu+mhot"
-    _compile(tpu_stream.stream_expand.lower(
+    _compile(tpu_stream.wk_walk_merge_stream_expand.lower(
         *_merge_args(one_chip, S, E, C), cap_out=cap_out, mxu=True,
         mhot=True, mdup=tpu_stream.MDUP), f"stream_expand[{size}]", True)
 
@@ -111,8 +111,8 @@ def test_merge_expand_compiles(one_chip):
     alone takes the chip's compiler ~30 s there."""
     S, E, C, cap_out = SMALL
     args = _merge_args(one_chip, S, E, C)
-    _compile(K.merge_expand.lower(*args, cap_out=cap_out), "merge_expand",
-             False)
+    _compile(K.wk_walk_merge_expand.lower(*args, cap_out=cap_out),
+             "merge_expand", False)
     _compile(jax.jit(K._merge_lookup).lower(*args[:3], args[4]),
              "_merge_lookup", False)
 
@@ -124,7 +124,7 @@ def test_hash_probe_expand_compiles(one_chip, shapes640, size):
     S, E, C, cap_out = SMALL if size == "small" else shapes640
     NB = S // (BUCKET // 2)  # build_hash_table: <= 50 % load
     i = partial(_i32, one_chip)
-    _compile(K.expand.lower(
+    _compile(K.wk_walk_expand.lower(
         i(1, C), i(), i(NB * BUCKET), i(NB * BUCKET), i(NB * BUCKET), i(E),
         col=0, cap_out=cap_out, max_probe=2, fpw0=i(NB), fpw1=i(NB),
         fp_dup=2), f"expand[{size}]", False)

@@ -117,7 +117,7 @@ def test_stats_capacity_estimation_reduces_retries(world):
     triples, _ = generate_lubm(1, seed=42)
     stats = Stats.generate(triples)
     calls = []
-    orig = K.expand
+    orig = K.wk_walk_expand
 
     def counting_expand(*a, **k):
         calls.append(k.get("cap_out"))
@@ -125,7 +125,7 @@ def test_stats_capacity_estimation_reduces_retries(world):
 
     text = open(f"{BASIC}/lubm_q2").read()
     try:
-        K.expand = counting_expand
+        K.wk_walk_expand = counting_expand
         tpu = TPUEngine(g, ss, stats=stats)
         q = Parser(ss).parse(text)
         heuristic_plan(q)
@@ -140,7 +140,7 @@ def test_stats_capacity_estimation_reduces_retries(world):
         tpu2.execute(q2)
         without = len(calls)
     finally:
-        K.expand = orig
+        K.wk_walk_expand = orig
     assert q.result.nrows == q2.result.nrows
     assert with_stats <= without  # stats never add retries
 

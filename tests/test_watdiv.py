@@ -272,8 +272,9 @@ def test_a_category_met_later_compiles_nothing(served, name):
     texts = _category_texts(world, name)
     ref.ids.update({f"<{watdiv.WSDBM}ProductCategory{k}>":
                     T[f"ProductCategory{k}"] for k in texts})
-    kernels = [K.init_from_list, K.expand, K.member_mask_known, K.compact,
-               K.compact_to]
+    kernels = [K.wk_walk_init_from_list, K.wk_walk_expand,
+               K.wk_walk_member_mask_known, K.wk_walk_compact,
+               K.wk_walk_compact_to]
     world.proxy.serve_query(texts[0], blind=False)
     before = [k._cache_size() for k in kernels]
     for k in range(1, watdiv.CATEGORIES):

@@ -19,6 +19,12 @@ holds the device plane to the same standard, three ways:
   ``DEVICE_DISPATCH_ALLOWLIST`` in ``obs/device.py`` with a written
   justification — a new jitted call path cannot silently run outside
   the cost ledger the actuator budgets with.
+- every function handed to ``jax.jit`` under ``engine/``, ``join/``,
+  ``vector/``, ``stream/`` or ``parallel/`` is named ``wk_<route>_...``
+  (a decorated def, a named function, or one ``shard_map``/``vmap``
+  wraps): its ``__name__`` is the module the profile shows
+  (``jit_wk_walk_expand``), and a lambda or a generic name would leave
+  the device's busy time to no program.
 - ``obs/device.py`` keeps the telemetry-gate posture: every mutable
   shared structure created in an ``__init__`` body carries a
   ``# guarded by:`` / ``# lock-free:`` annotation, and every lockdep
@@ -50,6 +56,11 @@ METRIC_PREFIX = "wukong_device_"
 SEAM_NAME = "maybe_device_dispatch"
 #: packages whose jitted call sites must charge the dispatch seam
 SEAMED_PREFIXES = ("engine/", "join/", "vector/")
+#: packages whose jitted functions must carry a program name
+NAMED_PREFIXES = SEAMED_PREFIXES + ("stream/", "parallel/")
+PROGRAM_PREFIX = "wk_"
+#: wrappers that keep the name of the function they wrap
+_NAME_KEEPING = ("shard_map", "vmap")
 _ANNOTATIONS = ("guarded by:", "lock-free:", "unguarded:", "caller holds:")
 _MUTABLE_CTORS = {"dict", "list", "set", "deque", "defaultdict",
                   "OrderedDict", "Counter"}
@@ -76,6 +87,46 @@ def _call_name(node: ast.Call) -> str:
     fn = node.func
     return fn.id if isinstance(fn, ast.Name) else (
         fn.attr if isinstance(fn, ast.Attribute) else "")
+
+
+def _is_jax_jit(node) -> bool:
+    return (isinstance(node, ast.Attribute) and node.attr == "jit"
+            and isinstance(node.value, ast.Name) and node.value.id == "jax")
+
+
+def _is_jit_partial(node) -> bool:
+    """``partial(jax.jit, ...)`` (or ``functools.partial``)."""
+    return (isinstance(node, ast.Call) and _call_name(node) == "partial"
+            and bool(node.args) and _is_jax_jit(node.args[0]))
+
+
+def _handed_name(node) -> str:
+    """The ``__name__`` the function expression handed to ``jax.jit``
+    gives its program, as far as the source shows it."""
+    if isinstance(node, ast.Name):
+        return node.id
+    if isinstance(node, ast.Attribute):
+        return node.attr
+    if isinstance(node, ast.Lambda):
+        return "<lambda>"
+    if (isinstance(node, ast.Call) and _call_name(node) in _NAME_KEEPING
+            and node.args):
+        return _handed_name(node.args[0])
+    return "<expression>"
+
+
+def _jitted_names(tree):
+    """(line, name) of every function the module hands to ``jax.jit``:
+    decorated defs, ``jax.jit(f)`` and ``partial(jax.jit, ...)(f)``."""
+    for node in ast.walk(tree):
+        if isinstance(node, ast.FunctionDef):
+            for d in node.decorator_list:
+                if _is_jax_jit(d) or _is_jit_partial(d) or (
+                        isinstance(d, ast.Call) and _is_jax_jit(d.func)):
+                    yield node.lineno, node.name
+        elif isinstance(node, ast.Call) and node.args and (
+                _is_jax_jit(node.func) or _is_jit_partial(node.func)):
+            yield node.lineno, _handed_name(node.args[0])
 
 
 def _literal_str_dict(sf, name: str):
@@ -106,7 +157,8 @@ class DeviceTelemetryGate(AnalysisPlugin):
     name = "device-telemetry"
     description = ("DEVICE_INPUTS <-> registrations parity; every jitted "
                    "call site in engine/join/vector charges the dispatch "
-                   "seam or sits in the justified allowlist; device-"
+                   "seam or sits in the justified allowlist; every jitted "
+                   "function carries a wk_ program name; device-"
                    "observatory shared state annotated and its locks "
                    "declared lockdep leaves")
 
@@ -118,6 +170,7 @@ class DeviceTelemetryGate(AnalysisPlugin):
         out: list[Violation] = []
         out.extend(self._check_inputs(ctx, sf))
         out.extend(self._check_dispatch_coverage(ctx, sf))
+        out.extend(self._check_program_names(ctx))
         out.extend(self._check_init_annotations(sf))
         out.extend(self._check_leaf_locks(sf))
         out.extend(self._check_template_coherence(ctx, sf))
@@ -321,6 +374,24 @@ class DeviceTelemetryGate(AnalysisPlugin):
                     f"{ALLOWLIST_NAME} entry {rel!r} is stale — the "
                     "module no longer mints uncharged jitted calls; drop "
                     "the exemption so it cannot mask a future regression"))
+        return out
+
+    # ------------------------------------------------------------------
+    # every device program carries a wk_ name into the profile
+    # ------------------------------------------------------------------
+    def _check_program_names(self, ctx: RepoContext) -> list[Violation]:
+        out = []
+        for sf in ctx.iter_files():
+            if sf.tree is None or not sf.rel.startswith(NAMED_PREFIXES):
+                continue
+            for line, name in _jitted_names(sf.tree):
+                if not name.startswith(PROGRAM_PREFIX):
+                    out.append(Violation(
+                        self.name, sf.rel, line,
+                        f"jax.jit is handed {name!r}, which does not start "
+                        f"with {PROGRAM_PREFIX!r} — name the function "
+                        f"{PROGRAM_PREFIX}<route>_<kernel> so the profile "
+                        "can put its device time down to a program"))
         return out
 
     # ------------------------------------------------------------------

@@ -338,7 +338,7 @@ def extract_template(q) -> tuple | None:
 
 def _build_program(spec: tuple, caps: tuple, depths: tuple,
                    id_bounds: tuple, proj: tuple | None,
-                   blind: bool = False):
+                   blind: bool = False, label: str = "t"):
     """jax.jit the whole plan: one traced function from the padded
     start list to the (projected) padded result table. All structure —
     op kinds, capacity classes, binary-search depths, each CSR op's id
@@ -346,6 +346,11 @@ def _build_program(spec: tuple, caps: tuple, depths: tuple,
     value (start list, CSR triplets, member lists, const ids) is a
     traced argument, so same-shape templates share compiles and consts
     never mint variants.
+
+    The program is named ``wk_template_<label>`` (``label``: the template's
+    ``_label``, one a family, so no draw mints a name), the module the
+    profile shows; step ``k`` of the plan runs under the scope
+    ``s<k>_<op>``.
 
     Returns ``(fn, forms)``: ``forms`` lists, once ``fn`` has traced,
     which form each key lookup of the program took (True the direct
@@ -356,53 +361,55 @@ def _build_program(spec: tuple, caps: tuple, depths: tuple,
     n_expand = sum(1 for op in spec if op[0] == "expand")
     forms: list[bool] = []
 
-    def run(*args):
+    def wk_template(*args):
         del forms[:]
         it = iter(args)
         vals = next(it)
         n0 = next(it)
-        valid = jnp.arange(caps[0]) < n0
+        with jax.named_scope(f"s0_{spec[0][0]}"):
+            valid = jnp.arange(caps[0]) < n0
         cols = [vals]
         totals, ovfs = [], []
         ci, di, bi = 1, 0, 0
-        for op in spec[1:]:
-            kind = op[0]
-            if kind != "filter_member":
-                keys, offsets, edges = next(it), next(it), next(it)
-                bound = id_bounds[bi]
-                bi += 1
-                forms.append(direct_lookup_wins(
-                    cols[op[3]].shape[0], keys.shape[0], bound))
-            if kind == "expand":
-                start, deg = lookup_ranges_device(keys, offsets,
-                                                  cols[op[3]], bound)
-                deg = jnp.where(valid, deg, 0)
-                rowc, newv, valid, total, ovf = expand_padded_device(
-                    start, deg, edges, caps[ci])
-                cols = [c[rowc] for c in cols] + [newv]
-                totals.append(total)
-                ovfs.append(ovf)
-                ci += 1
-            elif kind == "filter_pair":
-                ok = pair_member(keys, offsets, edges, cols[op[3]],
-                                 cols[op[4]], xp=jnp, depth=depths[di],
-                                 id_bound=bound)
-                di += 1
-                valid = valid & ok
-            elif kind == "filter_pair_const":
-                objc = next(it)
-                anchors = cols[op[3]]
-                ok = pair_member(keys, offsets, edges, anchors,
-                                 jnp.broadcast_to(objc, anchors.shape),
-                                 xp=jnp, depth=depths[di], id_bound=bound)
-                di += 1
-                valid = valid & ok
-            else:  # filter_member
-                mlist, mlen = next(it), next(it)
-                col = cols[op[4]]
-                idx = jnp.searchsorted(mlist, col)
-                idxc = jnp.clip(idx, 0, mlist.shape[0] - 1)
-                valid = valid & (idx < mlen) & (mlist[idxc] == col)
+        for k, op in enumerate(spec[1:], 1):
+            with jax.named_scope(f"s{k}_{op[0]}"):
+                kind = op[0]
+                if kind != "filter_member":
+                    keys, offsets, edges = next(it), next(it), next(it)
+                    bound = id_bounds[bi]
+                    bi += 1
+                    forms.append(direct_lookup_wins(
+                        cols[op[3]].shape[0], keys.shape[0], bound))
+                if kind == "expand":
+                    start, deg = lookup_ranges_device(keys, offsets,
+                                                      cols[op[3]], bound)
+                    deg = jnp.where(valid, deg, 0)
+                    rowc, newv, valid, total, ovf = expand_padded_device(
+                        start, deg, edges, caps[ci])
+                    cols = [c[rowc] for c in cols] + [newv]
+                    totals.append(total)
+                    ovfs.append(ovf)
+                    ci += 1
+                elif kind == "filter_pair":
+                    ok = pair_member(keys, offsets, edges, cols[op[3]],
+                                     cols[op[4]], xp=jnp, depth=depths[di],
+                                     id_bound=bound)
+                    di += 1
+                    valid = valid & ok
+                elif kind == "filter_pair_const":
+                    objc = next(it)
+                    anchors = cols[op[3]]
+                    ok = pair_member(keys, offsets, edges, anchors,
+                                     jnp.broadcast_to(objc, anchors.shape),
+                                     xp=jnp, depth=depths[di], id_bound=bound)
+                    di += 1
+                    valid = valid & ok
+                else:  # filter_member
+                    mlist, mlen = next(it), next(it)
+                    col = cols[op[4]]
+                    idx = jnp.searchsorted(mlist, col)
+                    idxc = jnp.clip(idx, 0, mlist.shape[0] - 1)
+                    valid = valid & (idx < mlen) & (mlist[idxc] == col)
         live = jnp.sum(valid.astype(jnp.int32))
         totals_a = (jnp.stack(totals) if totals
                     else jnp.zeros(0, dtype=jnp.int32))
@@ -418,7 +425,8 @@ def _build_program(spec: tuple, caps: tuple, depths: tuple,
         return table, valid, totals_a, ovfs_a, live
 
     assert len(caps) == n_expand + 1
-    return jax.jit(run), forms
+    wk_template.__name__ = wk_template.__qualname__ = f"wk_template_{label}"
+    return jax.jit(wk_template), forms
 
 
 class _Program:
@@ -602,14 +610,15 @@ class TemplateCompiledEngine:
                                 if op[4] < NORMAL_ID_START else None)
             else:  # filter_member
                 args += [None, None]
+        label = _label(tsig)
         fn, forms = _build_program(spec, caps, tuple(depths),
-                                   tuple(id_bounds), proj, blind)
+                                   tuple(id_bounds), proj, blind, label)
         # what a cached program keeps on the device is its start list. (A
         # run's result buffer lives for that run: counted, one program of
         # 2^24 rows was twice the budget alone, and it and whichever
         # program ran beside it evicted each other on every request.)
         return _Program(fn, forms, args, caps, spec, v2c, proj, width,
-                        caps[0] * 4, _label(tsig), blind)
+                        caps[0] * 4, label, blind)
 
     def _start_args(self, op, cap: int) -> list:
         vals = self._start_values(op)

@@ -481,7 +481,8 @@ class TPUEngine:
                           K.next_capacity(heavy, self.cap_min, self.cap_max))
                 if tr is not None:
                     tr.event("device.dispatch", kernel="init_from_list")
-                table, nn = K.init_from_list(edges, jnp.int32(real), cap)
+                table, nn = K.wk_walk_init_from_list(edges, jnp.int32(real),
+                                                     cap)
                 state.begin(table, nn, end, est_rows=heavy)
                 state.local_var = end
                 return
@@ -556,7 +557,7 @@ class TPUEngine:
             fd = self._fp_dup(vseg)
             if tr is not None:
                 tr.event("device.dispatch", kernel="expand2")
-            out, nn, total = K.expand2(
+            out, nn, total = K.wk_walk_expand2(
                 state.table, state.n, vseg.bkey, vseg.bstart, vseg.bdeg,
                 vseg.edges2, vseg.edges, col=col, cap_out=cap_out,
                 max_probe=vseg.max_probe,
@@ -572,7 +573,7 @@ class TPUEngine:
                     tr.event("device.dispatch", kernel="compact")
                 keep = (jnp.arange(cap_out, dtype=jnp.int32) < nn) \
                     & (out[-1] == jnp.int32(end))
-                out, nn = K.compact(out, keep)
+                out, nn = K.wk_walk_compact(out, keep)
                 state.table = out[:-1]
                 state.n = nn
                 state.cols[pid] = state.width
@@ -599,7 +600,7 @@ class TPUEngine:
             fd = self._fp_dup(seg)
             if tr is not None:
                 tr.event("device.dispatch", kernel="expand")
-            out, nn, total = K.expand(
+            out, nn, total = K.wk_walk_expand(
                 state.table, state.n, seg.bkey, seg.bstart, seg.bdeg,
                 seg.edges, col=col, cap_out=cap_out,
                 max_probe=seg.max_probe,
@@ -618,7 +619,7 @@ class TPUEngine:
                 fd = self._fp_dup(seg)
                 if tr is not None:
                     tr.event("device.dispatch", kernel="member_mask_known")
-                keep = K.member_mask_known(
+                keep = K.wk_walk_member_mask_known(
                     state.table, state.n, vals, seg.bkey, seg.bstart,
                     seg.bdeg, seg.edges, col=col, max_probe=seg.max_probe,
                     depth=seg.max_deg_log2,
@@ -636,13 +637,14 @@ class TPUEngine:
                 # underestimate retries the chain, never drops rows
                 if tr is not None:
                     tr.event("device.dispatch", kernel="compact_to")
-                out, nn, total = K.compact_to(state.table, keep, cap_new)
+                out, nn, total = K.wk_walk_compact_to(state.table, keep,
+                                                      cap_new)
                 state.advance_filter(out, nn)
                 state.totals.append((step, total, cap_new))
             else:
                 if tr is not None:
                     tr.event("device.dispatch", kernel="compact")
-                out, nn = K.compact(state.table, keep)
+                out, nn = K.wk_walk_compact(state.table, keep)
                 state.advance_filter(out, nn)
 
     # ------------------------------------------------------------------
@@ -786,7 +788,7 @@ class TPUEngine:
             # (the init step does not participate in the overflow-retry loop)
             cap0 = K.next_capacity(
                 max(total0, 1), self.cap_min, self.cap_max)
-            state.table, state.n = K.init_batch_index(
+            state.table, state.n = K.wk_walk_init_batch_index(
                 edges, jnp.int32(real), B=B, cap=cap0, slice_mode=slice_mode)
             state.width = 2
             state.cols[pats[0].object] = 1
@@ -1191,12 +1193,12 @@ def _qid_counts(table, n, B: int):
         import jax
         import jax.numpy as jnp
 
-        def impl(table, n, B: int):
+        def wk_walk_qid_counts(table, n, B: int):
             C = table.shape[1]
             live = jnp.arange(C, dtype=jnp.int32) < n
             qid = jnp.where(live, table[0], B)
             return jnp.bincount(qid, length=B + 1)[:B]
 
         _qid_counts_jit = functools.partial(
-            jax.jit, static_argnames=("B",))(impl)
+            jax.jit, static_argnames=("B",))(wk_walk_qid_counts)
     return _qid_counts_jit(table, n, B=B)

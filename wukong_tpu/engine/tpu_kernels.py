@@ -23,6 +23,11 @@ All kernels take padded arrays (see device_store) and static capacities, so the
 jit cache is bounded by (log2 sizes x width x probe bound). `n` is the live row
 count (device scalar). No kernel ever forces a host sync — overflow totals ride
 along as device scalars.
+
+Each jitted kernel is named for the route that calls it: ``wk_walk_<kernel>``
+for the walk's chain (``engine/tpu.py``), ``wk_walk_merge_<kernel>`` for its
+merge executor (``engine/tpu_merge.py``). The name is the module the profile
+shows (``jit_wk_walk_expand``); ``analysis/devicegate.py`` holds it.
 """
 
 from __future__ import annotations
@@ -193,8 +198,8 @@ def _probe(bkey, bstart, bdeg, cur, n, max_probe: int,
 
 @partial(jax.jit,
          static_argnames=("col", "cap_out", "max_probe", "fp_dup"))
-def expand(table, n, bkey, bstart, bdeg, edges, col, cap_out, max_probe,
-           fpw0=None, fpw1=None, fp_dup=0):
+def wk_walk_expand(table, n, bkey, bstart, bdeg, edges, col, cap_out,
+                   max_probe, fpw0=None, fpw1=None, fp_dup=0):
     """known_to_unknown: expand each live row by its neighbor list.
 
     table: [W, C]. Returns (out [W+1, cap_out], out_n, total) — total may
@@ -228,13 +233,13 @@ def expand(table, n, bkey, bstart, bdeg, edges, col, cap_out, max_probe,
 
 @partial(jax.jit,
          static_argnames=("col", "cap_out", "max_probe", "fp_dup"))
-def expand2(table, n, bkey, bstart, bdeg, edges_pid, edges_val, col, cap_out,
-            max_probe, fpw0=None, fpw1=None, fp_dup=0):
+def wk_walk_expand2(table, n, bkey, bstart, bdeg, edges_pid, edges_val, col,
+                    cap_out, max_probe, fpw0=None, fpw1=None, fp_dup=0):
     """VERSATILE known_unknown_unknown (?x ?p ?y with x bound — the
     reference's sparql.hpp:601-650 kernel; its GPU engine refuses the
     shape): expand each live row by its COMBINED adjacency — every
     (predicate, neighbor) pair — binding TWO new columns. Identical
-    machinery to expand(), one extra aligned-edge-array gather.
+    machinery to wk_walk_expand(), one extra aligned-edge-array gather.
 
     Returns (out [W+2, cap_out] with pid then val rows, out_n, total)."""
     W, C = table.shape
@@ -264,8 +269,9 @@ def expand2(table, n, bkey, bstart, bdeg, edges_pid, edges_val, col, cap_out,
 
 @partial(jax.jit,
          static_argnames=("col", "max_probe", "depth", "fp_dup"))
-def member_mask_known(table, n, vals, bkey, bstart, bdeg, edges,
-                      col, max_probe, depth, fpw0=None, fpw1=None, fp_dup=0):
+def wk_walk_member_mask_known(table, n, vals, bkey, bstart, bdeg, edges, col,
+                              max_probe, depth, fpw0=None, fpw1=None,
+                              fp_dup=0):
     """known_to_known / known_to_const: per-row membership of vals[i] in
     adj(cur[i]). table: [W, C]; vals: [C]."""
     W, C = table.shape
@@ -278,7 +284,8 @@ def member_mask_known(table, n, vals, bkey, bstart, bdeg, edges,
     return valid & found & ok
 
 
-def _compact_to_impl(table, keep, cap_out):
+@partial(jax.jit, static_argnames=("cap_out",))
+def wk_walk_compact_to(table, keep, cap_out):
     """compact into a (possibly smaller) capacity class (estimate-driven
     mid-chain shrink: later kernels pay for capacity, not live rows). Returns
     (out [W, cap_out], n, total) — total is the true surviving count; if it
@@ -293,19 +300,17 @@ def _compact_to_impl(table, keep, cap_out):
         jnp.minimum(total, cap_out).astype(jnp.int32), total
 
 
-def _compact_impl(table, keep):
-    out, n, _total = _compact_to_impl(table, keep, table.shape[1])
+# the dist engine composes the unjitted body (``__wrapped__``) inside its
+# one shard_map program
+@jax.jit
+def wk_walk_compact(table, keep):
+    out, n, _total = wk_walk_compact_to.__wrapped__(table, keep,
+                                                    table.shape[1])
     return out, n
 
 
-compact_to = partial(jax.jit, static_argnames=("cap_out",))(_compact_to_impl)
-# jit exposes __wrapped__ = _compact_impl (the dist engine composes the
-# unjitted bodies inside one shard_map program)
-compact = jax.jit(_compact_impl)
-
-
 @partial(jax.jit, static_argnames=("cap",))
-def init_from_list(edge_list, real_len, cap):
+def wk_walk_init_from_list(edge_list, real_len, cap):
     """index/const start: one-row table [1, cap] from an edge list."""
     j = jnp.arange(cap, dtype=jnp.int32)
     E = edge_list.shape[0]
@@ -316,7 +321,7 @@ def init_from_list(edge_list, real_len, cap):
 
 
 @partial(jax.jit, static_argnames=("B", "cap", "slice_mode"))
-def init_batch_index(edge_list, real_len, B, cap, slice_mode):
+def wk_walk_init_batch_index(edge_list, real_len, B, cap, slice_mode):
     """Batched index-origin start: [2, cap] table with a qid row.
 
     replicate mode (slice_mode=False): B full copies of the index list —
@@ -345,9 +350,9 @@ def init_batch_index(edge_list, real_len, B, cap, slice_mode):
     return table, jnp.minimum(total, cap).astype(jnp.int32)
 
 
-@partial(jax.jit, static_argnames=("col",))
 def member_mask_list(table, n, col, sorted_list, real_len):
-    """index_to_known / const_to_known: membership of a row in a sorted list."""
+    """index_to_known / const_to_known: membership of a row in a sorted list
+    (a body the dist engine traces into its program; ``col`` is static)."""
     W, C = table.shape
     rows = jnp.arange(C, dtype=jnp.int32)
     valid = rows < n
@@ -358,21 +363,6 @@ def member_mask_list(table, n, col, sorted_list, real_len):
     hi = jnp.minimum(jnp.full(C, jnp.int32(min(L, INT32_MAX))), real_len)
     ok = _range_member(sorted_list, lo, hi, vals, depth)
     return valid & ok
-
-
-@jax.jit
-def distinct_rows(table, n):
-    """DISTINCT on live rows. table: [W, C]."""
-    W, C = table.shape
-    valid = jnp.arange(C, dtype=jnp.int32) < n
-    keyed = jnp.where(valid[None, :], table, INT32_MAX)
-    order = jnp.arange(C, dtype=jnp.int32)
-    for c in range(W - 1, -1, -1):
-        order = order[jnp.argsort(keyed[c, order], stable=True)]
-    st = keyed[:, order]
-    same = jnp.all(st[:, 1:] == st[:, :-1], axis=0)
-    keep = jnp.concatenate([jnp.array([True]), ~same]) & (jnp.arange(C) < n)
-    return compact(st, keep)
 
 
 # ---------------------------------------------------------------------------
@@ -426,7 +416,7 @@ def _merge_lookup(skey, sstart, sdeg, cur):
 
 def _emit_gather(ts, S, start, deg, st_ex, edges, total, cap_out):
     """The scatter+cummax+gather emit over the [cap_out] output grid (shared
-    by merge_expand and tpu_stream's duplicate-anchor fallback branch).
+    by wk_walk_merge_expand and tpu_stream's duplicate-anchor fallback branch).
     Returns (val, parent), zero-masked outside [0, total)."""
     base = start - st_ex  # eidx = base[src] + j (one gather instead of two)
     M = ts.shape[0]
@@ -446,8 +436,9 @@ def _emit_gather(ts, S, start, deg, st_ex, edges, total, cap_out):
 
 
 @partial(jax.jit, static_argnames=("cap_out", "max_probe", "fp_dup"))
-def probe_expand(bkey, bstart, bdeg, edges, cur, n, live, cap_out,
-                 max_probe, fpw0=None, fpw1=None, fp_dup=0):
+def wk_walk_merge_probe_expand(bkey, bstart, bdeg, edges, cur, n, live,
+                               cap_out, max_probe, fpw0=None, fpw1=None,
+                               fp_dup=0):
     """known_to_unknown for the merge chain when the frontier is far
     smaller than the segment: O(C) hash-probe run lookup against the v1
     bucket table + the shared scatter-emit, instead of _merge_lookup's
@@ -456,7 +447,7 @@ def probe_expand(bkey, bstart, bdeg, edges, cur, n, live, cap_out,
     sort (the whole segment is re-sorted per call); the probe pays
     ~max_probe row-contiguous gathers over the frontier only.
 
-    Same contract as merge_expand — (val [cap_out], parent [cap_out],
+    Same contract as wk_walk_merge_expand — (val [cap_out], parent [cap_out],
     out_n, total), parents are input row ids — except output rows are in
     INPUT row order rather than key-sorted anchor order (downstream is
     order-insensitive: nothing assumes emission order).
@@ -479,7 +470,7 @@ def probe_expand(bkey, bstart, bdeg, edges, cur, n, live, cap_out,
 
 
 @partial(jax.jit, static_argnames=("cap_out",))
-def merge_expand(skey, sstart, sdeg, edges, cur, n, live, cap_out):
+def wk_walk_merge_expand(skey, sstart, sdeg, edges, cur, n, live, cap_out):
     """known_to_unknown without probes: returns (val [cap_out],
     parent [cap_out] into the input row space, out_n, total).
 
@@ -521,7 +512,7 @@ def _run_head_match(k_all, extra_eq, is_rel):
 
 
 @jax.jit
-def merge_member_list(sorted_list, real_len, cur, n, live):
+def wk_walk_merge_member_list(sorted_list, real_len, cur, n, live):
     """Membership of cur[i] in a sorted list (k2c against a const object,
     type checks, index membership). Returns a bool mask in INPUT row order.
     Gather-free: merge + run-head propagation + sort-back by tag.
@@ -548,10 +539,10 @@ def merge_member_list(sorted_list, real_len, cur, n, live):
 
 
 @jax.jit
-def member_list_binsearch(sorted_list, real_len, cur, n, live):
+def wk_walk_merge_member_binsearch(sorted_list, real_len, cur, n, live):
     """k2c membership for SMALL frontiers: binary-search each row in the
     sorted const list (O(C log L) sorted gathers) instead of merge-sorting
-    the whole list with the frontier (merge_member_list pays
+    the whole list with the frontier (wk_walk_merge_member_list pays
     O((L + C) log) per call — at LUBM-2560 a 2^22-member type list
     re-sorts for a 16K-row frontier). Returns a bool mask in INPUT row
     order; search depth derives from the list's padded length (static
@@ -569,7 +560,7 @@ def member_list_binsearch(sorted_list, real_len, cur, n, live):
 
 
 @jax.jit
-def merge_member_pairs(ekey, eval_, e_real, cur, vals, n, live):
+def wk_walk_merge_member_pairs(ekey, eval_, e_real, cur, vals, n, live):
     """known_to_known: does edge (cur[i] -> vals[i]) exist? ekey/eval_ are the
     segment's per-edge (key, neighbor) pairs, lex-sorted (CSR order). Returns
     a bool mask in INPUT row order. Gather-free (num_keys=3 sort).
@@ -598,14 +589,14 @@ def merge_member_pairs(ekey, eval_, e_real, cur, vals, n, live):
 
 
 @jax.jit
-def gather_col(col, parent):
+def wk_walk_merge_gather_col(col, parent):
     """Materialize a column one parent-hop down: col[parent]."""
     L = col.shape[0]
     return col[jnp.clip(parent, 0, L - 1)]
 
 
 @partial(jax.jit, static_argnames=("cap_out",))
-def merge_compact(vals, parent, keep, n, cap_out):
+def wk_walk_merge_compact(vals, parent, keep, n, cap_out):
     """Estimate-driven shrink of a (vals, parent) level: keep surviving rows,
     re-based into a smaller capacity class. Returns (vals', parent', n',
     total) — total rides along for the overflow-retry loop."""
@@ -620,7 +611,7 @@ def merge_compact(vals, parent, keep, n, cap_out):
 
 
 @partial(jax.jit, static_argnames=("B", "r", "slice_mode"))
-def qid_counts_pos0(pos0, n, live, B, r, slice_mode):
+def wk_walk_merge_qid_counts(pos0, n, live, B, r, slice_mode):
     """Per-qid surviving row counts from composed space-0 positions.
 
     replicate mode: qid = pos0 // r (r = real index length); slice mode:

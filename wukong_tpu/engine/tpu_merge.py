@@ -108,8 +108,8 @@ class _MergeState:
             return self.levels[top].vals
         idx = self.levels[top].parent
         for k in range(top - 1, lv, -1):
-            idx = K.gather_col(self.levels[k].parent, idx)
-        return K.gather_col(self.levels[lv].vals, idx)
+            idx = K.wk_walk_merge_gather_col(self.levels[k].parent, idx)
+        return K.wk_walk_merge_gather_col(self.levels[lv].vals, idx)
 
     def pos0(self):
         """Space-0 position of every current row (for qid recovery). The
@@ -123,7 +123,7 @@ class _MergeState:
             p = self.levels[k].parent
             if p is None:
                 continue
-            idx = p if idx is None else K.gather_col(p, idx)
+            idx = p if idx is None else K.wk_walk_merge_gather_col(p, idx)
         if idx is None:
             return jnp.arange(self.cap, dtype=jnp.int32)
         return idx
@@ -224,10 +224,10 @@ class MergeExecutor:
         eng = self.eng
         cap0 = K.next_capacity(max(total0, 1), eng.cap_min, eng.cap_max)
         if slice_mode:
-            vals, n = K.init_from_list(edges, jnp.int32(real), cap0)
+            vals, n = K.wk_walk_init_from_list(edges, jnp.int32(real), cap0)
         else:
-            tab, n = K.init_batch_index(edges, jnp.int32(real), B=B,
-                                        cap=cap0, slice_mode=False)
+            tab, n = K.wk_walk_init_batch_index(
+                edges, jnp.int32(real), B=B, cap=cap0, slice_mode=False)
             vals = tab[1:2]
         state.levels.append(_Level(pats[0].object, vals[0], None))
         state.var_level[pats[0].object] = 0
@@ -269,9 +269,9 @@ class MergeExecutor:
             for k, pat, _kind, fold in self.classify(
                     pats, folds, index_mode=True):
                 self._dispatch(q, pat, k, state, cap_override, {}, fold)
-            counts = K.qid_counts_pos0(state.pos0(), state.n,
-                                       state.live_mask(), B=B,
-                                       r=max(real, 1), slice_mode=False)
+            counts = K.wk_walk_merge_qid_counts(
+                state.pos0(), state.n, state.live_mask(), B=B,
+                r=max(real, 1), slice_mode=False)
             return counts, state.totals
 
         return self._run_many(pats, True, list(range(K_batches)),
@@ -297,9 +297,9 @@ class MergeExecutor:
             for k, pat, _kind, fold in self.classify(
                     pats, folds, index_mode=False):
                 self._dispatch(q, pat, k, state, cap_override, {}, fold)
-            counts = K.qid_counts_pos0(state.pos0(), state.n,
-                                       state.live_mask(), B=B, r=1,
-                                       slice_mode=False)
+            counts = K.wk_walk_merge_qid_counts(
+                state.pos0(), state.n, state.live_mask(), B=B, r=1,
+                slice_mode=False)
             return counts, state.totals
 
         return self._run_many(pats, False, consts_list, dispatch_one,
@@ -330,10 +330,9 @@ class MergeExecutor:
                 for k, pat, _kind, fold in self.classify(
                         pats, folds, index_mode=False):
                     self._dispatch(q, pat, k, state, cap_override, {}, fold)
-                counts = K.qid_counts_pos0(state.pos0(), state.n,
-                                           state.live_mask(),
-                                           B=len(consts), r=1,
-                                           slice_mode=False)
+                counts = K.wk_walk_merge_qid_counts(
+                    state.pos0(), state.n, state.live_mask(),
+                    B=len(consts), r=1, slice_mode=False)
                 return counts, state.totals
             return thunk
 
@@ -418,9 +417,9 @@ class MergeExecutor:
                         pats, folds, index_mode=(mode != "const")):
                     self._dispatch(q, pat, k, state, cap_override,
                                    step_est, fold)
-                counts = K.qid_counts_pos0(state.pos0(), state.n,
-                                           state.live_mask(), B=B, r=r,
-                                           slice_mode=slice_mode)
+                counts = K.wk_walk_merge_qid_counts(
+                    state.pos0(), state.n, state.live_mask(), B=B, r=r,
+                    slice_mode=slice_mode)
                 payload = (counts, [t for (_, t, _) in state.totals])
                 host_counts, totals = jax.device_get(payload)
                 _charge_merge("tpu.merge", state.totals, totals,
@@ -735,7 +734,7 @@ class MergeExecutor:
 
                 self.emit_counts["probe"] += 1
                 fd = TPUEngine._fp_dup(seg)
-                vals, parent, n, total = K.probe_expand(
+                vals, parent, n, total = K.wk_walk_merge_probe_expand(
                     seg.bkey, seg.bstart, seg.bdeg, seg.edges, cur,
                     state.n, state.live_mask(), cap_out=cap_out,
                     max_probe=seg.max_probe,
@@ -749,7 +748,7 @@ class MergeExecutor:
                 # the m-hot arm up to multiplicity MDUP, beyond that a
                 # device-side lax.cond falls back to the XLA emit
                 self.emit_counts["stream"] += 1
-                vals, parent, n, total = tpu_stream.stream_expand(
+                vals, parent, n, total = tpu_stream.wk_walk_merge_stream_expand(
                     seg.skey, seg.sstart, seg.sdeg, seg.edges, cur, state.n,
                     state.live_mask(), cap_out=cap_out,
                     interpret=tpu_stream.FORCE_INTERPRET,
@@ -757,7 +756,7 @@ class MergeExecutor:
                     mdup=tpu_stream.stream_mdup())
             else:
                 self.emit_counts["merge"] += 1
-                vals, parent, n, total = K.merge_expand(
+                vals, parent, n, total = K.wk_walk_merge_expand(
                     seg.skey, seg.sstart, seg.sdeg, seg.edges, cur, state.n,
                     state.live_mask(), cap_out=cap_out)
             state.levels.append(_Level(end, vals, parent))
@@ -780,7 +779,7 @@ class MergeExecutor:
 
                     vals = state.materialize(end)
                     fd = TPUEngine._fp_dup(seg)
-                    keep = K.member_mask_known(
+                    keep = K.wk_walk_member_mask_known(
                         cur[None, :], state.n, vals, seg.bkey, seg.bstart,
                         seg.bdeg, seg.edges, col=0,
                         max_probe=seg.max_probe, depth=seg.max_deg_log2,
@@ -793,21 +792,21 @@ class MergeExecutor:
                     keep = jnp.zeros(state.cap, dtype=bool)
                 else:
                     vals = state.materialize(end)
-                    keep = K.merge_member_pairs(
+                    keep = K.wk_walk_merge_member_pairs(
                         seg.ekey, seg.edges, jnp.int32(seg.num_edges),
                         cur, vals, state.n, state.live_mask())
         else:
             rev, real = eng.dstore.const_list(pid, d, end)
             if real >= state.cap * self._lookup_factor():
-                keep = K.member_list_binsearch(rev, jnp.int32(real), cur,
-                                               state.n, state.live_mask())
+                keep = K.wk_walk_merge_member_binsearch(
+                    rev, jnp.int32(real), cur, state.n, state.live_mask())
             else:
-                keep = K.merge_member_list(rev, jnp.int32(real), cur,
-                                           state.n, state.live_mask())
+                keep = K.wk_walk_merge_member_list(
+                    rev, jnp.int32(real), cur, state.n, state.live_mask())
         cap_new = self._member_cap(step, step_est, cap_override)
         if cap_new is not None and cap_new < state.cap:
             top = state.levels[-1]
-            vals, parent, n, total = K.merge_compact(
+            vals, parent, n, total = K.wk_walk_merge_compact(
                 top.vals, top.parent if top.parent is not None
                 else jnp.arange(state.cap, dtype=jnp.int32),
                 keep, state.n, cap_new)
@@ -921,7 +920,7 @@ class MergeExecutor:
                     seg_b += W * (3 * self._probe_rounds(pid, d) * cap
                                   + cap_out)
                 else:
-                    # merge_expand / stream_expand read skey+sstart+sdeg+
+                    # the merge and stream expands read skey+sstart+sdeg+
                     # edges (ekey stays untouched on the expand path)
                     if fold is not None:
                         nk, ne = seg_arrays(

@@ -107,9 +107,9 @@ def _probe_variant(mxu: bool, mhot: bool) -> str:
     edges = edges.at[0].set(big).at[1].set(65_537)
     cur = jnp.full(8, INT32_MAX, jnp.int32).at[5].set(3)
     live = jnp.ones(8, bool)
-    v, p, n, t = stream_expand(skey, sstart, sdeg, edges, cur,
-                               jnp.int32(6), live, cap_out=1024,
-                               mxu=mxu, mhot=mhot, mdup=stream_mdup())
+    v, p, n, t = wk_walk_merge_stream_expand(
+        skey, sstart, sdeg, edges, cur, jnp.int32(6), live, cap_out=1024,
+        mxu=mxu, mhot=mhot, mdup=stream_mdup())
     got = [(int(v[i]), int(p[i])) for i in range(min(int(n), 8))]
     if got != [(big, 5), (65_537, 5)]:
         return f"distinct-anchor probe emitted {got}"
@@ -118,9 +118,9 @@ def _probe_variant(mxu: bool, mhot: bool) -> str:
         # rows 1 and 5 both anchor key 3 — expect each edge twice
         # with both parents (edge-repeat order)
         cur2 = cur.at[1].set(3)
-        v, p, n, t = stream_expand(skey, sstart, sdeg, edges, cur2,
-                                   jnp.int32(6), live, cap_out=1024,
-                                   mxu=mxu, mhot=True, mdup=stream_mdup())
+        v, p, n, t = wk_walk_merge_stream_expand(
+            skey, sstart, sdeg, edges, cur2, jnp.int32(6), live,
+            cap_out=1024, mxu=mxu, mhot=True, mdup=stream_mdup())
         got = sorted((int(v[i]), int(p[i])) for i in range(min(int(n), 8)))
         want = sorted([(big, 1), (big, 5), (65_537, 1), (65_537, 5)])
         if int(t) != 4 or got != want:
@@ -651,14 +651,15 @@ def _stream_emit_m(edges2, dsel2, drow2, cap_out: int, interpret: bool = False,
 
 @partial(jax.jit, static_argnames=("cap_out", "interpret", "mxu", "mhot",
                                    "mdup"))
-def stream_expand(skey, sstart, sdeg, edges, cur, n, live, cap_out: int,
-                  interpret: bool = False, mxu: bool | None = None,
-                  mhot: bool = True, mdup: int = MDUP):
+def wk_walk_merge_stream_expand(skey, sstart, sdeg, edges, cur, n, live,
+                                cap_out: int, interpret: bool = False,
+                                mxu: bool | None = None, mhot: bool = True,
+                                mdup: int = MDUP):
     """known_to_unknown expansion with the streaming emitter: (val
     [cap_out], parent [cap_out], out_n, total).
 
     Distinct-anchor frontiers are bit-identical to
-    tpu_kernels.merge_expand (edge order = key-sorted anchor order).
+    tpu_kernels.wk_walk_merge_expand (edge order = key-sorted anchor order).
     Duplicate-anchor frontiers with per-key multiplicity <= MDUP stream
     through the m-hot kernel (edge-repeat order — a permutation of the
     same bag; downstream is order-insensitive); higher multiplicity falls

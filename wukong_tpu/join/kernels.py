@@ -151,9 +151,13 @@ def jit_kernels():
     import jax
     import jax.numpy as jnp
 
-    member = jax.jit(lambda s, v: member_sorted(s, v, xp=jnp))
-    pair = jax.jit(lambda k, o, e, a, v: pair_member(k, o, e, a, v, xp=jnp))
-    return member, pair
+    def wk_join_member(s, v):
+        return member_sorted(s, v, xp=jnp)
+
+    def wk_join_pair(k, o, e, a, v):
+        return pair_member(k, o, e, a, v, xp=jnp)
+
+    return jax.jit(wk_join_member), jax.jit(wk_join_pair)
 
 
 # ---------------------------------------------------------------------------
@@ -621,11 +625,11 @@ def jit_seed_extract():
     import jax
     import jax.numpy as jnp
 
-    def one(s, p, o, tp, ts, to, eq, ca, cb):
+    def wk_stream_seed_extract(s, p, o, tp, ts, to, eq, ca, cb):
         return seed_extract_term(s, p, o, tp, ts, to, eq, ca, cb, xp=jnp)
 
-    _SEED_EXTRACT_FN = jax.jit(
-        jax.vmap(one, in_axes=(None, None, None, 0, 0, 0, 0, 0, 0)))
+    _SEED_EXTRACT_FN = jax.jit(jax.vmap(
+        wk_stream_seed_extract, in_axes=(None, None, None, 0, 0, 0, 0, 0, 0)))
     return _SEED_EXTRACT_FN
 
 
@@ -663,8 +667,10 @@ def jit_concat_rows():
     import jax
     import jax.numpy as jnp
 
-    _CONCAT_ROWS_FN = jax.jit(
-        lambda st, c: concat_rows_padded(st, c, xp=jnp))
+    def wk_dist_concat_rows(st, c):
+        return concat_rows_padded(st, c, xp=jnp)
+
+    _CONCAT_ROWS_FN = jax.jit(wk_dist_concat_rows)
     return _CONCAT_ROWS_FN
 
 
@@ -682,7 +688,8 @@ def jit_seed_masks():
     import jax
     import jax.numpy as jnp
 
-    _SEED_MASK_FN = jax.jit(
-        lambda s, p, o, tp, ts, to, eq: seed_masks(s, p, o, tp, ts, to,
-                                                   eq, xp=jnp))
+    def wk_stream_seed_masks(s, p, o, tp, ts, to, eq):
+        return seed_masks(s, p, o, tp, ts, to, eq, xp=jnp)
+
+    _SEED_MASK_FN = jax.jit(wk_stream_seed_masks)
     return _SEED_MASK_FN

@@ -597,10 +597,12 @@ class WCOJExecutor:
                 except Exception as e:
                     device_failed(e)
             if mask is None:
-                mask = host_mask(row_idx, newcol)
+                with span(tr, "wcoj.probe.host"):
+                    mask = host_mask(row_idx, newcol)
                 state["slots"] += len(newcol)
-            kept_rows.append(row_idx[mask])
-            kept_vals.append(newcol[mask])
+            with span(tr, "wcoj.compact"):
+                kept_rows.append(row_idx[mask])
+                kept_vals.append(newcol[mask])
 
         pending = None
         for lo, hi in chunks:
@@ -633,12 +635,13 @@ class WCOJExecutor:
         if lvl_route == "device":
             _M_PROBE_LOOKUPS.labels(form="direct").inc(state["direct"])
             _M_PROBE_LOOKUPS.labels(form="search").inc(state["searched"])
-        row_idx = np.concatenate(kept_rows) if kept_rows else \
-            np.empty(0, dtype=np.int64)
-        newcol = np.concatenate(kept_vals) if kept_vals else \
-            np.empty(0, dtype=np.int64)
-        new_prefix = np.column_stack(
-            [prefix[row_idx], newcol]).astype(np.int64, copy=False)
+        with span(tr, "wcoj.compact"):
+            row_idx = np.concatenate(kept_rows) if kept_rows else \
+                np.empty(0, dtype=np.int64)
+            newcol = np.concatenate(kept_vals) if kept_vals else \
+                np.empty(0, dtype=np.int64)
+            new_prefix = np.column_stack(
+                [prefix[row_idx], newcol]).astype(np.int64, copy=False)
         if tr is not None:
             tr.event("join.level", var=int(v),
                      candidates=state["candidates"], slots=state["slots"],

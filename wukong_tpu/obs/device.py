@@ -288,7 +288,7 @@ class _SiteVariants:
     def __init__(self):
         self.variants: set = set()  # caller holds: device.compile (the compile lock)
         self.mints_us: deque = deque(maxlen=4096)  # caller holds: device.compile (the compile lock)
-        self.last_trip_us = 0
+        self.last_trip_us = None  # the storm cooldown cursor: never yet
 
 
 class CompileLedger:
@@ -330,7 +330,8 @@ class CompileLedger:
                 while sv.mints_us and now - sv.mints_us[0] > cool:
                     sv.mints_us.popleft()
                 if (len(sv.mints_us) > self._lim()
-                        and now - sv.last_trip_us >= cool):
+                        and (sv.last_trip_us is None
+                             or now - sv.last_trip_us >= cool)):
                     sv.last_trip_us = now
                     storm = len(sv.mints_us)
         return cold, storm

@@ -398,7 +398,7 @@ class _TemplateStats:
         self.count = 0
         self.example = ""
         self.trips = 0
-        self.last_trip_us = 0  # sentinel cooldown cursor
+        self.last_trip_us = None  # sentinel cooldown cursor: never yet
 
     def baseline(self) -> tuple[float, dict]:
         """(p95 total, mean component shares) over the current window."""
@@ -440,7 +440,8 @@ class LatencyAttributor:
                 st = self._templates[template] = _TemplateStats(win)
             if example and not st.example:
                 st.example = example
-            armed = (get_usec() - st.last_trip_us
+            armed = (st.last_trip_us is None
+                     or get_usec() - st.last_trip_us
                      >= Global.attribution_cooldown_s * 1_000_000)
             if armed and len(st.totals) >= max(
                     int(Global.attribution_min_samples), 2):

@@ -195,7 +195,7 @@ class _TenantSLO:
         self.good = 0
         self.errors = 0
         self.alerts = 0
-        self.last_alert_us = 0  # sentinel cooldown cursor
+        self.last_alert_us = None  # sentinel cooldown cursor: never yet
 
     def charge_bucket(self, now: int, good: bool, slow_window_s: int) -> None:
         """Caller holds the tracker lock. Bucket width tracks the slow
@@ -305,7 +305,7 @@ class SLOTracker:
         """Caller holds the tracker lock. The SRE-workbook multi-window
         rule: page only when BOTH the fast and the slow window burn the
         budget faster than their thresholds."""
-        if now - st.last_alert_us < max(
+        if st.last_alert_us is not None and now - st.last_alert_us < max(
                 int(Global.slo_dump_cooldown_s), 0) * 1_000_000:
             return None
         fast, n_fast = self._burn(

@@ -165,17 +165,6 @@ class QueryTrace:
     def dur_us(self) -> int:
         return (self.t1_us if self.t1_us is not None else get_usec()) - self.t0_us
 
-    def step_summary(self) -> dict[str, dict]:
-        """Aggregate span timings by name: the per-step time-breakdown
-        section bench artifacts carry ({name: {count, total_us, max_us}})."""
-        out: dict[str, dict] = {}
-        for sp in self.spans:  # unguarded: reporting surface — runs on finished traces (recorder/bench), after every writer ended
-            d = out.setdefault(sp.name, {"count": 0, "total_us": 0, "max_us": 0})
-            d["count"] += 1
-            d["total_us"] += sp.dur_us
-            d["max_us"] = max(d["max_us"], sp.dur_us)
-        return out
-
     def event_names(self) -> list[str]:
         return [n for sp in self.spans for (_t, n, _a) in sp.events]  # unguarded: reporting surface on finished traces
 

@@ -981,7 +981,7 @@ class DistEngine:
                 probes[i] = seg.max_probe if seg else 1
                 depths[i] = seg.max_deg_log2 if seg else 1
 
-        def shard_fn(*flat):
+        def wk_dist_chain(*flat):
             # unflatten per-step args (squeeze the leading shard axis)
             per_step = []
             it = iter(flat)
@@ -1004,7 +1004,7 @@ class DistEngine:
                     continue
                 if s.kind == "init_index":
                     edges, lens = per_step[i]
-                    table, n = K.init_from_list.__wrapped__(
+                    table, n = K.wk_walk_init_from_list.__wrapped__(
                         edges, lens[0], s.cap)
                     totals[i] = lens[0]
                     continue
@@ -1016,7 +1016,7 @@ class DistEngine:
                         n = jnp.int32(0)
                         continue
                     bkey, bstart, bdeg, edges = arrs
-                    table, n, tot = K.expand.__wrapped__(
+                    table, n, tot = K.wk_walk_expand.__wrapped__(
                         const_tab, jnp.int32(1), bkey, bstart, bdeg, edges,
                         col=0, cap_out=s.cap, max_probe=probes[i])
                     table = table[1:, :]  # drop the const row ([W, C] layout)
@@ -1031,9 +1031,9 @@ class DistEngine:
 
                 if s.kind == "member_index":
                     edges_i, lens = per_step[i]
-                    keep = K.member_mask_list.__wrapped__(
+                    keep = K.member_mask_list(
                         table, n, s.col, edges_i, lens[0])
-                    table, n = K.compact.__wrapped__(table, keep)
+                    table, n = K.wk_walk_compact.__wrapped__(table, keep)
                     continue
 
                 arrs = per_step[i]
@@ -1048,7 +1048,7 @@ class DistEngine:
                         n = jnp.int32(0)
                         continue
                     bkey, bstart, bdeg, edges, edges2 = arrs
-                    table, n, tot = K.expand2.__wrapped__(
+                    table, n, tot = K.wk_walk_expand2.__wrapped__(
                         table, n, bkey, bstart, bdeg, edges2, edges,
                         col=s.col, cap_out=s.cap, max_probe=probes[i])
                     totals[i] = jnp.maximum(totals[i], tot)
@@ -1058,7 +1058,7 @@ class DistEngine:
                         # only the predicate column
                         keep = (jnp.arange(s.cap, dtype=jnp.int32) < n) \
                             & (table[-1] == jnp.int32(s.const))
-                        table, n = K.compact.__wrapped__(table, keep)
+                        table, n = K.wk_walk_compact.__wrapped__(table, keep)
                         table = table[:-1]
                 elif s.kind in ("expand", "expand_type_all"):
                     if s.kind == "expand_type_all":
@@ -1070,7 +1070,7 @@ class DistEngine:
                         n = jnp.int32(0)
                         continue
                     bkey, bstart, bdeg, edges = arrs
-                    table, n, tot = K.expand.__wrapped__(
+                    table, n, tot = K.wk_walk_expand.__wrapped__(
                         table, n, bkey, bstart, bdeg, edges, col=s.col,
                         cap_out=s.cap, max_probe=probes[i])
                     totals[i] = jnp.maximum(totals[i], tot)
@@ -1083,10 +1083,10 @@ class DistEngine:
                             vals = table[s.vals_col]
                         else:
                             vals = jnp.full(table.shape[1], np.int32(s.const))
-                        keep = K.member_mask_known.__wrapped__(
+                        keep = K.wk_walk_member_mask_known.__wrapped__(
                             table, n, vals, bkey, bstart, bdeg, edges,
                             col=s.col, max_probe=probes[i], depth=depths[i])
-                    table, n = K.compact.__wrapped__(table, keep)
+                    table, n = K.wk_walk_compact.__wrapped__(table, keep)
 
             return {
                 "table": table[None],
@@ -1095,10 +1095,9 @@ class DistEngine:
             }
 
         out_specs = {"table": P(axis), "n": P(axis), "totals": P(axis)}
-        mapped = shard_map(shard_fn, mesh=self.mesh,
-                           in_specs=tuple(arg_specs), out_specs=out_specs,
-                           check_vma=False)
-        return jax.jit(mapped)
+        return jax.jit(shard_map(wk_dist_chain, mesh=self.mesh,
+                                 in_specs=tuple(arg_specs),
+                                 out_specs=out_specs, check_vma=False))
 
 
 def _gather_host(tree):

@@ -121,12 +121,12 @@ def _jit_scan(metric: str, k: int):
         import jax
         import jax.numpy as jnp
 
-        def scan(base, mask, anchor):
+        def wk_knn_scan(base, mask, anchor):
             s = scores(base, anchor[None, :], metric, jnp)[0]
             s = jnp.where(mask, s, -jnp.inf)
             return jax.lax.top_k(s, k)
 
-        fn = _SCAN_JIT_CACHE[(metric, k)] = jax.jit(scan)
+        fn = _SCAN_JIT_CACHE[(metric, k)] = jax.jit(wk_knn_scan)
     return fn
 
 
