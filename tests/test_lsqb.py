@@ -4,10 +4,11 @@ reference: the walk, the worst-case-optimal join with host and with device
 levels, a whole-plan template program, and the program's own choice; a level
 forced through more than one slice and more than one run of prefix rows; the
 spans, the ``join.level`` event and the counters of a traced reply; and the
-faults the scale-factor-10 deployment found, one test each; and since PR 35
-the probe's lookups by a table over the id range in every level: the jitted
-probe beside ``level_probe_host`` on the patterns' own levels, both sides of
-``direct_lookup_wins`` reached and counted, one program for any list."""
+faults the scale-factor-10 deployment found, one test each; the lookups by
+a table over the id range in every level, both sides of
+``direct_lookup_wins`` reached and counted, one program for any list; and
+the two programs of a level made on the device, each call beside its NumPy
+twin on the patterns' own levels, the levels counted where they are made."""
 
 import os
 import sys
@@ -163,48 +164,64 @@ def test_a_level_in_slices_and_runs_of_rows(world, name, monkeypatch):
     assert names.count("wcoj.probe.sync") > names.count("wcoj.level")
 
 
-def test_a_level_of_one_run_probes_as_levels_always_did(world, monkeypatch):
+def test_a_level_of_one_run_makes_one_call_a_group(world, monkeypatch):
     """Up to ``LEVEL_CHUNK_SLICES`` slices of candidates a level is one call
-    a generator group at the ``pad_pow2`` class of its candidates, each mask
-    fetched before the next group is staged, as before LSQB; a level in runs
-    is cut into slices and fetched late. Since PR 35 either passes the
-    cached ``id_bound`` of every adjacency it probes, and the store's vertex
-    bound for its list: the form of a lookup follows the call's shapes
-    (``direct_lookup_wins``), not the level's size."""
+    of ``wk_level_probe`` a generator group at the ``pad_pow2`` class of its
+    candidates, the calls dispatched together and their survivors fetched
+    after; a level in runs cuts a group into slices and fetches a run
+    later. Either looks its
+    ranges up once, passing every adjacency's cached ``id_bound``, and the
+    store's vertex bound for its list: the form of a lookup follows the
+    call's shapes (``direct_lookup_wins``), not the level's size."""
     proxy, owed = world
-    seen = []
-    real = wcoj_mod.jit_level_probe
+    probes, ranges = [], []
+    real_probe, real_ranges = wcoj_mod.jit_level_probe, \
+        wcoj_mod.jit_level_ranges
 
-    def spy(depths, has_glob, id_bounds=None, list_bound=None):
-        seen.append((depths, has_glob, id_bounds, list_bound))
-        return real(depths, has_glob, id_bounds, list_bound)
+    def spy_probe(gen, depths, has_list, list_bound, out_cap, rows_cap,
+                  used=None):
+        probes.append((gen, depths, has_list, list_bound, out_cap))
+        return real_probe(gen, depths, has_list, list_bound, out_cap,
+                          rows_cap, used)
 
-    monkeypatch.setattr(wcoj_mod, "jit_level_probe", spy)
+    def spy_ranges(id_bounds, anchor_of, has_list, used=None):
+        ranges.append((id_bounds, anchor_of, has_list))
+        return real_ranges(id_bounds, anchor_of, has_list, used)
+
+    monkeypatch.setattr(wcoj_mod, "jit_level_probe", spy_probe)
+    monkeypatch.setattr(wcoj_mod, "jit_level_ranges", spy_ranges)
     Global.enable_tracing = True
     vbound = wcoj_mod.store_vertex_bound(proxy.g)
 
     def passes_its_bounds():
-        assert seen and not getattr(q, "_join_device_broken", False)
-        for depths, has_glob, bounds, list_bound in seen:
-            assert isinstance(bounds, tuple) and len(bounds) == len(depths)
+        assert probes and ranges and not getattr(q, "_join_device_broken",
+                                                 False)
+        for bounds, anchor_of, _has_list in ranges:
+            assert isinstance(bounds, tuple) and len(bounds) == len(anchor_of)
             assert all(isinstance(b, int) and 0 < b <= vbound for b in bounds)
-            assert list_bound == (vbound if has_glob else None)
+        for _gen, _depths, has_list, list_bound, _cap in probes:
+            assert list_bound in (None, vbound)
+            assert list_bound == vbound or not has_list
 
     q = serve(proxy, "q3", "wcoj-device")
     passes_its_bounds()
+    made = [lv for lv in q.join_stats if lv["enumerated"] == "device"]
+    assert len(ranges) == len(made) > 0
     calls = [d for d in q.device_steps if d.get("site") == "wcoj.probe"]
     assert calls and all(d["capacity"] == kernels.pad_pow2(d["live"])
                          for d in calls)
     names = [sp.name for sp in q.trace.spans]
     at = [i for i, n in enumerate(names) if n == "wcoj.probe.dispatch"]
-    assert at and all(names[i + 1] == "wcoj.probe.sync" for i in at)
-    whole = list(seen)
-    del seen[:]
+    # a run's calls are dispatched together, then fetched in order
+    assert at and all(names[i + 1] in ("wcoj.probe.dispatch",
+                                       "wcoj.probe.sync") for i in at)
+    whole = {p[:4] for p in probes}
+    del probes[:]
     monkeypatch.setattr(kernels, "LEVEL_SLICE", 2048)
     q = serve(proxy, "q3", "wcoj-device")
     assert np.array_equal(rows_of(q), owed["q3"])
     passes_its_bounds()
-    assert set(seen) == set(whole)  # the same programs, at other classes
+    assert {p[:4] for p in probes} == whole  # the same groups, other classes
 
 
 @pytest.mark.parametrize("n,want", [
@@ -279,15 +296,22 @@ def test_spans_event_and_counters_of_a_traced_reply(world, name):
     routes = set()
     for (_n, a), lv, sp in zip(events, q.join_stats, levels):
         assert set(a) == {"var", "candidates", "slots", "rows_out", "route",
-                          "direct", "searched"}
+                          "direct", "searched", "enumerated"}
         assert (a["var"], a["candidates"], a["slots"], a["rows_out"],
-                a["route"], a["direct"], a["searched"]) == (
+                a["route"], a["direct"], a["searched"], a["enumerated"]) == (
                     lv["var"], lv["candidates"], lv["slots"], lv["rows_out"],
-                    lv["route"], lv["direct"], lv["searched"])
+                    lv["route"], lv["direct"], lv["searched"],
+                    lv["enumerated"])
         if a["route"] == "host":
             assert a["direct"] == a["searched"] == 0
+        # a device-route level with a bound adjacency (every level but the
+        # first here) makes its candidates on the chip
+        assert (a["enumerated"] == "device") == (
+            a["route"] == "device" and lv["level"] > 0)
         inside = [s.name for s in spans if s.parent == sp.index]
-        assert inside[0] == "wcoj.enumerate"
+        # the host enumerates first, or the device level ships its anchors
+        assert inside[0] in ("wcoj.enumerate", "wcoj.probe.stage")
+        assert "wcoj.enumerate" in inside
         # a device level stages; it dispatches and syncs unless its one
         # constraint is its own generator (q2's first level)
         assert ("wcoj.probe.stage" in inside) == (a["route"] == "device")
@@ -341,46 +365,66 @@ def test_the_host_compaction_has_its_span(world, name, route, monkeypatch):
             assert spans[sp.parent].name == "wcoj.level"
 
 
-def test_the_level_probe_has_a_stable_name():
+def test_the_level_programs_have_stable_names():
     import jax.numpy as jnp
 
-    fn = kernels.jit_level_probe((3,), False)
+    fn = kernels.jit_level_ranges((4,), (0,), False)
+    assert fn.__wrapped__.__name__ == "wk_level_ranges"
+    assert fn is kernels.jit_level_ranges((4,), (0,), False)
+    anchors = jnp.zeros((1, 8), dtype=jnp.int32)
+    keys, offsets = jnp.arange(4, dtype=jnp.int32), \
+        jnp.arange(5, dtype=jnp.int32)
+    assert "wk_level_ranges" in fn.lower(anchors, 0, keys, offsets).as_text(
+        debug_info=True)
+    fn = kernels.jit_level_probe(0, (3,), False, None, 8, 8)
     assert fn.__wrapped__.__name__ == "wk_level_probe"
-    assert fn is kernels.jit_level_probe((3,), False, (None,))
-    args = [jnp.ones(8, dtype=bool), jnp.arange(8, dtype=jnp.int32),
-            jnp.zeros(1, dtype=jnp.int32), jnp.arange(4, dtype=jnp.int32),
-            jnp.arange(5, dtype=jnp.int32) * 2,
-            jnp.arange(8, dtype=jnp.int32), jnp.zeros(8, dtype=jnp.int32)]
+    assert fn is kernels.jit_level_probe(0, (3,), False, 7, 8, 8)  # no list
+    args = [jnp.zeros(8, dtype=jnp.int8), jnp.zeros((1, 8), dtype=jnp.int32),
+            jnp.ones((1, 8), dtype=jnp.int32), jnp.array([0, 0, 8, 0]),
+            jnp.zeros(1, dtype=jnp.int32), jnp.arange(8, dtype=jnp.int32)]
     assert "wk_level_probe" in fn.lower(*args).as_text(debug_info=True)
 
 
-@pytest.mark.parametrize("rows", [64, 1 << 18])
-def test_a_probe_that_addresses_its_keys_equals_one_that_searches(rows):
-    """``id_bounds``: at 2^18 candidates over 3,000 keys the anchors' key
-    lookup addresses a table over the id range (``direct_lookup_wins``), at
-    64 it searches; both equal the NumPy probe."""
+def _adjacency(rng, nkeys, id_bound, max_deg=9, values=16):
+    """A CSR adjacency of ``nkeys`` keys under ``id_bound``, each with a
+    sorted run of up to ``max_deg - 1`` distinct values under ``values``."""
+    keys = np.sort(rng.choice(id_bound, nkeys, replace=False))
+    deg = rng.integers(0, max_deg, nkeys)
+    offsets = np.concatenate([[0], np.cumsum(deg)])
+    edges = np.concatenate([np.sort(rng.choice(values, d, replace=False))
+                            for d in deg]).astype(np.int64)
+    return keys, offsets, edges
+
+
+def _i32(*arrays):
     import jax.numpy as jnp
 
+    return [jnp.asarray(np.asarray(a).astype(np.int32)) for a in arrays]
+
+
+@pytest.mark.parametrize("rows", [64, 1 << 18])
+def test_ranges_that_address_their_keys_equal_ranges_that_search(rows):
+    """``wk_level_ranges``: at 2^18 prefix rows over 3,000 keys an
+    adjacency's key lookup addresses a table over the id range
+    (``direct_lookup_wins``), at 64 it searches; both equal the NumPy
+    ranges, the generator choice and the minimum degree included, with an
+    anchor that is no key, and -1 (a padding row), of degree 0."""
     rng = np.random.default_rng(rows)
     nkeys, id_bound = 3_000, 5_000
-    keys = np.sort(rng.choice(id_bound, nkeys, replace=False))
-    deg = rng.integers(0, 9, nkeys)
-    offsets = np.concatenate([[0], np.cumsum(deg)])
-    edges = np.concatenate([np.sort(rng.choice(16, d, replace=False))
-                            for d in deg]).astype(np.int64)
-    anchors = rng.integers(0, id_bound + 50, rows)
-    cand = rng.integers(0, 16, rows)
-    valid = rng.random(rows) < 0.9
-    want = kernels.level_probe_host(valid, cand, None, keys, offsets, edges,
-                                    anchors)
+    first = _adjacency(rng, nkeys, id_bound)
+    second = _adjacency(rng, nkeys, id_bound)
+    anchors = rng.integers(-1, id_bound + 50, (2, rows))
+    tables = [first[:2], second[:2]]
+    want = kernels.level_ranges(anchors, tables, (1, 0), 4)
     assert kernels.direct_lookup_wins(rows, nkeys, id_bound) == (rows > 64)
-    dev = [jnp.asarray(a.astype(np.int32)) for a in
-           (cand, np.zeros(1), keys, offsets, edges, anchors)]
-    for bounds in (None, (id_bound,)):
-        fn = kernels.jit_level_probe((5,), False, bounds)
-        got = np.asarray(fn(jnp.asarray(valid), *dev))
-        assert np.array_equal(got, want), bounds
-    assert want.any() and not want.all()
+    fn = kernels.jit_level_ranges((id_bound, id_bound), (1, 0), True)
+    got = fn(*_i32(anchors), 4, *_i32(*first[:2], *second[:2]))
+    for w, g in zip(want, got):
+        assert np.array_equal(np.asarray(g), w)
+    assert np.asarray(got[2]).dtype == np.int8
+    assert (want[3] == 0).any() and (want[3] > 0).any()
+    if rows > 64:  # every generator is somebody's: both, and the list
+        assert set(np.unique(want[2])) == {0, 1, 2}
 
 
 def _lookup_forms():
@@ -388,43 +432,81 @@ def _lookup_forms():
     return got.get("direct", 0), got.get("search", 0)
 
 
-@pytest.mark.parametrize("name", ["q2", "q3"])
-def test_the_probe_with_tables_equals_the_host_probe_level_by_level(
-        world, name, monkeypatch):
-    """Every call of the jitted probe that a pattern's levels make, with the
-    bounds the executor passes, equals ``level_probe_host`` on the same
-    padded tensors; the forms the executor counts are the forms the programs
-    were traced with (one scatter a table)."""
+def _scatters(fn, *args):
+    """The tables a program builds: its scatters that set at sorted
+    indices (the compaction sets at unsorted ones, the spread adds)."""
+    import re
+
     import jax
 
+    return sum("indices_are_sorted=True" in p for p in re.findall(
+        r" scatter\[([^\]]*)\]", str(jax.make_jaxpr(fn)(*args))))
+
+
+@pytest.mark.parametrize("name", ["q2", "q3"])
+def test_the_level_programs_equal_their_numpy_twins_level_by_level(
+        world, name, monkeypatch):
+    """Every call of the two level programs that a pattern's levels make
+    equals the NumPy twin (``kernels.level_ranges``, ``level_probe``) on
+    the same operands, survivor for survivor; the forms the executor
+    counts are the forms the programs were traced with: one sorted scatter
+    a table."""
     proxy, owed = world
-    real = wcoj_mod.jit_level_probe
-    calls, scatters = [], []
+    real_probe, real_ranges = wcoj_mod.jit_level_probe, \
+        wcoj_mod.jit_level_ranges
+    lookups, scatters = [], []
 
-    def spy(depths, has_glob, id_bounds=None, list_bound=None):
-        fn = real(depths, has_glob, id_bounds, list_bound)
+    def host(a):
+        return np.asarray(a).astype(np.int64)
 
-        def probe(*args):
-            got = fn(*args)
-            host = [np.asarray(a) for a in args]
-            want = kernels.level_probe_host(
-                host[0], host[1], host[2] if has_glob else None, *host[3:])
-            assert np.array_equal(np.asarray(got), want), (depths, id_bounds)
-            calls.append(len(depths) + has_glob)
-            scatters.append(str(jax.make_jaxpr(fn)(*args)).count(" scatter["))
+    def spy_ranges(id_bounds, anchor_of, has_list, used=None):
+        fn = real_ranges(id_bounds, anchor_of, has_list, used)
+
+        def ranges(anchors, list_len, *tables):
+            got = fn(anchors, list_len, *tables)
+            t = [host(a) for a in tables]
+            want = kernels.level_ranges(host(anchors),
+                                        list(zip(t[::2], t[1::2])), anchor_of,
+                                        list_len if has_list else None)
+            for w, g in zip(want, got):
+                assert np.array_equal(np.asarray(g), w)
+            lookups.append(len(id_bounds))
+            scatters.append(_scatters(fn, anchors, list_len, *tables))
+            return got
+
+        return ranges
+
+    def spy_probe(gen, depths, has_list, list_bound, out_cap, rows_cap,
+                  used=None):
+        fn = real_probe(gen, depths, has_list, list_bound, out_cap, rows_cap,
+                        used)
+
+        def probe(choice, starts, degs, window, glob, *edges):
+            got = fn(choice, starts, degs, window, glob, *edges)
+            want = kernels.level_probe(
+                np.asarray(choice), host(starts), host(degs), host(window),
+                host(glob), [host(e) for e in edges], gen, depths, has_list,
+                out_cap, rows_cap=rows_cap)
+            count = int(got[2])
+            assert count == int(want[2]) and count > 0
+            for w, g in zip(want[:2], got[:2]):
+                assert np.array_equal(np.asarray(g)[:count], w[:count])
+            lookups.append(int(has_list))
+            scatters.append(_scatters(fn, choice, starts, degs, window, glob,
+                                      *edges))
             return got
 
         return probe
 
-    monkeypatch.setattr(wcoj_mod, "jit_level_probe", spy)
+    monkeypatch.setattr(wcoj_mod, "jit_level_probe", spy_probe)
+    monkeypatch.setattr(wcoj_mod, "jit_level_ranges", spy_ranges)
     before = _lookup_forms()
     q = serve(proxy, name, "wcoj-device")
     assert np.array_equal(rows_of(q), owed[name])
     assert not getattr(q, "_join_device_broken", False)
     direct = sum(lv["direct"] for lv in q.join_stats)
     searched = sum(lv["searched"] for lv in q.join_stats)
-    assert len(calls) >= len(q.join_stats) - 1
-    assert (direct + searched, direct) == (sum(calls), sum(scatters))
+    assert (direct + searched, direct) == (sum(lookups), sum(scatters))
     now = _lookup_forms()
     assert (now[0] - before[0], now[1] - before[1]) == (direct, searched)
     # at these sizes every lookup of every level addresses a table
@@ -460,23 +542,55 @@ def test_one_probe_program_for_any_list_of_a_length():
     """The table's bound is the store's, not the list's: two lists of one
     length and different last ids run one compiled program (the length
     specialises it, as it always did; the values must not)."""
-    import jax.numpy as jnp
-
     bound, rows = 5_000, 4096
     rng = np.random.default_rng(35)
     low = np.sort(rng.choice(1_000, 300, replace=False))
     high = np.sort(rng.choice(np.arange(2_000, bound), 300, replace=False))
     assert low[-1] != high[-1]
     assert kernels.direct_lookup_wins(rows, 300, bound)
-    fn = kernels.jit_level_probe((), True, (), bound)
-    assert fn is kernels.jit_level_probe((), True, None, bound)
+    keys, offsets, edges = _adjacency(rng, 600, 1_000, max_deg=12,
+                                      values=bound)
+    anchors = rng.integers(0, 1_000, (1, 512))
+    starts, degs, choice, mins = kernels.level_ranges(
+        anchors, [(keys, offsets)], (0,), None)
+    assert 0 < int(mins.sum()) <= rows
+    fn = kernels.jit_level_probe(0, (5,), True, bound, rows, 512)
+    assert fn is kernels.jit_level_probe(0, (5,), True, bound, rows, 512)
     was = fn._cache_size()
-    cand = rng.integers(-10, bound + 10, rows)
-    valid = rng.random(rows) < 0.9
+    window = np.array([0, 0, 512, 0], dtype=np.int32)
     for lst in (low, high):
-        got = np.asarray(fn(jnp.asarray(valid),
-                            jnp.asarray(cand.astype(np.int32)),
-                            jnp.asarray(lst.astype(np.int32))))
-        assert np.array_equal(got, kernels.level_probe_host(valid, cand, lst))
-        assert got.any()
+        got = fn(*_i32(choice), *_i32(starts, degs, window, lst, edges))
+        want = kernels.level_probe(choice, starts, degs, window, lst,
+                                   [edges], 0, (5,), True, rows)
+        count = int(got[2])
+        assert count == int(want[2]) > 0
+        assert np.array_equal(np.asarray(got[1])[:count], want[1][:count])
+        assert np.isin(want[1][:count], lst).all()
     assert fn._cache_size() == was + 1
+
+
+@pytest.mark.parametrize("name", ["q2", "q3"])
+def test_levels_made_on_the_device_are_counted(world, name):
+    """``wukong_join_level_enumerations_total{where}`` counts a level a
+    reply by where its candidates were made: on the chip every level of
+    the device route after the first (the first binds no adjacency: its
+    candidates are the list's, made on the host), on the host the rest;
+    the ``join.level`` event says the same in ``enumerated``."""
+    proxy, _owed = world
+    Global.enable_tracing = True
+    Global.join_device_min_candidates = 4096  # small levels stay on the host
+    before = _family("wukong_join_level_enumerations_total", "where")
+    q = serve(proxy, name, "auto")
+    assert q.join_route == "device"
+    now = _family("wukong_join_level_enumerations_total", "where")
+    rose = {w: now.get(w, 0) - before.get(w, 0) for w in ("device", "host")}
+    events = [a for sp in q.trace.spans for _t, n, a in sp.events
+              if n == "join.level"]
+    on_device = [lv["level"] for lv in q.join_stats
+                 if lv["route"] == "device"]
+    made = [a["var"] for a in events if a["enumerated"] == "device"]
+    assert rose == {"device": len(made),
+                    "host": len(q.join_stats) - len(made)}
+    assert [lv["level"] for lv in q.join_stats
+            if lv["enumerated"] == "device"] == \
+        [k for k in on_device if k > 0] and made

@@ -136,20 +136,30 @@ def test_a_template_program_scopes_its_steps():
         assert scope in text, scope
 
 
-def test_the_level_probe_runs_under_its_name(tmp_path):
+def test_the_level_programs_run_under_their_names(tmp_path):
     import jax
     import jax.numpy as jnp
 
     from wukong_tpu.join import kernels
 
-    fn = kernels.jit_level_probe((3,), False)
-    args = [jnp.ones(8, dtype=bool), jnp.arange(8, dtype=jnp.int32),
-            jnp.zeros(1, dtype=jnp.int32), jnp.arange(4, dtype=jnp.int32),
-            jnp.arange(5, dtype=jnp.int32) * 2,
-            jnp.arange(8, dtype=jnp.int32), jnp.zeros(8, dtype=jnp.int32)]
-    jax.block_until_ready(fn(*args))  # compiled, and the operands made
+    ranges = kernels.jit_level_ranges((4,), (0,), False)
+    anchors = jnp.arange(8, dtype=jnp.int32).reshape(1, 8) % 5
+    keys, offsets = jnp.arange(4, dtype=jnp.int32), \
+        jnp.arange(5, dtype=jnp.int32) * 2
+    edges = jnp.arange(8, dtype=jnp.int32)
+    jax.block_until_ready(ranges(anchors, 0, keys, offsets))
+    probe = kernels.jit_level_probe(0, (3,), False, None, 16, 8)
+    window, dummy = np.array([0, 0, 8, 0], dtype=np.int32), \
+        jnp.zeros(1, dtype=jnp.int32)
     got = []
-    ops = profiled(tmp_path, lambda: got.append(
-        jax.block_until_ready(fn(*args))))
-    assert set(ops) == {"jit_wk_level_probe"}
-    assert np.asarray(got[0]).dtype == bool
+
+    def level():  # as the executor calls them: no eager operation between
+        starts, degs, choice, _mins = ranges(anchors, 0, keys, offsets)
+        got.append(jax.block_until_ready(probe(
+            choice, starts, degs, window, dummy, edges)))
+
+    level()  # compiled, and the operands made
+    del got[:]
+    ops = profiled(tmp_path, level)
+    assert set(ops) == {"jit_wk_level_ranges", "jit_wk_level_probe"}
+    assert int(got[0][2]) == 14  # seven rows' runs of two; 4 is no key

@@ -205,57 +205,74 @@ def test_c3_last_expansion_compiles_smaller_at_its_finer_class(one_chip):
         assert 0 < small < 0.6 * large, what  # the classes: 0.5625
 
 
-@pytest.mark.parametrize("case", ["knows", "hasCreator"])
-def test_level_probe_compiles_at_lsqb_sizes(one_chip, case):
-    """One slice of the join's level probe (``kernels.LEVEL_SLICE`` = 2^22
-    candidates) over LSQB's tables at scale factor 10: q3's widest level
-    probes ``knows`` (73,000 keys, 3.9 M edges, a table over 204,000 ids for
-    the anchors) and ``isPartOf``; q2's probes ``hasCreator`` keyed by 29.3 M
-    messages (a table over 30.2 M ids) beside the list of 21.9 M comments.
-    Each is a few seconds of compiling and under 0.5 GiB of temporaries,
-    where the level as one ``pad_pow2`` tensor was 2^27 slots."""
+# a level of the join's device route at LSQB's sizes (PERF.md section 4 has
+# the counts): (prefix rows, adjacencies as (keys, edges, id bound, search
+# depth), the prefix column each anchors on, the generator, the list as
+# (length, the store's vertex bound) or None, the slots of one call)
+KNOWS3 = (27_000, 1_090_334, 158_072, 11)
+KNOWS10 = (73_000, 3_906_840, 204_100, 12)
+CREATOR3 = (10_841_688, 10_841_688, 11_245_376, 2)
+CREATOR10 = (29_300_000, 29_300_000, 30_200_000, 2)
+LEVELS = {
+    # q3's widest level at scale factor 3, one tensor of 2^24 slots
+    "q3_widest_sf3": (459_816, (KNOWS3, KNOWS3), (0, 1), 0, None, 1 << 24),
+    # one slice of q3's widest level at scale factor 10
+    "q3_slice_sf10": (1_714_554, (KNOWS10, KNOWS10), (0, 1), 0, None,
+                      1 << 22),
+    # q2's last level at scale factor 3: the comment's author, known by the
+    # post's, in the persons' list
+    "q2_last_level_sf3": (4_557_932, (CREATOR3, KNOWS3), (0, 1), 0,
+                          (27_000, 11_245_376), 1 << 23),
+    # q2's third level: a post's replies in the comments' list
+    "q2_comments_sf3": (1_410_892, ((2_737_800, 4_557_932, 11_245_376, 9),),
+                        (0,), 0, (8_103_888, 11_245_376), 1 << 23),
+    # one slice of q2's last level at scale factor 10
+    "q2_slice_sf10": (12_300_000, (CREATOR10, KNOWS10), (0, 1), 0,
+                      (73_000, 30_200_000), 1 << 22),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LEVELS))
+def test_level_programs_compile_at_lsqb_sizes(one_chip, case):
+    """The two programs of a level made on the chip: ``wk_level_ranges``
+    over every prefix row (each adjacency's keys a table over the id
+    range, by ``direct_lookup_wins`` at these shapes: no ``while``) and
+    ``wk_level_probe`` for one call (the rows spread over the slots, the
+    edge-run search of the other adjacency in one loop, the list's
+    membership as a table, the compaction by a scatter of a permutation a
+    column). A probe compiles to 15.7-19.9 MB of program text at these
+    shapes and a ranges program to 2.4-3.6, which stays resident while it
+    is cached; the probe's temporaries stay under 1 GiB (874 MB at q3's
+    2^24 slots and at a slice of q2 over 2^24 rows: the spread rows, the
+    searches' cursors, the compaction's permutation), the ranges' under
+    0.5 GiB (403 MB)."""
     from wukong_tpu.join import kernels
 
     i = partial(_i32, one_chip)
-    S = kernels.LEVEL_SLICE
-    valid = jax.ShapeDtypeStruct((S,), jnp.bool_, sharding=one_chip)
-    if case == "knows":
-        fn = kernels.jit_level_probe((11, 8), False, (204_100, 205_500))
-        args = [i(1), i(73_000), i(73_001), i(3_950_000), i(S),
-                i(1_343), i(1_344), i(1_343), i(S)]
-    else:
-        fn = kernels.jit_level_probe((2,), True, (30_200_000,))
-        args = [i(21_900_000), i(29_300_000), i(29_300_001), i(29_300_000),
-                i(S)]
-    compiled = _compile(fn.lower(valid, i(S), *args), f"wk_level_probe[{case}]",
-                        False)
-    assert compiled.memory_analysis().temp_size_in_bytes < 512 << 20
-
-
-@pytest.mark.parametrize("case", ["q2_last_level", "q2_comments"])
-def test_level_probe_of_one_run_addresses_tables_at_lsqb_sizes(one_chip, case):
-    """A level of one run at LSQB's scale factor 3 (2^23 slots): q2's last
-    level looks the post's author up among ``knows``' 27,000 keys and the
-    candidate in the persons' list of 27,000; its third level looks the
-    candidate up in the comments' list of 8,103,888. Since PR 35 each is a
-    table over the id range (``direct_lookup_wins`` at these shapes), so the
-    program holds no ``while`` (``searchsorted``'s loop: 15 and 23 rounds of
-    a gather a slot), and its temporaries stay under 0.3 GiB."""
-    from wukong_tpu.join import kernels
-
-    i = partial(_i32, one_chip)
-    S, vbound = 1 << 23, 11_245_376
-    valid = jax.ShapeDtypeStruct((S,), jnp.bool_, sharding=one_chip)
-    if case == "q2_last_level":
-        assert kernels.direct_lookup_wins(S, 27_000, 158_072)
-        assert kernels.direct_lookup_wins(S, 27_000, vbound)
-        fn = kernels.jit_level_probe((11,), True, (158_072,), vbound)
-        args = [i(27_000), i(27_000), i(27_001), i(1_090_334), i(S)]
-    else:
-        assert kernels.direct_lookup_wins(S, 8_103_888, vbound)
-        fn = kernels.jit_level_probe((), True, (), vbound)
-        args = [i(8_103_888)]
-    compiled = _compile(fn.lower(valid, i(S), *args),
-                        f"wk_level_probe[{case}]", False)
-    assert "while" not in compiled.as_text()
-    assert compiled.memory_analysis().temp_size_in_bytes < 300 << 20
+    n, adj, anchor_of, gen, lst, slots = LEVELS[case]
+    rows = kernels.pad_pow2(n)
+    for nkeys, _ne, bound, _d in adj:
+        assert kernels.direct_lookup_wins(rows, nkeys, bound)
+    ranges = kernels.jit_level_ranges(tuple(a[2] for a in adj), anchor_of,
+                                      lst is not None)
+    tables = [x for nkeys, _ne, _b, _d in adj for x in (i(nkeys),
+                                                        i(nkeys + 1))]
+    looked = _compile(ranges.lower(i(max(anchor_of) + 1, rows), i(), *tables),
+                      f"wk_level_ranges[{case}]", False)
+    if lst is not None:
+        assert kernels.direct_lookup_wins(slots, *lst)
+    probe = kernels.jit_level_probe(gen, tuple(a[3] for a in adj),
+                                    lst is not None,
+                                    None if lst is None else lst[1], slots,
+                                    rows)
+    choice = jax.ShapeDtypeStruct((rows,), jnp.int8, sharding=one_chip)
+    probed = _compile(probe.lower(
+        choice, i(len(adj), rows), i(len(adj), rows), i(4),
+        i(1 if lst is None else lst[0]), *[i(a[1]) for a in adj]),
+        f"wk_level_probe[{case}]", False)
+    assert "while" not in looked.as_text()
+    assert probed.as_text().count(" while(") == (len(adj) > 1)
+    for c, limit in ((looked, 1 << 29), (probed, 1 << 30)):
+        mem = c.memory_analysis()
+        assert mem.temp_size_in_bytes < limit
+        assert mem.generated_code_size_in_bytes < 22 << 20

@@ -607,44 +607,184 @@ def test_kernels_jit_empty_candidate_lists():
                                            seg.edges, empty, empty))
 
 
-def test_kernels_level_probe_all_padding_and_singletons():
-    """The padded level probe: all-padding buckets come back all-False,
-    singleton ragged rows (degree-1 runs) and live/padding mixes match
-    the NumPy twin exactly."""
+def _group_oracle(choice, starts, degs, window, glob, segs, gen, cap):
+    """What one call of the level program keeps, one candidate at a time:
+    the group's candidates in the host's order, ``cap`` of them from the
+    window's base on, those every other constraint passes."""
+    lo, hi, base = window
+    cand = []
+    for r in range(lo, hi):
+        if choice[r] == gen:
+            run = glob if gen == len(segs) else \
+                segs[gen].edges[starts[gen][r]:starts[gen][r] + degs[gen][r]]
+            cand += [(r, int(x)) for x in run]
+    keep = []
+    for r, x in cand[base:base + cap]:
+        ok = gen == len(segs) or x in glob
+        for i, seg in enumerate(segs):
+            if i != gen:
+                ok = ok and x in seg.edges[starts[i][r]:starts[i][r]
+                                           + degs[i][r]]
+        if ok:
+            keep.append((r, x))
+    return keep
+
+
+def test_kernels_level_probe_windows_singletons_and_a_base():
+    """The level program against its NumPy twin and a one-candidate-at-a-
+    time oracle: each generator (two adjacencies, one of degree-1 runs, and
+    the list), a window of no row of its generator (count 0), a window of
+    some rows, and a window that starts inside a row's run (``base``) and
+    stops inside a later one (``out_cap``), each over the level's rows and
+    over a run's own (``rows_cap`` from ``row0``), survivor for survivor.
+    """
     from wukong_tpu.join.kernels import (
         jit_level_probe,
-        level_probe_host,
+        level_probe,
+        level_ranges,
         pad_pow2,
         to_device_i32,
     )
-
-    seg, rng = _rand_csr(seed=11)
-    # degree-1 CSR (singleton ragged rows) as the second adjacency
     from wukong_tpu.store.segment import CSRSegment
 
-    k1 = np.arange(50)
-    seg1 = CSRSegment.from_pairs(k1, rng.integers(0, 80, 50))
+    seg, rng = _rand_csr(seed=11)
+    seg1 = CSRSegment.from_pairs(np.arange(50), rng.integers(0, 80, 50))
     glob = np.unique(rng.integers(0, 80, 30))
-    for C in (0, 1, 7, 33):  # incl. the all-padding bucket (C == 0)
-        Cp = pad_pow2(C, floor=16)
-        valid = np.zeros(Cp, dtype=bool)
-        valid[:C] = True
-        cand = rng.integers(0, 80, Cp).astype(np.int64)
-        a0 = rng.integers(0, 60, Cp).astype(np.int64)
-        a1 = rng.integers(0, 50, Cp).astype(np.int64)
-        want = level_probe_host(valid, cand, glob,
-                                seg.keys, seg.offsets, seg.edges, a0,
-                                seg1.keys, seg1.offsets, seg1.edges, a1)
-        fn = jit_level_probe((8, 2), True)  # generous depths converge
-        got = np.asarray(fn(
-            np.asarray(valid), to_device_i32(cand), to_device_i32(glob),
-            to_device_i32(seg.keys), to_device_i32(seg.offsets),
-            to_device_i32(seg.edges), to_device_i32(a0),
-            to_device_i32(seg1.keys), to_device_i32(seg1.offsets),
-            to_device_i32(seg1.edges), to_device_i32(a1)))
-        assert np.array_equal(got, want), C
-        if C == 0:
-            assert not got.any()  # all-padding: nothing may pass
+    n = 64
+    anchors = np.stack([rng.integers(0, 70, n), rng.integers(0, 60, n)])
+    starts, degs, _choice, _mins = level_ranges(
+        anchors, [(seg.keys, seg.offsets), (seg1.keys, seg1.offsets)],
+        (0, 1), len(glob))
+    choice = rng.integers(0, 3, n).astype(np.int8)  # any rows to any group
+    dev = [to_device_i32(a) for a in (starts, degs, glob, seg.edges,
+                                      seg1.edges)]
+    seen = set()
+    for gen, rows_cap in [(g, r) for g in (0, 1, 2) for r in (n, 32)]:
+        for window, cap in (((0, 0, 0), 16), ((0, n, 0), None),
+                            ((10, 40, 0), None), ((10, 40, 3), 8)):
+            if window[1] - window[0] > rows_cap:
+                continue
+            sizes = degs[gen] if gen < 2 else np.full(n, len(glob))
+            total = int(sizes[window[0]:window[1]][
+                choice[window[0]:window[1]] == gen].sum())
+            cap = cap or pad_pow2(total, floor=16)
+            win = np.array((min(window[0], n - rows_cap),) + window,
+                           dtype=np.int32)
+            want = level_probe(choice, starts, degs, win, glob,
+                               [seg.edges, seg1.edges], gen, (8, 2),
+                               gen != 2, cap, rows_cap=rows_cap)
+            fn = jit_level_probe(gen, (8, 2), gen != 2, None, cap, rows_cap)
+            got = fn(np.asarray(choice), dev[0], dev[1], win, dev[2],
+                     dev[3], dev[4])
+            count = int(got[2])
+            assert count == int(want[2]), (gen, window)
+            pairs = list(zip(np.asarray(got[0])[:count].tolist(),
+                             np.asarray(got[1])[:count].tolist()))
+            assert pairs == list(zip(want[0][:count].tolist(),
+                                     want[1][:count].tolist()))
+            assert pairs == _group_oracle(choice, starts, degs, window, glob,
+                                          [seg, seg1], gen, cap)
+            seen.add((window == (0, 0, 0), count > 0, rows_cap))
+    assert {(True, False, 32), (False, True, 32), (False, True, n)} <= seen
+    # an empty window keeps nothing
+    assert not any(empty and kept for empty, kept, _r in seen)
+
+
+# the device-made level against the host's, through ``_level``: the worlds
+# level by level, and the triangle's last level on prefixes made to hold
+# what the worlds do not (a small list, rows of degree 0, no candidate, a
+# level in runs, an id past int32)
+LEVEL_CASES = ("triangle", "diamond", "clique4", "list_generated",
+               "degree_zero_rows", "empty_level", "level_in_runs",
+               "int32_degrades")
+
+
+@pytest.mark.parametrize("case", LEVEL_CASES)
+def test_a_level_made_on_the_device_equals_the_host_level(case, monkeypatch):
+    """A level of the device route makes its candidates on the chip and
+    keeps the survivors the host path keeps, in its order: the new prefix
+    is the host level's array for array, and so are the candidates. An id
+    past int32 in the prefix degrades the level to the host, latched for
+    the query and counted as ``int32_range``."""
+    from wukong_tpu.join import kernels
+    from wukong_tpu.obs.metrics import get_registry
+    from wukong_tpu.store.gstore import build_partition
+
+    def fallbacks():
+        snap = get_registry().snapshot()
+        return sum(x["value"] for x in (snap.get(
+            "wukong_join_device_fallback_total") or {}).get("series", [])
+            if x["labels"].get("reason") == "int32_range")
+
+    triples, meta = WORLDS[case if case in WORLDS else "triangle"]()
+    g = build_partition(triples, 0, 1)
+    ex = WCOJExecutor(g)
+    q0 = mkq(meta)
+    heuristic_plan(q0)
+    qg, unary = ex._analyze_and_warm(q0)
+    Global.join_device = "device"  # the device floor: one candidate
+    prefixes = [np.empty((1, 0), dtype=np.int64)]
+    for k, v in enumerate(qg.order):
+        cols = {qg.order[i]: i for i in range(k)}
+        prefixes.append(ex._level(qg, v, k, prefixes[-1], cols, unary[v],
+                                  "host")[0])
+    calls = []
+    real = WCOJExecutor._probe_start
+
+    def spy(self, lvl, groups, lo, hi, whole, tr=None):
+        calls.append((lvl["generators"], [j for j, _c in groups], whole))
+        return real(self, lvl, groups, lo, hi, whole, tr)
+
+    monkeypatch.setattr(WCOJExecutor, "_probe_start", spy)
+
+    def both(k, prefix, lst):
+        v, cols = qg.order[k], {qg.order[i]: i for i in range(k)}
+        host = ex._level(qg, v, k, prefix, cols, lst, "host")
+        q = SPARQLQuery()
+        made = ex._level(qg, v, k, prefix, cols, lst, "device", q)
+        assert np.array_equal(made[0], host[0]), (case, k)
+        assert made[1]["candidates"] == host[1]["candidates"]
+        assert host[1]["enumerated"] == "host"
+        return made[1], host[1], q
+
+    if case in WORLDS:
+        for k in range(1, len(qg.order)):
+            rec, _host, _q = both(k, prefixes[k], unary[qg.order[k]])
+            assert (rec["route"], rec["enumerated"]) == ("device", "device")
+            assert rec["candidates"] > 0
+        return
+    k = len(qg.order) - 1
+    prefix, lst = prefixes[k].copy(), list(unary[qg.order[k]])
+    nobody = store_vertex_bound(g) + 7  # no adjacency's key
+    if case == "list_generated":
+        ends = np.unique(prefixes[k + 1][:, -1])
+        lst.append(np.sort(np.random.default_rng(3).choice(ends, 3,
+                                                           replace=False)))
+    elif case == "degree_zero_rows":
+        prefix[::2] = nobody
+    elif case == "empty_level":
+        prefix[:] = nobody
+    elif case == "level_in_runs":
+        monkeypatch.setattr(kernels, "LEVEL_SLICE", 16)
+    elif case == "int32_degrades":
+        prefix[0] = 1 << 31
+    was = fallbacks()
+    rec, host, q = both(k, prefix, lst)
+    if case == "int32_degrades":
+        assert q._join_device_broken and fallbacks() == was + 1
+        assert (rec["route"], rec["enumerated"]) == ("host", "host")
+        return
+    assert (rec["route"], rec["enumerated"]) == ("device", "device")
+    if case == "list_generated":  # some rows took the list as generator
+        assert any(gens - 1 in gen for gens, gen, _w in calls)
+    elif case == "degree_zero_rows":
+        assert 0 < rec["candidates"] < both(k, prefixes[k], lst)[0][
+            "candidates"]
+    elif case == "empty_level":
+        assert rec["candidates"] == 0 and not calls
+    elif case == "level_in_runs":
+        assert calls and not any(whole for _g, _j, whole in calls)
+        assert len(calls) > 1
 
 
 # the list's membership on the device: (the sorted list, the candidates, the
@@ -802,11 +942,14 @@ def test_wcoj_device_route_byte_identical(world):
     assert all(lv["route"] == "host" for lv in qh.join_stats), name
 
 
-@pytest.mark.parametrize("half", ["_probe_start", "_probe_finish"])
+@pytest.mark.parametrize("half", ["_device_ranges", "_probe_start",
+                                  "_probe_finish"])
 def test_wcoj_device_failure_degrades_to_host(world, monkeypatch, half):
-    """Any device-path failure, where the probe is dispatched or where its
-    mask is fetched, degrades the level (and latches the rest of the
-    query) to the host kernels — correct rows, never an error."""
+    """Any device-path failure, where the ranges are looked up, where a
+    group's candidates are dispatched or where the survivors are fetched,
+    degrades the level (and latches the rest of the query) to the host
+    kernels — the same table, row order included, never an error. The
+    first level has no bound adjacency and never reaches the device."""
     name, _t, g, stats, meta = world
     Global.join_device = "device"
     wc = WCOJExecutor(g, stats=stats)
@@ -817,12 +960,14 @@ def test_wcoj_device_failure_degrades_to_host(world, monkeypatch, half):
     heuristic_plan(q)
     wc.execute(q)
     assert q.result.status_code == ErrorCode.SUCCESS
-    assert all(lv["route"] == "host" for lv in q.join_stats)
+    assert q._join_device_broken
+    assert all(lv["route"] == "host" for lv in q.join_stats[1:])
+    assert all(lv["enumerated"] == "host" for lv in q.join_stats)
     qh = mkq(meta)
     heuristic_plan(qh)
     Global.join_device = "host"
     WCOJExecutor(g, stats=stats).execute(qh)
-    assert rows_of(q) == rows_of(qh), name
+    assert np.array_equal(q.result.table, qh.result.table), name
 
 
 def test_choose_join_route_knob_and_threshold(world):
@@ -868,6 +1013,77 @@ def test_proxy_route_memoized_and_demoted(tri_proxy, monkeypatch):
     Global.join_device_min_candidates = 1
     q3 = proxy.run_single_query(text, blind=False)
     assert q3.join_route == "device"
+
+
+@pytest.mark.parametrize("floor", [1, 1 << 40])
+def test_a_proxy_request_on_the_device_route_answers_as_the_host(
+        tri_proxy, monkeypatch, floor):
+    """A request the proxy sends down the join's device route answers the
+    table the host route answers: every level with a bound adjacency made
+    on the chip (a floor of one candidate), or none (a floor no level
+    reaches); the demoted request that follows, on the host route,
+    answers the same table."""
+    from wukong_tpu.planner.optimizer import Planner as _P
+
+    proxy, text = tri_proxy
+    Global.wcoj_min_rows = 1
+    Global.wcoj_ratio = 1
+    monkeypatch.setattr(_P, "choose_join_route",
+                        lambda self, pats: "device")
+    monkeypatch.setattr(WCOJExecutor, "_device_floor",
+                        staticmethod(lambda: floor))
+    q = proxy.run_single_query(text, blind=False)
+    assert q.join_route == "device"
+    made = [lv["enumerated"] for lv in q.join_stats]
+    assert ("device" in made) == (floor == 1)
+    assert bool(getattr(q, "_join_programs", None)) == (floor == 1)
+    # the triangle's measured candidates sit under the threshold: demoted
+    qh = proxy.run_single_query(text, blind=False)
+    assert qh.join_route == "host"
+    assert np.array_equal(q.result.table, qh.result.table)
+
+
+@pytest.mark.parametrize("shared", [False, True])
+def test_a_demoted_template_lets_go_the_programs_only_it_ran(
+        tri_proxy, monkeypatch, shared):
+    """The level programs a request ran belong to its template; a demotion
+    off the join's device route lets go each one no other template ran
+    (their text is resident while cached), and keeps one another template
+    ran too; the next request of the template takes the host route."""
+    from wukong_tpu.join import kernels
+    from wukong_tpu.planner.optimizer import Planner as _P
+
+    def cached(keys):
+        return {k for k in keys if k in (kernels._LEVEL_RANGES_CACHE
+                                         if k[0] == "ranges"
+                                         else kernels._LEVEL_PROBE_CACHE)}
+
+    proxy, text = tri_proxy
+    Global.wcoj_min_rows = 1
+    Global.wcoj_ratio = 1
+    monkeypatch.setattr(_P, "choose_join_route",
+                        lambda self, pats: "device")
+    # every level of the route on the device, where the measured volume
+    # (under join_device_min_candidates) demotes the template after
+    monkeypatch.setattr(WCOJExecutor, "_device_floor", staticmethod(lambda: 1))
+    if shared:  # another template's request ran the same programs
+        real = WCOJExecutor._device_level
+
+        def spy(self, adj, G, prefix, q, k, tr):
+            got = real(self, adj, G, prefix, q, k, tr)
+            kernels.own_level_programs(q._join_programs, "another")
+            return got
+
+        monkeypatch.setattr(WCOJExecutor, "_device_level", spy)
+    q = proxy.run_single_query(text, blind=False)
+    assert q.join_route == "device"
+    used = q._join_programs
+    assert {k[0] for k in used} == {"ranges", "probe"}
+    # the triangle's measured candidates sit under the threshold: demoted
+    assert cached(used) == (used if shared else set())
+    assert proxy.run_single_query(text, blind=False).join_route == "host"
+    kernels.disown_level_programs("another")
+    assert not cached(used)
 
 
 def test_proxy_route_demoted_after_device_failure(tri_proxy, monkeypatch):

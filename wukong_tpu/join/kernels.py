@@ -12,9 +12,11 @@ the slowest thing a TPU does an element (the readings are beside
 addresses the domain instead, by one rule on static shapes
 (:func:`direct_lookup_wins`): the whole-plan template programs
 (:func:`expand_padded_device`, :func:`lookup_ranges_device`) and, in every
-level, the join's level probe (:func:`jit_level_probe`: the anchors' keys by
-:func:`lookup_ranges_device`, the class list by
-:func:`member_sorted_device`), with the NumPy forms as their parity oracle.
+level on the join's device route, its two programs: :func:`jit_level_ranges`
+(the prefix rows' anchors looked up by :func:`lookup_ranges_device`, once a
+level) and :func:`jit_level_probe` (the candidates expanded, probed and
+compacted on the chip, the class list by :func:`member_sorted_device`),
+with the NumPy forms as their parity oracle.
 
 Data model: adjacency is the store's CSR triplet (sorted unique ``keys``,
 ``offsets``, ``edges`` sorted within each key run); candidate sets are
@@ -25,7 +27,15 @@ each row's [start, end) edge range.
 
 from __future__ import annotations
 
+import sys
+
 import numpy as np
+
+from wukong_tpu.analysis.lockdep import (
+    declare_leaf,
+    make_lock,
+    register_global_lock,
+)
 
 
 def member_sorted(sorted_arr, vals, xp=np):
@@ -117,30 +127,53 @@ def pair_member(keys, offsets, edges, anchors, vals, xp=np, depth=None,
     the anchors' key lookup take :func:`lookup_ranges_device`'s direct
     form where its shape rule says so; without it the lookup searches.
     """
-    ne = int(edges.shape[0])
-    if ne == 0:
+    if int(edges.shape[0]) == 0:
         return xp.zeros(anchors.shape[0], dtype=bool)
     if id_bound is None or xp is np:
         start, deg = lookup_ranges(keys, offsets, anchors, xp=xp)
     else:
         start, deg = lookup_ranges_device(keys, offsets, anchors, id_bound)
+    return edge_run_member(edges, start, deg, vals, xp=xp, depth=depth)
+
+
+def edge_run_member(edges, start, deg, vals, xp=np, depth=None,
+                    loop: bool = False):
+    """Boolean mask: is ``vals[i]`` in the sorted edge run ``[start[i],
+    start[i] + deg[i])``? :func:`pair_member` once each row's run is known
+    (the join's level program spreads it from the level's ranges).
+    ``loop`` (device only) runs the rounds in one ``fori_loop``: the same
+    gathers, where each unrolled round adds some 0.35 MB of program text
+    at 2^24 slots (compiled for a described v5e)."""
+    ne = int(edges.shape[0])
+    if ne == 0:
+        return xp.zeros(vals.shape[0], dtype=bool)
     # int64 search cursors on the host; under an xp=jnp trace the inputs'
     # own dtype rules (int32 by default, int64 under enable_x64) — an
     # unconditional astype would fight the x64-off config every trace
     lo = start.astype(np.int64) if xp is np else start
     end = (start + deg) if xp is not np else (start + deg).astype(np.int64)
-    hi = end
     iters = ne.bit_length() + 1 if depth is None else max(int(depth), 1)
-    for _ in range(iters):
+
+    def halve(_i, run):
+        lo, hi = run
         active = lo < hi
         # lo + (hi - lo) // 2, NOT (lo + hi) // 2: the device route runs
         # int32, and lo + hi overflows past 2^30 edges, mis-converging
         # the search (the classic binary-search midpoint bug)
         mid = lo + (hi - lo) // 2
-        mv = edges[xp.clip(mid, 0, ne - 1)]
-        less = mv < vals
-        lo = xp.where(active & less, mid + 1, lo)
-        hi = xp.where(active & ~less, mid, hi)
+        less = edges[xp.clip(mid, 0, ne - 1)] < vals
+        return (xp.where(active & less, mid + 1, lo),
+                xp.where(active & ~less, mid, hi))
+
+    if loop and xp is not np:
+        import jax
+
+        lo, _hi = jax.lax.fori_loop(0, iters, halve, (lo, end))
+    else:
+        run = (lo, end)
+        for i in range(iters):
+            run = halve(i, run)
+        lo = run[0]
     inb = lo < end
     return inb & (edges[xp.clip(lo, 0, ne - 1)] == vals)
 
@@ -239,9 +272,9 @@ def to_device_i32(arr):
 #: 2^24 candidates): a generator group is cut into slices of this one size,
 #: its last slice at the class of what is left. At LSQB's scale factor 10 a
 #: level of the triangle holds 8.0 x 10^7 candidates, which as one
-#: ``pad_pow2`` tensor is 2^27 slots with their anchors, 2 GiB shipped and
-#: probed before the host may enumerate again. A smaller level is one
-#: dispatch a group at ``pad_pow2`` of its candidates, as it always was.
+#: ``pad_pow2`` tensor is 2^27 slots and a temporary of that size for each
+#: step of the probe, made before the host may take the next run. A smaller
+#: level is one call a group at ``pad_pow2`` of its candidates.
 LEVEL_SLICE = 1 << 22
 
 
@@ -257,89 +290,295 @@ def level_slices(n: int) -> list:
     return out
 
 
-# jitted level-probe variants keyed on (per-adjacency depths, has_glob,
-# per-adjacency id bounds, the list's id bound): the candidate tensor shape
-# is handled by pad_pow2 bucketing and LEVEL_SLICE, so the cache stays small
+def level_ranges(anchors, tables, anchor_of, list_len, xp=np,
+                 id_bounds=None):
+    """The generator choice of one WCOJ level, a prefix row at a time:
+    each adjacency's ``(start, degree)`` (its anchor's key looked up among
+    the adjacency's keys), and the argmin and the min over those degrees
+    and the list's constant length ``list_len`` (``None``: the level has no
+    list). ``anchors`` holds one row of ids a prefix column the level
+    anchors on, ``anchor_of[j]`` the row of adjacency j, ``tables`` one
+    ``(keys, offsets)`` an adjacency. -> ``(starts [A, n], degs [A, n],
+    choice [n] int8, mindeg [n])``. The first minimum wins a tie, as in
+    ``np.argmin``: an adjacency before a later one, any before the list.
+    On the device (``xp=jax.numpy``) ``id_bounds[j]``, the adjacency's
+    last key + 1, gives its lookup the form :func:`direct_lookup_wins`
+    picks (:func:`lookup_ranges_device`); an anchor of -1 (a padding row)
+    has degree 0."""
+    starts, degs = [], []
+    for j, (keys, offsets) in enumerate(tables):
+        vids = anchors[anchor_of[j]]
+        if xp is np:
+            start, deg = lookup_ranges(keys, offsets, vids)
+        else:
+            start, deg = lookup_ranges_device(keys, offsets, vids,
+                                              id_bounds[j])
+        starts.append(start)
+        degs.append(deg)
+    cols = degs if list_len is None else \
+        degs + [xp.full_like(degs[0], list_len)]
+    every = xp.stack(cols)
+    return (xp.stack(starts), xp.stack(degs),
+            xp.argmin(every, axis=0).astype(np.int8), xp.min(every, axis=0))
+
+
+def prefix_sum(x, xp=np, block: int = 1024):
+    """Inclusive running sum along the last axis. On the device
+    (``xp=jax.numpy``) in blocks: each block of ``block`` summed along,
+    then the blocks' totals (recursively) added in. Compiled for a
+    described v5e, XLA's own scan of 2^19-2^20 int32 took 7.6-16.6 s of
+    compiling and 0.7-1.3 MB of program text, the blocked one 0.2-0.4 s
+    and 0.6-0.9 MB; its sum wraps in int32 as the scan's does."""
+    if xp is np:
+        return np.cumsum(x, axis=-1)
+    n = int(x.shape[-1])
+    if n <= block:
+        return xp.cumsum(x, axis=-1)
+    lead = tuple(x.shape[:-1])
+    pad = -n % block
+    if pad:
+        x = xp.concatenate([x, xp.zeros(lead + (pad,), x.dtype)], axis=-1)
+    y = xp.cumsum(x.reshape(lead + (-1, block)), axis=-1)
+    last = y[..., -1]
+    y = y + (prefix_sum(last, xp, block) - last)[..., None]
+    return y.reshape(lead + (n + pad,))[..., :n]
+
+
+def spread_rows(at, values, out_cap: int, xp=np):
+    """Each of ``out_cap`` slots takes the values of the last row whose
+    first slot ``at`` (non-decreasing a row; before 0 is slot 0) is at or
+    before it: ``values`` is ``[k, rows]``, the result ``[k, out_cap]``.
+    Each row's difference from the row before is added at its first slot
+    and the slots are summed along, so a row of no slots, which shares its
+    first slot with the next row, cancels out; a slot costs a running sum
+    and no gather (in wrapping int32 the sums are exact)."""
+    at = xp.maximum(at, 0)
+    diffs = values - xp.concatenate(
+        [xp.zeros_like(values[:, :1]), values[:, :-1]], axis=1)
+    if xp is np:
+        z = np.zeros((values.shape[0], out_cap), dtype=values.dtype)
+        keep = at < out_cap
+        for row, d in zip(z, diffs):
+            np.add.at(row, at[keep], d[keep])
+    else:
+        # one scatter a value: one scatter of [k, rows] into [k, slots]
+        # took some 90 ns a row on a v5e
+        z = xp.stack([xp.zeros(out_cap, dtype=values.dtype).at[at].add(
+            d, mode="drop", indices_are_sorted=True) for d in diffs])
+    return prefix_sum(z, xp)
+
+
+def compact_padded(mask, cols, xp=np):
+    """The slots ``mask`` keeps, moved to the front of each of ``cols`` (a
+    ``[k, n]`` array) in slot order: -> ``(compacted [k, n], count)``; past
+    ``count`` a column holds the slots not kept. A kept slot goes to its
+    rank (the exclusive running sum of the mask), a slot not kept to
+    ``count`` plus the slots not kept before it: a permutation, so the
+    scatter is told its indices are unique, one column at a time. On a
+    v5e the two columns' scatters took some 110 ms at 2^23 slots, where
+    one scatter by ``max`` at the ranks, every slot not kept landing on
+    the rank of the next kept one, took 510."""
+    m = mask.astype(np.int32)
+    cum = prefix_sum(m, xp)
+    n = int(mask.shape[0])
+    count = cum[n - 1]
+    if xp is np:
+        order = np.concatenate([np.flatnonzero(mask), np.flatnonzero(~mask)])
+        return cols[:, order], count
+    rank = cum - m
+    slot = xp.arange(n, dtype=np.int32)
+    to = xp.where(mask, rank, count + slot - rank)
+    return xp.stack([xp.zeros_like(c).at[to].set(c, unique_indices=True)
+                     for c in cols]), count
+
+
+def level_probe(choice, starts, degs, window, glob, edges, gen: int,
+                depths: tuple, has_list: bool, out_cap: int, xp=np,
+                list_bound: int | None = None, rows_cap: int | None = None):
+    """One call of a WCOJ level's candidates, made, probed and compacted:
+    the prefix rows in ``[window[1], window[2])`` whose generator
+    (:func:`level_ranges`' ``choice``) is ``gen`` expand to their
+    candidates, and ``out_cap`` of them from the group's ``window[3]``-th
+    on are kept where every constraint but the generator holds. -> ``(rows,
+    values, count)``: the first ``count`` slots hold each survivor's prefix
+    row and value, in the order the host enumerates them (rows ascending,
+    each row's edge run in order; a list-generated row takes the whole
+    list ``glob``). ``rows_cap`` rows from ``window[0]`` are read, where
+    it is under the level's (a run of rows of a level in runs); the rest
+    of the level's rows cost the call nothing.
+
+    ``gen`` is an adjacency (its run ``starts[gen]``, ``degs[gen]`` out of
+    ``edges[gen]``) or ``len(edges)``, the list. A slot learns its row, its
+    place in the generator's run and every other adjacency's run from the
+    rows by :func:`spread_rows` (no key is looked up here); every other
+    adjacency ``i`` is probed by :func:`edge_run_member` over that run, in
+    ``depths[i]`` rounds on the device, and where ``has_list`` the list's
+    membership (:func:`member_sorted_device` by ``list_bound``); the
+    survivors are compacted by :func:`compact_padded`. A slot's gathers are
+    its value and the searches'. With ``xp=np`` it is the NumPy twin
+    (searches converge without ``depths``)."""
+    row0, lo, hi, base = window[0], window[1], window[2], window[3]
+    if rows_cap is not None and rows_cap < choice.shape[0]:
+        if xp is np:
+            at = slice(int(row0), int(row0) + rows_cap)
+            choice, starts, degs = choice[at], starts[:, at], degs[:, at]
+        else:
+            import jax
+
+            def cut(a):
+                return jax.lax.dynamic_slice_in_dim(a, row0, rows_cap,
+                                                    axis=a.ndim - 1)
+
+            choice, starts, degs = cut(choice), cut(starts), cut(degs)
+    else:
+        row0 = 0 * row0
+    rows = xp.arange(choice.shape[0], dtype=np.int32)
+    pick = (choice == gen) & (rows >= lo - row0) & (rows < hi - row0)
+    if gen < len(edges):
+        start, run = starts[gen], edges[gen]
+        deg = xp.where(pick, degs[gen], 0)
+    else:
+        start, run = xp.zeros_like(starts[0]), glob
+        deg = xp.where(pick, glob.shape[0], 0).astype(degs.dtype)
+    cum = prefix_sum(deg, xp)
+    first = cum - deg
+    others = [i for i in range(len(edges)) if i != gen]
+    per_row = xp.stack([rows.astype(starts.dtype), start - first]
+                       + [starts[i] for i in others]
+                       + [degs[i] for i in others])
+    got = spread_rows(first - base, per_row, out_cap, xp)
+    pos = base + xp.arange(out_cap, dtype=np.int32)
+    vals = run[xp.clip(got[1] + pos, 0, run.shape[0] - 1)]
+    mask = pos < cum[-1]
+    for k, i in enumerate(others):
+        mask = mask & edge_run_member(
+            edges[i], got[2 + k], got[2 + len(others) + k], vals, xp=xp,
+            depth=None if xp is np else depths[i], loop=True)
+    if has_list:
+        mask = mask & (member_sorted(glob, vals) if xp is np
+                       else member_sorted_device(glob, vals, list_bound))
+    out, count = compact_padded(mask, xp.stack([got[0] + row0, vals]), xp=xp)
+    return out[0], out[1], count
+
+
+# the jitted level programs keyed on what their tracing reads beside the
+# operands' shapes: the candidates come in pad_pow2 classes and slices of
+# LEVEL_SLICE, the prefix rows in pad_pow2 classes, so the caches stay small
+_LEVEL_RANGES_CACHE: dict = {}
 _LEVEL_PROBE_CACHE: dict = {}
+# which templates' requests ran each cached level program, by its key
+_LEVEL_OWNERS: dict = {}
+_LEVEL_OWNERS_LOCK = make_lock("join.level_owners")
+declare_leaf("join.level_owners")
+register_global_lock(sys.modules[__name__], "_LEVEL_OWNERS_LOCK",
+                     "join.level_owners")
 
 
-def jit_level_probe(adj_depths: tuple, has_glob: bool,
-                    id_bounds: tuple | None = None,
-                    list_bound: int | None = None):
-    """The fused XLA probe for one WCOJ generator group: a padded flat
-    candidate tensor is masked by every LISTED constraint in one compiled
-    call — global sorted-list membership plus one ragged pair probe per
-    adjacency — instead of one NumPy pass per constraint with
-    materialized intermediates (where the host path pays its
-    per-candidate cost). The caller lists only the constraints the group
-    actually needs (a generator's self-probe is true by construction and
-    is elided), and ``adj_depths[j]`` is adjacency j's binary-search
-    iteration bound (log2(max_degree)+1, cached with its device table).
+def own_level_programs(keys, owner) -> None:
+    """``owner`` (a template's signature) ran the level programs ``keys``
+    (what ``jit_level_ranges`` and ``jit_level_probe`` put in ``used``)."""
+    with _LEVEL_OWNERS_LOCK:
+        for key in keys:
+            _LEVEL_OWNERS.setdefault(key, set()).add(owner)
 
-    Every lookup of the probe takes the form :func:`direct_lookup_wins`
-    picks from the static shapes, in every level: ``id_bounds[j]`` (the
-    segment's last key + 1, cached beside its tables) lets the anchors'
-    key lookup address a table over the id range
-    (:func:`lookup_ranges_device`), ``list_bound`` (the store's vertex id
-    bound, ``JoinTableCache.vertex_bound``) lets the list's membership
-    mark the list in one (:func:`member_sorted_device`). At 2^23
-    candidates the key of LSQB's ``knows`` among 27,000 and the persons'
-    list of 27,000 were 15 rounds of a gather a candidate each, the
-    comments' list of 8.1 M 23; a table is one. Both tables are
-    temporaries of the call. Without a bound (``None``) a lookup searches;
-    a small level over a big segment searches by the rule.
 
-    Signature of the returned fn:
-        fn(valid, cand, glob, k0, o0, e0, a0, k1, o1, e1, a1, ...) -> mask
-    where ``valid``/``cand`` are the padded candidate tensor and its
-    validity mask, ``glob`` the intersected global candidate list (ignored
-    when has_glob is False — pass a 1-element dummy), and each adjacency
-    contributes (keys, offsets, edges, anchors). The compiled program is
-    named ``wk_level_probe`` (the function and a ``jax.named_scope``), so a
-    profile that carries scopes can tell it from the template programs."""
+def disown_level_programs(owner) -> int:
+    """``owner``'s template left the join's device route: let go each
+    cached level program no other template ran, whose text is resident on
+    the device while it is cached (15.7-19.9 MB a call's program and
+    2.4-3.6 a ranges program at LSQB's shapes, compiled for a described
+    v5e). A caller that holds one
+    keeps it; a later request compiles it anew (from the persistent compile
+    cache where there is one). -> how many went."""
+    gone = 0
+    with _LEVEL_OWNERS_LOCK:
+        for key, owners in list(_LEVEL_OWNERS.items()):
+            owners.discard(owner)
+            if not owners:
+                del _LEVEL_OWNERS[key]
+                cache = _LEVEL_RANGES_CACHE if key[0] == "ranges" \
+                    else _LEVEL_PROBE_CACHE
+                gone += cache.pop(key, None) is not None
+    return gone
+
+
+def jit_level_ranges(id_bounds: tuple, anchor_of: tuple, has_list: bool,
+                     used: set | None = None):
+    """:func:`level_ranges` on the chip, once a level of the join's device
+    route: ``fn(anchors, list_len, k0, o0, k1, o1, ...) -> (starts, degs,
+    choice, mindeg)``, where ``anchors`` is ``int32 [columns, rows]``
+    (padding rows -1), ``list_len`` the list's length (ignored without
+    ``has_list``) and each adjacency gives its cached ``(keys, offsets)``.
+    The ranges stay on the device for the level's calls of
+    :func:`jit_level_probe`; the host fetches ``choice`` and ``mindeg``.
+    Named ``wk_level_ranges``. ``used`` gets the program's key
+    (:func:`own_level_programs`)."""
     import jax
     import jax.numpy as jnp
 
-    depths = tuple(int(d) for d in adj_depths)
-    bounds = (None,) * len(depths) if id_bounds is None \
-        else tuple(None if b is None else int(b) for b in id_bounds)
-    list_bound = None if list_bound is None or not has_glob \
+    bounds = tuple(int(b) for b in id_bounds)
+    anchor_of = tuple(int(a) for a in anchor_of)
+    key = ("ranges", bounds, anchor_of, bool(has_list))
+    if used is not None:
+        used.add(key)
+    fn = _LEVEL_RANGES_CACHE.get(key)
+    if fn is not None:
+        return fn
+
+    def wk_level_ranges(anchors, list_len, *tables):
+        with jax.named_scope("wk_level_ranges"):
+            pairs = [tables[2 * j: 2 * j + 2] for j in range(len(bounds))]
+            return level_ranges(anchors, pairs, anchor_of,
+                                list_len if has_list else None, xp=jnp,
+                                id_bounds=bounds)
+
+    fn = jax.jit(wk_level_ranges)
+    _LEVEL_RANGES_CACHE[key] = fn
+    return fn
+
+
+def jit_level_probe(gen: int, depths: tuple, has_list: bool,
+                    list_bound: int | None, out_cap: int, rows_cap: int,
+                    used: set | None = None):
+    """:func:`level_probe` on the chip, one call a generator group (or a
+    slice of one) of a level on the join's device route: ``fn(choice,
+    starts, degs, window, glob, e0, e1, ...) -> (rows, values, count)``
+    over :func:`jit_level_ranges`' device-resident outputs, ``window`` the
+    ``int32 [4]`` of the first row read (``rows_cap`` of them), the prefix
+    rows' run and the group's first candidate,
+    ``glob`` the level's list (a 1-element dummy without one) and each
+    adjacency's cached edges. The candidates never leave the chip; the host
+    fetches the compacted survivors and keeps ``[:count]``. ``depths[i]``
+    is adjacency ``i``'s search bound (log2(max_degree)+1, cached with its
+    table); ``list_bound`` (the store's vertex id bound,
+    ``JoinTableCache.vertex_bound``) lets the list's membership mark the
+    list in a table over the id range, by :func:`direct_lookup_wins` on the
+    call's shapes (``None`` searches). Named ``wk_level_probe`` (the
+    function and a ``jax.named_scope``), which the profile's reader of the
+    level's device time finds. ``used`` gets the program's key."""
+    import jax
+    import jax.numpy as jnp
+
+    depths = tuple(int(d) for d in depths)
+    list_bound = None if list_bound is None or not has_list \
         else int(list_bound)
-    key = (depths, bool(has_glob), bounds, list_bound)
+    key = ("probe", int(gen), depths, bool(has_list), list_bound,
+           int(out_cap), int(rows_cap))
+    if used is not None:
+        used.add(key)
     fn = _LEVEL_PROBE_CACHE.get(key)
     if fn is not None:
         return fn
 
-    def wk_level_probe(valid, cand, glob, *adj):
+    def wk_level_probe(choice, starts, degs, window, glob, *edges):
         with jax.named_scope("wk_level_probe"):
-            mask = valid
-            if has_glob:
-                mask = mask & member_sorted_device(glob, cand, list_bound)
-            for j, depth in enumerate(depths):
-                keys, offsets, edges, anchors = adj[4 * j: 4 * j + 4]
-                mask = mask & pair_member(keys, offsets, edges, anchors,
-                                          cand, xp=jnp, depth=depth,
-                                          id_bound=bounds[j])
-            return mask
+            return level_probe(choice, starts, degs, window, glob, edges,
+                               gen, depths, has_list, out_cap, xp=jnp,
+                               list_bound=list_bound, rows_cap=rows_cap)
 
     fn = jax.jit(wk_level_probe)
     _LEVEL_PROBE_CACHE[key] = fn
     return fn
-
-
-def level_probe_host(valid, cand, glob, *adj):
-    """NumPy twin of the jitted level probe (same argument layout) — the
-    parity tests compare the two directly on padded tensors, including
-    all-padding buckets and empty candidate lists."""
-    mask = np.asarray(valid).copy()
-    if glob is not None:
-        mask &= member_sorted(np.asarray(glob), np.asarray(cand))
-    for j in range(len(adj) // 4):
-        keys, offsets, edges, anchors = adj[4 * j: 4 * j + 4]
-        mask &= pair_member(np.asarray(keys), np.asarray(offsets),
-                            np.asarray(edges), np.asarray(anchors),
-                            np.asarray(cand))
-    return mask
 
 
 def seed_masks(s, p, o, tp, ts, to, eq, xp=np):

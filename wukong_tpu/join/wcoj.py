@@ -20,16 +20,18 @@ Resilience parity with the walk: the per-query deadline is checked and the
 row budget charged at every level; expiry commits the prefix built so far
 as a structured partial result (``result.complete = False``).
 
-Level routes (``join_device`` knob, ROADMAP item 6i): each level's probe
-phase — the per-candidate intersection cost TrieJax moves on-accelerator —
-runs either on the NumPy host kernels or as ONE fused XLA dispatch over a
-padded/bucketed flat candidate tensor (``kernels.jit_level_probe``), with
+Level routes (``join_device`` knob, ROADMAP item 6i): a level is either
+enumerated and probed by the NumPy host kernels, or made on the chip: the
+prefix rows' ranges looked up once (``kernels.jit_level_ranges``), then one
+fused XLA dispatch a generator group that expands the candidates, probes
+them and compacts the survivors (``kernels.jit_level_probe``), with
 device-resident int32 copies of the sorted tables cached per store version
-next to their host twins. The two routes are byte-identical by
-construction (same candidate enumeration, same mask semantics); any
-device-path failure (missing jax, int32 range overflow, a bug) degrades
-the level to the host kernels and latches host for the rest of the query —
-the same degrade-don't-error posture as the wcoj->walk fallback.
+next to their host twins; only each row's generator and the survivors come
+back. The two routes are byte-identical by construction (same candidates in
+the same order, same mask semantics); any device-path failure (missing jax,
+int32 range overflow, a bug) degrades the level to the host kernels and
+latches host for the rest of the query — the same degrade-don't-error
+posture as the wcoj->walk fallback.
 """
 
 from __future__ import annotations
@@ -47,6 +49,7 @@ from wukong_tpu.join.kernels import (
     expand_ragged,
     intersect_many,
     jit_level_probe,
+    jit_level_ranges,
     level_slices,
     lookup_ranges,
     member_sorted,
@@ -104,17 +107,31 @@ _M_LEVEL_SLOTS = get_registry().counter(
     "Slots probed by WCOJ levels (padded on the device route)",
     labels=("route",))
 
-# which form each lookup of a device level's probe calls took (a call looks
-# up the keys of each adjacency it probes and the list, where it has one):
-# a table over the id range or the sorted search, by
-# ``kernels.direct_lookup_wins`` on the shapes the program was traced at
+# which form each lookup of a device level's programs took (the keys of
+# each adjacency once a level, in ``wk_level_ranges``, and the list once a
+# call of ``wk_level_probe``, where it has one): a table over the id range
+# or the sorted search, by ``kernels.direct_lookup_wins`` on the shapes the
+# program was traced at
 _M_PROBE_LOOKUPS = get_registry().counter(
     "wukong_join_probe_lookups_total",
     "Lookups of the WCOJ level probe's calls by form", labels=("form",))
 
+# where each level's candidates were made: on the chip from the prefix rows
+# (a level of the device route with a bound adjacency, ``_device_level``)
+# or by the host's NumPy enumeration
+_M_LEVEL_ENUM = get_registry().counter(
+    "wukong_join_level_enumerations_total",
+    "WCOJ levels by where their candidates were enumerated",
+    labels=("where",))
+
 #: a level of more candidates than this many slices of ``LEVEL_SLICE`` is
 #: enumerated and probed a run of prefix rows at a time
 LEVEL_CHUNK_SLICES = 4
+
+
+def _concat(parts: list, dtype) -> np.ndarray:
+    return np.concatenate(parts).astype(dtype, copy=False) if parts \
+        else np.empty(0, dtype=dtype)
 
 
 def _row_chunks(counts: np.ndarray) -> list:
@@ -515,12 +532,14 @@ class WCOJExecutor:
         self-probe is redundant but always true). Returns the new prefix
         and the level's intersection stats.
 
-        A level of more than ``LEVEL_CHUNK_SLICES`` slices of candidates
-        (2^24) is taken a run of prefix rows at a time (``_row_chunks``):
-        the host holds one run's candidates, not the level's, and on the
-        device route it enumerates the next run while the chip probes this
-        one (the probe is dispatched, the mask fetched a run later). A
-        smaller level is one run, probed as ``_probe_start`` says.
+        On the device route a level with a bound adjacency and at least
+        ``_device_floor`` prefix rows or candidates makes its candidates on
+        the chip (``_device_level``): the host ships the anchor columns and
+        reads back each row's generator and minimum degree, and the
+        survivors. Any other level, and a device level that fails, is
+        enumerated and probed on the host, a run of prefix rows at a time
+        (``_host_level``); the two are byte-identical, row order included.
+        The host keeps the widening of the prefix by the new column.
         """
         tr = getattr(q, "trace", None)
         adj = []  # (anchor col, pid, dir, segment) — other endpoint bound
@@ -536,156 +555,151 @@ class WCOJExecutor:
                 glob.append(self.tables.index_list(
                     e.pid, IN if e.s == v else OUT))
         G = intersect_many(glob)
-        n = len(prefix)
         if not adj and G is None:
             raise WukongError(ErrorCode.UNSUPPORTED_SHAPE,
                               f"wcoj: variable {v} has no constraint to "
                               "generate candidates from")
 
-        with span(tr, "wcoj.enumerate"):
-            # per-row generator: argmin over each adjacency's degree and
-            # the global list's (constant) length
-            ranges = [lookup_ranges(seg.keys, seg.offsets, prefix[:, c])
-                      for c, _pid, _d, seg in adj]
-            deg_stack = [d for (_s, d) in ranges]
-            if G is not None:
-                deg_stack.append(np.full(n, len(G), dtype=np.int64))
-            degs = np.stack(deg_stack) if n else \
-                np.empty((len(deg_stack), 0), dtype=np.int64)
-            choice = np.argmin(degs, axis=0) if n else \
-                np.empty(0, dtype=np.int64)
-            chunks = _row_chunks(degs.min(axis=0)) if n else [(0, 0)]
-            del degs, deg_stack
-
-        device = route == "device"
-        probes = len(adj) + (1 if G is not None else 0)
-        state = {"candidates": 0, "slots": 0, "route": "host",
-                 "direct": 0, "searched": 0}
-        kept_rows, kept_vals = [], []
-
-        def host_mask(row_idx, newcol):
-            mask = np.ones(len(newcol), dtype=bool)
-            if G is not None:
-                mask &= member_sorted(G, newcol)
-            for c, _pid, _d, seg in adj:
-                mask &= pair_member(seg.keys, seg.offsets, seg.edges,
-                                    prefix[row_idx, c], newcol)
-            return mask
-
-        def device_failed(e):
-            # degrade THIS query's remaining levels to host (the
-            # wcoj->walk posture, one layer down); the host probe
-            # serves this chunk
-            reason = (type(e).__name__ if not isinstance(
-                e, DeviceRangeError) else "int32_range")
-            _M_DEVICE_FALLBACK.labels(reason=reason).inc()
-            if q is not None:
-                q._join_device_broken = True
-
-        def finish(pending):
-            """The mask of a chunk whose probe was dispatched (or not),
-            and its surviving candidates kept, in chunk order."""
-            row_idx, newcol, job = pending
-            mask = None
-            if job is not None:
+        device = route == "device" and not (
+            q is not None and getattr(q, "_join_device_broken", False))
+        floor = self._device_floor()
+        choice = None
+        made = None
+        if device and adj:
+            if len(prefix) < floor:
+                # a short prefix is looked up on the host: its candidates
+                # decide, as they always did, whether the level is worth
+                # the device
+                with span(tr, "wcoj.enumerate"):
+                    choice = self._host_choice(adj, G, prefix)
+                mins = choice[2]
+                device = int(mins.sum()) >= floor or \
+                    len(_row_chunks(mins)) > 1
+            if device:
                 try:
-                    mask = self._probe_finish(job, len(newcol), q, k)
-                    state["slots"] += job["slots"]
-                    state["direct"] += job["direct"]
-                    state["searched"] += job["searched"]
-                    state["route"] = "device"
+                    made = self._device_level(adj, G, prefix, q, k, tr)
                 except Exception as e:
-                    device_failed(e)
-            if mask is None:
-                with span(tr, "wcoj.probe.host"):
-                    mask = host_mask(row_idx, newcol)
-                state["slots"] += len(newcol)
-            with span(tr, "wcoj.compact"):
-                kept_rows.append(row_idx[mask])
-                kept_vals.append(newcol[mask])
+                    self._device_failed(e, q)
+        if made is None:
+            made = self._host_level(adj, G, prefix, k, choice, tr,
+                                    device and not adj, floor)
+        kept_rows, kept_vals, state = made
 
-        pending = None
-        for lo, hi in chunks:
-            with span(tr, "wcoj.enumerate"):
-                row_idx, newcol, gid = self._enumerate(
-                    adj, G, ranges, choice, lo, hi, k, device)
-            state["candidates"] += len(newcol)
-            job = None
-            if len(newcol) and device \
-                    and (len(chunks) > 1
-                         or len(newcol) >= self._device_floor()) \
-                    and not (q is not None
-                             and getattr(q, "_join_device_broken", False)):
-                try:
-                    job = self._probe_start(G, adj, prefix, row_idx,
-                                            newcol, gid, len(chunks) == 1,
-                                            tr)
-                except Exception as e:
-                    device_failed(e)
-            if pending is not None:
-                finish(pending)
-            pending = (row_idx, newcol, job) if len(newcol) else None
-        if pending is not None:
-            finish(pending)
-
-        lvl_route = state["route"]
+        lvl_route, where = state["route"], state["enumerated"]
         _M_DEVICE_LEVELS.labels(route=lvl_route).inc()
         _M_LEVEL_CAND.labels(route=lvl_route).inc(state["candidates"])
         _M_LEVEL_SLOTS.labels(route=lvl_route).inc(state["slots"])
+        _M_LEVEL_ENUM.labels(where=where).inc()
         if lvl_route == "device":
             _M_PROBE_LOOKUPS.labels(form="direct").inc(state["direct"])
             _M_PROBE_LOOKUPS.labels(form="search").inc(state["searched"])
         with span(tr, "wcoj.compact"):
-            row_idx = np.concatenate(kept_rows) if kept_rows else \
-                np.empty(0, dtype=np.int64)
-            newcol = np.concatenate(kept_vals) if kept_vals else \
-                np.empty(0, dtype=np.int64)
+            row_idx = _concat(kept_rows, np.int64)
             new_prefix = np.column_stack(
-                [prefix[row_idx], newcol]).astype(np.int64, copy=False)
+                [prefix[row_idx], _concat(kept_vals, np.int64)]).astype(
+                    np.int64, copy=False)
         if tr is not None:
             tr.event("join.level", var=int(v),
                      candidates=state["candidates"], slots=state["slots"],
                      rows_out=len(new_prefix), route=lvl_route,
-                     direct=state["direct"], searched=state["searched"])
+                     direct=state["direct"], searched=state["searched"],
+                     enumerated=where)
         return new_prefix, {"candidates": state["candidates"],
-                            "slots": state["slots"], "probes": probes,
+                            "slots": state["slots"],
+                            "probes": len(adj) + (G is not None),
                             "route": lvl_route, "direct": state["direct"],
-                            "searched": state["searched"]}
+                            "searched": state["searched"],
+                            "enumerated": where}
 
-    def _enumerate(self, adj, G, ranges, choice, lo: int, hi: int, k: int,
-                   want_gid: bool):
-        """The candidates of prefix rows ``[lo, hi)``: (row index into the
-        whole prefix, candidate value, generator id or None), generator
-        group by generator group."""
+    @staticmethod
+    def _level_state(route: str) -> dict:
+        return {"candidates": 0, "slots": 0, "route": route,
+                "enumerated": route, "direct": 0, "searched": 0}
+
+    @staticmethod
+    def _device_failed(e: Exception, q) -> None:
+        """Degrade THIS query's remaining levels to the host (the
+        wcoj->walk posture, one layer down); the host serves the level."""
+        reason = (type(e).__name__ if not isinstance(
+            e, DeviceRangeError) else "int32_range")
+        _M_DEVICE_FALLBACK.labels(reason=reason).inc()
+        if q is not None:
+            q._join_device_broken = True
+
+    @staticmethod
+    def _host_choice(adj, G, prefix: np.ndarray):
+        """Each prefix row's ranges in every adjacency, its generator (the
+        argmin over their degrees and the list's constant length) and its
+        minimum degree, by NumPy searches: ``(ranges, choice, mins)``."""
+        n = len(prefix)
+        ranges = [lookup_ranges(seg.keys, seg.offsets, prefix[:, c])
+                  for c, _pid, _d, seg in adj]
+        degs = [d for (_s, d) in ranges]
+        if G is not None:
+            degs.append(np.full(n, len(G), dtype=np.int64))
+        degs = np.stack(degs)
+        return ranges, np.argmin(degs, axis=0), degs.min(axis=0)
+
+    def _host_level(self, adj, G, prefix: np.ndarray, k: int, choice, tr,
+                    passes: bool, floor: int):
+        """The level enumerated and probed in NumPy, a run of prefix rows
+        at a time (``_row_chunks``), so the host holds one run's
+        candidates. ``passes``: a level of the device route whose one
+        constraint is its generator (no bound adjacency): its candidates
+        all pass, and a run at the device floor is the route's, though
+        nothing is shipped. -> ``(kept_rows, kept_vals, state)``: the
+        survivors' prefix rows and values, a list a run."""
+        with span(tr, "wcoj.enumerate"):
+            if choice is None:
+                choice = self._host_choice(adj, G, prefix)
+            ranges, ch, mins = choice
+            chunks = _row_chunks(mins)
+        state = self._level_state("host")
+        kept_rows, kept_vals = [], []
+        for lo, hi in chunks:
+            with span(tr, "wcoj.enumerate"):
+                row_idx, newcol = self._enumerate(adj, G, ranges, ch, lo,
+                                                  hi, k)
+            state["candidates"] += len(newcol)
+            if not len(newcol):
+                continue
+            mask = None
+            if passes and (len(chunks) > 1 or len(newcol) >= floor):
+                with span(tr, "wcoj.probe.stage"):
+                    _M_DEVICE_CAND.observe(len(newcol))
+                state["route"] = "device"
+            else:
+                with span(tr, "wcoj.probe.host"):
+                    mask = np.ones(len(newcol), dtype=bool)
+                    if G is not None:
+                        mask &= member_sorted(G, newcol)
+                    for c, _pid, _d, seg in adj:
+                        mask &= pair_member(seg.keys, seg.offsets, seg.edges,
+                                            prefix[row_idx, c], newcol)
+            state["slots"] += len(newcol)
+            with span(tr, "wcoj.compact"):
+                kept_rows.append(row_idx if mask is None else row_idx[mask])
+                kept_vals.append(newcol if mask is None else newcol[mask])
+        return kept_rows, kept_vals, state
+
+    def _enumerate(self, adj, G, ranges, choice, lo: int, hi: int, k: int):
+        """The candidates of prefix rows ``[lo, hi)`` on the host: (row
+        index into the whole prefix, candidate value), generator group by
+        generator group."""
         ch = choice[lo:hi]
-        parts = []  # (generator id, row_idx, newcol) per generator group
+        parts = []  # (row_idx, newcol) per generator group, in order
         for j, (start, deg) in enumerate(ranges):
             rows = np.nonzero(ch == j)[0] + lo
             if len(rows) == 0:
                 continue
             row_idx, pos = expand_ragged(start[rows], deg[rows])
-            parts.append((j, rows[row_idx], adj[j][3].edges[pos]))
+            parts.append((rows[row_idx], adj[j][3].edges[pos]))
         if G is not None:
             rows = np.nonzero(ch == len(ranges))[0] + lo
             if len(rows):
-                parts.append((len(adj), np.repeat(rows, len(G)),
-                              np.tile(G, len(rows))))
-        if parts:
-            row_idx = np.concatenate([p[1] for p in parts])
-            newcol = np.concatenate([p[2] for p in parts]).astype(
-                np.int64, copy=False)
-            # which generator produced each candidate (non-decreasing by
-            # construction — groups are appended in generator order), so
-            # the device path can elide each group's always-true
-            # self-probe and slice groups as contiguous ranges. Only the
-            # device route consumes it — the host route skips the alloc
-            gid = (np.concatenate([np.full(len(p[1]), p[0],
-                                           dtype=np.int16) for p in parts])
-                   if want_gid else None)
-        else:
-            row_idx = np.empty(0, dtype=np.int64)
-            newcol = np.empty(0, dtype=np.int64)
-            gid = np.empty(0, dtype=np.int16) if want_gid else None
+                parts.append((np.repeat(rows, len(G)), np.tile(G, len(rows))))
+        row_idx = _concat([p[0] for p in parts], np.int64)
+        newcol = _concat([p[1] for p in parts], np.int64)
 
         if self.part is not None and k == 0 and len(newcol):
             # distributed generic join: this slice keeps only its hash
@@ -696,129 +710,189 @@ class WCOJExecutor:
 
             pm = hash_mod(newcol.astype(np.int32), S) == kk
             row_idx, newcol = row_idx[pm], newcol[pm]
-            if gid is not None:
-                gid = gid[pm]
-        return row_idx, newcol, gid
+        return row_idx, newcol
 
     # ------------------------------------------------------------------
-    def _probe_start(self, G, adj, prefix: np.ndarray, row_idx: np.ndarray,
-                     newcol: np.ndarray, gid: np.ndarray, whole: bool,
-                     tr=None) -> dict:
-        """Dispatch the probe phase of a level, or of one run of its rows:
-        one fused XLA call per generator group, masking each padded flat
-        candidate tensor by every constraint EXCEPT its own generator
-        (whose self-probe is true by construction — candidates were drawn
-        from that list); the adjacencies ship as cached device-resident
-        tables with their binary-search depth bounds, the global list
-        ships per call of this (it is an intersection result, not a
-        cacheable table). -> the job ``_probe_finish`` takes.
+    def _device_level(self, adj, G, prefix: np.ndarray, q, k: int, tr):
+        """The level's candidates made on the chip, from the prefix rows.
 
-        ``whole`` (the level is one run: up to ``LEVEL_CHUNK_SLICES``
-        slices of candidates, 2^24, the largest level any cell ran before
-        LSQB's) probes as levels always were: a group is ONE call at the
-        ``pad_pow2`` class of its candidates, and its mask is fetched
-        before the next group's tensors are built. A level in runs cuts a
-        group into slices of ``LEVEL_SLICE`` (``kernels.level_slices``) and
-        fetches nothing here, so the host may enumerate the next run
-        meanwhile. In either, every lookup of a call (an anchor's key, the
-        list) takes the form ``kernels.direct_lookup_wins`` picks from the
-        call's shapes: the cached ``id_bound`` of each adjacency and the
-        list's bound (``_list_bound``) go to ``jit_level_probe`` whatever
-        the level's size, and the job counts the forms (``direct``,
-        ``searched``) by the same rule on the same shapes.
+        ``_device_ranges`` ships the anchor columns and runs
+        ``wk_level_ranges``: each adjacency's (start, degree) a row stays
+        on the device, the host reads each row's generator and minimum
+        degree. From them the host cuts runs of rows (``_row_chunks``) and
+        counts each generator group's candidates; ``_probe_start`` makes
+        one call of ``wk_level_probe`` a group (a slice of ``LEVEL_SLICE``
+        in a level in runs), which expands the group's rows, probes every
+        other constraint and compacts the survivors; ``_probe_finish``
+        fetches them, call by call, while the chip runs the calls after. A
+        level in runs dispatches run ``i + 1`` before it fetches run ``i``.
+        The level's device arrays go when it ends. Raises on any device
+        failure, before the prefix is touched. -> what ``_host_level``
+        returns."""
+        used = None if q is None else getattr(q, "_join_programs", None)
+        if q is not None and used is None:
+            used = q._join_programs = set()
+        lvl = self._device_ranges(adj, G, prefix, tr, used)
+        state = self._level_state("device")
+        state["direct"], state["searched"] = lvl["direct"], lvl["searched"]
+        kept_rows, kept_vals = [], []
+
+        def finish(job):
+            got = self._probe_finish(job, q, k)
+            state["direct"] += job["direct"]
+            state["searched"] += job["searched"]
+            with span(tr, "wcoj.compact"):
+                for rows, vals, count in got:
+                    kept_rows.append(rows[:count])
+                    kept_vals.append(vals[:count])
+
+        choice, mins = lvl["choice_host"], lvl["mins"]
+        with span(tr, "wcoj.enumerate"):
+            chunks = _row_chunks(mins)
+        whole = len(chunks) == 1
+        pending = None
+        for lo, hi in chunks:
+            with span(tr, "wcoj.enumerate"):
+                per = np.bincount(choice[lo:hi], weights=mins[lo:hi],
+                                  minlength=lvl["generators"])
+                groups = [(j, int(c)) for j, c in enumerate(per) if c]
+            candidates = sum(c for _j, c in groups)
+            state["candidates"] += candidates
+            job = None
+            if candidates:
+                _M_DEVICE_CAND.observe(candidates)
+                job = self._probe_start(lvl, groups, lo, hi, whole, tr)
+                state["slots"] += job["slots"]
+            if pending is not None:
+                finish(pending)
+            pending = job
+        if pending is not None:
+            finish(pending)
+        for a in lvl["release"]:
+            a.delete()
+        return kept_rows, kept_vals, state
+
+    def _device_ranges(self, adj, G, prefix: np.ndarray, tr,
+                       used: set | None = None) -> dict:
+        """Ship the prefix columns the level's adjacencies anchor on (int32,
+        padded to the ``pad_pow2`` class of the rows with -1) and the list,
+        run ``wk_level_ranges`` against the cached device tables, and fetch
+        each row's generator (int8) and minimum degree. The ranges stay on
+        the device for the level's calls; what ``_probe_start`` needs is in
+        the returned dict. Counts the form of each adjacency's key lookup
+        by ``direct_lookup_wins`` on the program's shapes. ``used`` gets
+        the keys of the level's programs (``kernels.own_level_programs``).
         """
+        import jax
         import jax.numpy as jnp
 
+        n = len(prefix)
+        rows = pad_pow2(n)
         with span(tr, "wcoj.probe.stage"):
-            _M_DEVICE_CAND.observe(len(newcol))
             dev = [self.tables.device_tables(pid, d)
                    for (_c, pid, d, _s) in adj]
-            # a level in runs ships the list when its first group needs it
-            glob_dev = list_bound = None
-            if whole and G is not None:
-                glob_dev, list_bound = to_device_i32(G), self._list_bound(G)
-            dummy = jnp.zeros(1, dtype=jnp.int32)
-            # gid is non-decreasing by construction: one diff pass finds
-            # the group boundaries (no sort over millions of candidates)
-            bounds = np.flatnonzero(np.diff(gid)) + 1
-            starts = np.concatenate([[0], bounds]).tolist()
-            ends = np.concatenate([bounds, [len(gid)]]).tolist()
-        calls = []  # one a call: its place, its slots, what it gave
-        direct = lookups = 0  # the calls' lookups, and those by a table
-        passes = []  # (lo, hi): only the self-constraint, all pass
-        for glo, ghi in zip(starts, ends):
-            g = int(gid[glo])
-            use_glob = G is not None and g != len(adj)
-            adj_ids = [j for j in range(len(adj)) if j != g]
-            if not adj_ids and not use_glob:
-                passes.append((glo, ghi))
-                continue
-            if use_glob and glob_dev is None:
-                glob_dev, list_bound = to_device_i32(G), self._list_bound(G)
-            depths = tuple(dev[j][3] for j in adj_ids)
-            fn = jit_level_probe(depths, use_glob,
-                                 tuple(dev[j][4] for j in adj_ids),
-                                 list_bound)
-            n = ghi - glo
+            at = sorted({c for c, *_x in adj})
+            cols = prefix[:, at]
+            if cols.size:
+                # the prefix may hold ids a host-route level bound from
+                # never-range-checked host tables: an unchecked int32 fill
+                # would wrap ids past 2^31 and alias real keys (the
+                # degrade-don't-truncate contract, like the tables)
+                lo, hi = int(cols.min()), int(cols.max())
+                if lo < -(1 << 31) or hi >= (1 << 31):
+                    raise DeviceRangeError(
+                        f"anchor values [{lo}, {hi}] exceed int32 — host "
+                        "route required")
+            anchors = np.full((len(at), rows), -1, dtype=np.int32)
+            anchors[:, :n] = cols.T
+            anchors = jnp.asarray(anchors)
+            glob = jnp.zeros(1, dtype=jnp.int32) if G is None \
+                else to_device_i32(G)
+        fn = jit_level_ranges(tuple(t[4] for t in dev),
+                              tuple(at.index(c) for c, *_x in adj),
+                              G is not None, used)
+        t0 = get_usec()
+        with span(tr, "wcoj.probe.dispatch"):
+            if tr is not None:
+                tr.event("device.dispatch", kernel="wk_level_ranges")
+            starts, degs, choice, mins = fn(
+                anchors, 0 if G is None else len(G),
+                *[a for t in dev for a in t[:2]])
+        with span(tr, "wcoj.probe.sync"):
+            choice_host, mins_host = jax.device_get((choice, mins))
+        mins.delete()
+        maybe_device_dispatch("wcoj.ranges", template=f"r{len(adj)}",
+                              live=n, capacity=rows,
+                              nbytes=anchors.nbytes + 5 * rows,
+                              wall_us=get_usec() - t0)
+        direct = sum(direct_lookup_wins(rows, int(t[0].shape[0]), t[4])
+                     for t in dev)
+        return {"dev": dev, "G": G, "glob": glob, "choice": choice,
+                "starts": starts, "degs": degs,
+                "list_bound": None if G is None else self._list_bound(G),
+                "choice_host": choice_host[:n],
+                "mins": mins_host[:n].astype(np.int64),
+                "generators": len(adj) + (G is not None),
+                "direct": int(direct), "searched": len(adj) - int(direct),
+                "release": [anchors, glob, starts, degs, choice],
+                "used": used}
+
+    def _probe_start(self, lvl: dict, groups: list, lo: int, hi: int,
+                     whole: bool, tr=None) -> dict:
+        """Dispatch one run of prefix rows ``[lo, hi)``: a call of
+        ``wk_level_probe`` a generator group ``(j, candidates)``, which
+        expands the group's rows on the chip and keeps the candidates every
+        constraint but the generator passes (its self-probe is true by
+        construction): each other adjacency's edge run, spread by row from
+        the level's ranges, and the list. -> the job ``_probe_finish``
+        takes.
+
+        ``whole`` (the level is one run: up to ``LEVEL_CHUNK_SLICES``
+        slices of candidates, 2^24) makes a group ONE call at the
+        ``pad_pow2`` class of its candidates; a level in runs cuts a group
+        into slices of ``LEVEL_SLICE`` (``kernels.level_slices``). Nothing
+        is fetched here. A group whose generator is its only constraint is
+        made all the same and counted as its candidates' slots, as nothing
+        probes it; the list's membership takes the form
+        ``kernels.direct_lookup_wins`` picks from the call's shapes, and
+        the job counts it by the same rule."""
+        dev, G = lvl["dev"], lvl["G"]
+        depths = tuple(t[3] for t in dev)
+        edges = [t[2] for t in dev]
+        # a run reads its own rows: from row0, rows_cap of the level's
+        rows_cap = min(pad_pow2(hi - lo), int(lvl["choice"].shape[0]))
+        row0 = min(lo, int(lvl["choice"].shape[0]) - rows_cap)
+        calls = []  # one a call: what it gave, its live and padded slots
+        direct = lookups = 0
+        for j, n in groups:
+            has_list = G is not None and j != len(dev)
+            probed = len(dev) - (j < len(dev)) + has_list > 0
             for slo, shi, Cp in ([(0, n, pad_pow2(n))] if whole
                                  else level_slices(n)):
-                lo, hi = glo + slo, glo + shi
-                C = hi - lo
-                with span(tr, "wcoj.probe.stage"):
-                    valid = np.zeros(Cp, dtype=bool)
-                    valid[:C] = True
-                    cand = np.zeros(Cp, dtype=np.int32)
-                    cand[:C] = newcol[lo:hi]  # ids < 2^31 (range-checked)
-                    args = [jnp.asarray(valid), jnp.asarray(cand),
-                            glob_dev if use_glob else dummy]
-                    for j in adj_ids:
-                        keys, offsets, edges, _depth, _id_bound = dev[j]
-                        avals = prefix[row_idx[lo:hi], adj[j][0]]
-                        if len(avals):
-                            # anchors come from the PREFIX, which
-                            # host-route levels may have bound from
-                            # never-range-checked host tables — an
-                            # unchecked int32 fill would silently wrap
-                            # ids past 2^31 and alias real keys (the
-                            # degrade-don't-truncate contract, like the
-                            # tables)
-                            alo, ahi = int(avals.min()), int(avals.max())
-                            if alo < -(1 << 31) or ahi >= (1 << 31):
-                                raise DeviceRangeError(
-                                    f"anchor values [{alo}, {ahi}] exceed "
-                                    "int32 — host route required")
-                        anchors = np.zeros(Cp, dtype=np.int32)
-                        anchors[:C] = avals
-                        args.extend([keys, offsets, edges,
-                                     jnp.asarray(anchors)])
+                fn = jit_level_probe(j, depths, has_list, lvl["list_bound"],
+                                     Cp, rows_cap, lvl["used"])
+                window = np.array([row0, lo, hi, slo], dtype=np.int32)
                 t0 = get_usec()
                 with span(tr, "wcoj.probe.dispatch"):
                     if tr is not None:
                         tr.event("device.dispatch", kernel="wk_level_probe")
-                    mask = fn(*args)
-                if whole:
-                    with span(tr, "wcoj.probe.sync"):
-                        mask = np.asarray(mask)  # blocking D2H sync
-                del args
-                # the forms the program took, by the rule it was traced by
-                lookups += len(adj_ids) + use_glob
-                direct += sum(direct_lookup_wins(Cp, int(dev[j][0].shape[0]),
-                                                 dev[j][4]) for j in adj_ids)
-                if use_glob and list_bound is not None:
-                    direct += direct_lookup_wins(Cp, len(G), list_bound)
-                # candidate/anchor uploads + the mask back (device tables
-                # are cached residents and don't re-ship)
+                    out = fn(lvl["choice"], lvl["starts"], lvl["degs"],
+                             window, lvl["glob"], *edges)
+                if has_list:
+                    lookups += 1
+                    direct += lvl["list_bound"] is not None \
+                        and direct_lookup_wins(Cp, len(G), lvl["list_bound"])
                 calls.append({
-                    "lo": lo, "hi": hi, "slots": Cp, "mask": mask, "t0": t0,
-                    "wall_us": get_usec() - t0,
-                    "template": "p" + "".join(map(str, depths))
-                    + ("g" if use_glob else ""),
-                    "nbytes": Cp * (1 + 4 + 4 * len(adj_ids)) + C
-                    + (int(G.nbytes) if use_glob else 0)})
-        return {"calls": calls, "passes": passes, "whole": whole, "tr": tr,
+                    "out": out, "live": shi - slo,
+                    "slots": Cp if probed else shi - slo, "capacity": Cp,
+                    "t0": t0,
+                    "template": f"g{j}p" + "".join(map(str, depths))
+                    + ("l" if has_list else ""),
+                    # the window up, the survivors and their rows back
+                    "nbytes": 12 + 8 * Cp + 4})
+        return {"calls": calls, "tr": tr,
                 "direct": int(direct), "searched": int(lookups - direct),
-                "slots": sum(c["slots"] for c in calls)
-                + sum(hi - lo for lo, hi in passes)}
+                "slots": sum(c["slots"] for c in calls)}
 
     def _list_bound(self, G: np.ndarray) -> int | None:
         """The id bound under which the probe may mark the sorted candidate
@@ -832,30 +906,28 @@ class WCOJExecutor:
             return None
         return bound
 
-    def _probe_finish(self, job: dict, n: int, q=None,
-                      level: int = 0) -> np.ndarray:
-        """The host boolean mask over the ``n`` unpadded candidates of what
-        ``_probe_start`` dispatched, its masks fetched where they were not
-        yet — identical semantics to the host probes."""
-        mask = np.zeros(n, dtype=bool)
-        for lo, hi in job["passes"]:
-            mask[lo:hi] = True
-        with span(None if job["whole"] else job["tr"], "wcoj.probe.sync"):
+    def _probe_finish(self, job: dict, q=None, level: int = 0) -> list:
+        """The survivors of what ``_probe_start`` dispatched, ``[(rows,
+        values, count)]`` a call in order, fetched where they were not yet;
+        each call charged to the device observatory."""
+        import jax
+
+        got = []
+        with span(job["tr"], "wcoj.probe.sync"):
             for c in job["calls"]:
-                C = c["hi"] - c["lo"]
-                mask[c["lo"]:c["hi"]] = np.asarray(c["mask"])[:C]
+                rows, vals, count = jax.device_get(c["out"])
+                got.append((rows, vals, int(count)))
                 rec = maybe_device_dispatch(
-                    "wcoj.probe", template=c["template"], live=C,
-                    capacity=c["slots"], nbytes=c["nbytes"],
-                    wall_us=c["wall_us"] if job["whole"]
-                    else get_usec() - c["t0"])
+                    "wcoj.probe", template=c["template"], live=c["live"],
+                    capacity=c["capacity"], nbytes=c["nbytes"],
+                    wall_us=get_usec() - c["t0"])
                 if rec is not None and q is not None:
                     rec["step"] = int(level)
                     dsteps = getattr(q, "device_steps", None)
                     if dsteps is None:
                         dsteps = q.device_steps = []
                     dsteps.append(rec)
-        return mask
+        return got
 
     # ------------------------------------------------------------------
     def _commit(self, q, prefix: np.ndarray, cols: dict, levels: list,

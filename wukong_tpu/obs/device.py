@@ -102,7 +102,8 @@ DEVICE_DISPATCH_ALLOWLIST = {
         "streaming chain kernel definition; dispatched and charged at "
         "the batch-chain sync seam in engine/tpu.py"),
     "join/kernels.py": (
-        "jit minters (jit_kernels/jit_level_probe/jit_seed_masks); "
+        "jit minters (jit_kernels/jit_level_ranges/jit_level_probe/"
+        "jit_seed_masks); "
         "invocation sites join/wcoj.py and stream/continuous.py charge "
         "the seam at their blocking device_get"),
 }

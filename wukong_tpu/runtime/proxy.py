@@ -20,6 +20,7 @@ import numpy as np
 
 from wukong_tpu.analysis.lockdep import make_lock
 from wukong_tpu.config import Global
+from wukong_tpu.join.kernels import disown_level_programs, own_level_programs
 from wukong_tpu.obs import (
     activate,
     get_recorder,
@@ -772,6 +773,7 @@ class Proxy:
             self._plan_cache.put_aux("route", sig, self._route_memo_key(),
                                      "host")
             self._m_route_demoted.inc()
+            disown_level_programs(sig)
             note_feedback("join_route", "latched_host")
             log_info("wcoj device route: template demoted to host "
                      "(device path failed and latched host)")
@@ -781,6 +783,7 @@ class Proxy:
             self._plan_cache.put_aux("route", sig, self._route_memo_key(),
                                      "host")
             self._m_route_demoted.inc()
+            disown_level_programs(sig)
             note_feedback("join_route", "demote_host")
             log_info(f"wcoj device route: template demoted to host "
                      f"(measured candidates {measured:,} < "
@@ -913,6 +916,17 @@ class Proxy:
                      f"(measured live rows {live:,} < template_min_rows "
                      f"{Global.template_min_rows:,})")
 
+    @staticmethod
+    def _own_join_programs(q: SPARQLQuery) -> None:
+        """The join's level programs ``q`` ran belong to its template
+        until a demotion takes the template off the join's device route
+        (``disown_level_programs``): their text is resident while they are
+        cached, and a LUBM heavy's first request runs the route once."""
+        used = getattr(q, "_join_programs", None)
+        sig = template_signature(q) if used else None
+        if sig is not None:
+            own_level_programs(used, sig)
+
     def _record_wcoj_feedback(self, q: SPARQLQuery) -> None:
         """WCOJ auto-routing feedback (PR 9 headroom): after a successful
         wcoj execution, record the MEASURED materialized-prefix blowup
@@ -956,6 +970,7 @@ class Proxy:
         if measured > max(float(Global.wcoj_ratio), 1.0):
             self._plan_cache.put_aux("strategy", sig, key, "walk")
             self._m_join_demoted.inc()
+            disown_level_programs(sig)
             note_feedback("strategy", "demote_walk")
             tr = getattr(q, "trace", None)
             if tr is not None:
@@ -1140,6 +1155,7 @@ class Proxy:
                     else:
                         self.wcoj().try_execute(q)
                     self._note_route(q, "wcoj")
+                    self._own_join_programs(q)
                     self._record_wcoj_feedback(q)
                     self._record_route_feedback(q)
                     return q
