@@ -212,3 +212,44 @@ def test_console_started_twice_on_one_directory_builds_once(tmp_path,
     rows = [[ln.split("]")[-1] for ln in (o.out + o.err).splitlines()
              if "Department" in ln] for o in outs]
     assert len(rows[0]) == 3 and rows[0] == rows[1]
+
+
+def test_shards_boot_once_then_load(tmp_path, built):
+    """``boot_shards`` builds every shard from one assignment of the triples
+    and saves one file a shard and the statistics; the second start loads
+    them and reads no triple. Each shard is ``build_partition``'s, and the
+    planner plans as the whole store's does."""
+    d = str(tmp_path)
+    first = boot.boot_shards(boot.lubm_source(N, SEED, d), d, 3)
+    second = boot.boot_shards(boot.lubm_source(N, SEED, d), d, 3)
+    assert not first.from_bundle and second.from_bundle
+    assert set(first.phases) == {"boot.build", "boot.save"}
+    assert set(second.phases) == {"boot.bundle_load", "boot.stats_load"}
+    assert first.bundle_paths == second.bundle_paths
+    assert len(first.bundle_paths) == 3
+    for k, path in enumerate(first.bundle_paths):
+        assert "partitions=3" in os.path.basename(path)
+        assert persist.bundle_key(path) == {**boot.bundle_key(
+            {"generator": "lubm", "n_univ": N, "seed": SEED}),
+            "partitions": 3, "shard": k}
+    triples, _ = generate_lubm(N, SEED)
+    attrs = generate_lubm_attrs(N, SEED)
+    for k in range(3):
+        want = persist.gstore_digest(build_partition(triples, k, 3, attrs))
+        assert persist.gstore_digest(first.stores[k]) == want
+        assert persist.gstore_digest(second.stores[k]) == want
+    from wukong_tpu.sparql.parser import Parser
+
+    _g, planner = built
+    for k in range(1, 8):
+        with open(os.path.join(BASIC, f"lubm_q{k}")) as f:
+            text = f.read()
+        a, b = (Parser(second.str_server).parse(text) for _ in range(2))
+        assert planner.generate_plan(a) == second.planner.generate_plan(b)
+        assert [(p.subject, p.predicate, int(p.direction), p.object)
+                for p in a.pattern_group.patterns] == \
+            [(p.subject, p.predicate, int(p.direction), p.object)
+             for p in b.pattern_group.patterns], f"q{k} planned otherwise"
+    # the one-partition bundle of the same data is another file
+    one = boot.boot_store(boot.lubm_source(N, SEED, d), d)
+    assert not one.from_bundle and one.bundle_path not in first.bundle_paths

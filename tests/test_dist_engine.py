@@ -502,15 +502,19 @@ def test_dist_versatile_kuu(world):
     }}""")
 
 
-def test_dist_versatile_probe_bound(eight_cpu_devices):
+def test_dist_versatile_probe_bound(eight_cpu_devices, monkeypatch):
     """The compiled versatile step must bake the COMBINED segment's probe
-    bound, not a missing segment(pid=0)'s default of 1 — on this world the
-    versatile hash table needs 3 probe rounds, so a baked max_probe=1
-    silently drops every key outside its home bucket (a real bug once)."""
+    bound, not a missing segment(pid=0)'s default of 1 — on this world,
+    with each shard's keys hashed whole (no key shift: a shard's keys then
+    share their low bits and crowd a few home buckets), the versatile hash
+    table needs 3 probe rounds, so a baked max_probe=1 silently drops every
+    key outside its home bucket (a real bug once)."""
     from wukong_tpu.loader.generic_rdf import generate_generic
+    from wukong_tpu.parallel import sharded_store
     from wukong_tpu.sparql.ir import Pattern, SPARQLQuery
     from wukong_tpu.types import OUT, TYPE_ID
 
+    monkeypatch.setattr(sharded_store, "_key_shift", lambda _keys: 0)
     triples, meta = generate_generic(20_000, n_preds=8, n_types=4, seed=5)
     stores = build_all_partitions(triples, 8)
     dist = DistEngine(stores, None, make_mesh(8))
@@ -831,3 +835,328 @@ def test_dist_cap_memo_roundtrip(world, tmp_path):
     fresh._learned_caps[key] = {("cap", 0): 1024}
     fresh.load_cap_memo(path)
     assert fresh._learned_caps[key] == {("cap", 0): 1024}
+
+
+# ---------------------------------------------------------------------------
+# a sharded deployment served through Proxy.serve_query (the benchmark's
+# lubm_bundle_sharded loader: shards booted once, no whole store beside them)
+# ---------------------------------------------------------------------------
+
+def _route_attrs(q):
+    return [a for sp in q.trace.spans for (_t, n, a) in sp.events
+            if n == "proxy.route"]
+
+
+@pytest.fixture(scope="module")
+def sharded_world(tmp_path_factory, eight_cpu_devices):
+    """LUBM-1 hash-sharded over 4 of the 8 devices, as the loader builds it,
+    and a single-partition CPU oracle over the same triples."""
+    from benchmark.loaders import lubm_bundle_sharded
+
+    d = str(tmp_path_factory.mktemp("sharded") / "c")
+    world = lubm_bundle_sharded.load(
+        {"universities": 1, "partitions": 4, "data_seed": 42}, 0, d)
+    triples = np.asarray(world.triples, dtype=np.int64)
+    g1 = build_partition(triples, 0, 1)
+    return world, triples, CPUEngine(g1, world.proxy.str_server)
+
+
+@pytest.mark.parametrize("qn", [f"lubm_q{k}" for k in range(1, 8)])
+def test_sharded_proxy_serves_lubm(sharded_world, qn, monkeypatch):
+    """Every LUBM query a sharded proxy serves unpinned is the sharded
+    engine's, with no fallback, and its rows are the plain reference's and
+    the CPU oracle's, as sorted multisets."""
+    import re
+
+    from benchmark.reference import Reference, sorted_rows
+    from wukong_tpu.config import Global
+
+    world, _triples, cpu = sharded_world
+    text = open(f"{BASIC}/{qn}").read()
+    monkeypatch.setattr(Global, "enable_tracing", True)
+    q = world.proxy.serve_query(text, blind=False)
+    assert q.result.status_code == 0 and q.result.complete, qn
+    spans = {sp.name for sp in q.trace.spans}
+    assert "dist.execute" in spans, qn
+    assert not spans & {"cpu.execute", "tpu.execute"}, qn
+    assert "proxy.fallback" not in q.trace.event_names(), qn
+    assert [a["route"] for a in _route_attrs(q)] == ["dist"], qn
+    cols = [q.result.v2c_map[v] for v in q.result.required_vars]
+    got = sorted_rows(np.asarray(q.result.table)[:, cols])
+
+    ref = Reference(world.triples, world.index_rows)
+    ss = world.proxy.str_server
+    for iri in re.findall(r"<http://www\.[^>]*\.edu>", text):
+        ref.ids[iri] = int(ss.str2id(iri))
+    assert np.array_equal(got, ref.evaluate(text)), qn
+
+    qc = Parser(ss).parse(text)
+    heuristic_plan(qc)
+    cpu.execute(qc)
+    want = sorted_rows(np.asarray(qc.result.table)[
+        :, [qc.result.v2c_map[v] for v in qc.result.required_vars]])
+    assert np.array_equal(got, want), qn
+    assert len(got) or qn == "lubm_q3"  # q3 is empty by design
+
+
+UNION_TEXT = """PREFIX rdf: <http://www.w3.org/1999/02/22-rdf-syntax-ns#>
+PREFIX ub: <http://swat.cse.lehigh.edu/onto/univ-bench.owl#>
+SELECT ?X WHERE {
+  ?X rdf:type ub:FullProfessor .
+  { ?X ub:worksFor <http://www.Department0.University0.edu> . }
+  UNION
+  { ?X ub:headOf <http://www.Department0.University0.edu> . }
+}"""
+
+
+def test_sharded_proxy_serves_a_union_and_degrades_to_the_shards_in_place(
+        sharded_world, monkeypatch):
+    """A UNION is the sharded engine's, with no fallback. A shape the
+    sharded engine refuses falls back, with its ``proxy.fallback`` event, to
+    the host engine that walks every shard in place, not to the device
+    engine, which stages no shard: both answers are the oracle's. The
+    engine pool's engines and a template's constants read every shard, not
+    the proxy's own partition alone."""
+    from wukong_tpu.config import Global
+    from wukong_tpu.parallel.inplace import InplaceEngine
+    from wukong_tpu.types import IN
+    from wukong_tpu.utils.errors import ErrorCode
+    from wukong_tpu.utils.paths import LUBM_EMULATOR as EMU
+
+    world, _triples, cpu = sharded_world
+    proxy, ss = world.proxy, world.proxy.str_server
+    qc = Parser(ss).parse(UNION_TEXT)
+    heuristic_plan(qc)
+    cpu.execute(qc)
+    want = sorted(np.asarray(qc.result.table)[:, 0].tolist())
+    assert len(want) > 0
+    monkeypatch.setattr(Global, "enable_tracing", True)
+
+    def served():
+        q = proxy.serve_query(UNION_TEXT, blind=False)
+        assert q.result.status_code == 0 and q.result.complete
+        col = q.result.v2c_map[q.result.required_vars[0]]
+        # an event outside every span is a span of its own name
+        return ({sp.name for sp in q.trace.spans}
+                | set(q.trace.event_names()),
+                sorted(np.asarray(q.result.table)[:, col].tolist()))
+
+    names, got = served()
+    assert "dist.execute" in names and got == want
+    assert "proxy.fallback" not in names
+
+    def refuse(q, *a, **kw):
+        q.result.status_code = ErrorCode.UNSUPPORTED_SHAPE
+
+    monkeypatch.setattr(proxy.dist, "execute", refuse)
+    names, got = served()
+    assert "proxy.fallback" in names
+    assert "cpu.execute" in names and "tpu.execute" not in names
+    assert got == want
+
+    eng = proxy._new_host_engine()
+    assert isinstance(eng, InplaceEngine)
+    q4 = Parser(ss).parse(open(f"{BASIC}/lubm_q4").read())
+    heuristic_plan(q4)
+    eng.execute(q4)
+    assert q4.result.status_code == 0 and q4.result.nrows > 0
+
+    tmpl = Parser(ss).parse_template(open(f"{EMU}/q1").read())
+    proxy.fill_template(tmpl)
+    whole = sorted(cpu.g.get_index(tmpl.ptypes[0], IN).tolist())
+    assert sorted(tmpl.candidates[0].tolist()) == whole
+    assert len(whole) > len(proxy.g.get_index(tmpl.ptypes[0], IN))
+
+
+def test_shards_partition_the_whole(sharded_world):
+    """The four shards hold the single store's edges and no other, each edge
+    on the shard that owns its key (``hash(vid) % 4``): OUT edges with their
+    subject, IN edges with their object; each index member with its
+    vertex's owner."""
+    world, triples, cpu = sharded_world
+    stores = world.proxy.dist.sstore.stores
+    g1 = cpu.g
+    assert len(stores) == 4 and world.proxy.g is stores[0]
+
+    def pairs(seg):
+        return np.stack([np.repeat(seg.keys, np.diff(seg.offsets)),
+                         seg.edges], axis=1)
+
+    for key, seg in g1.segments.items():
+        parts = []
+        for k, st in enumerate(stores):
+            mine = st.segments.get(key)
+            if mine is None:
+                continue
+            assert np.all(mine.keys % 4 == k), key
+            parts.append(pairs(mine))
+        got = np.concatenate(parts)
+        assert np.array_equal(got[np.lexsort(got.T[::-1])],
+                              pairs(seg)[np.lexsort(pairs(seg).T[::-1])]), key
+    for key, members in g1.index.items():
+        got = np.sort(np.concatenate(
+            [st.index.get(key, np.empty(0, np.int64)) for st in stores]))
+        assert np.array_equal(got, np.sort(members)), key
+    assert sum(int(np.sum(st.v_set % 4 == k))
+               for k, st in enumerate(stores)) == sum(
+        len(st.v_set) for st in stores)
+
+
+def _counter_total(name):
+    from wukong_tpu.obs.metrics import get_registry
+
+    series = (get_registry().snapshot().get(name) or {}).get("series", [])
+    return sum(float(s.get("value", 0)) for s in series)
+
+
+def _hub_graph():
+    """A type T of 2,000 members, each with a p edge to one hub H (all but
+    a few) and H with a q edge to each of 3,000 vertices: H's shard holds
+    most of the q edges and every row of the last expansion."""
+    from wukong_tpu.types import NORMAL_ID_START, TYPE_ID
+
+    T, P, Q = 20, 21, 22
+    base = NORMAL_ID_START
+    xs = np.arange(base + 8, base + 8 + 2000)
+    hub, other = base + 1, base + 2
+    zs = np.arange(base + 100_000, base + 103_000)
+    obj = np.where(np.arange(len(xs)) % 50 == 0, other, hub)
+    rows = [np.stack([xs, np.full_like(xs, TYPE_ID), np.full_like(xs, T)], 1),
+            np.stack([xs, np.full_like(xs, P), obj], 1),
+            np.stack([np.full_like(zs, hub), np.full_like(zs, Q), zs], 1),
+            np.array([[other, Q, zs[0]]])]
+    return np.concatenate(rows).astype(np.int64), (T, P, Q)
+
+
+def _hub_query(T, P, Q):
+    from wukong_tpu.sparql.ir import Pattern, SPARQLQuery
+    from wukong_tpu.types import IN, OUT, TYPE_ID
+
+    q = SPARQLQuery()
+    q.pattern_group.patterns = [Pattern(T, TYPE_ID, IN, -1),
+                                Pattern(-1, P, OUT, -2),
+                                Pattern(-2, Q, OUT, -3)]
+    q.result.nvars = 3
+    q.result.required_vars = [-1, -2, -3]
+    return q
+
+
+def test_hot_vertex_answers_exactly_through_the_retry(eight_cpu_devices):
+    """One hot vertex owns most edges: its shard holds nearly every row of
+    the last expansion, and every shard is padded to it. Started at a class
+    that cannot hold its exchange, the chain runs again at the exact class,
+    answers exactly, and the retry counter and a ``capacity.retry`` event
+    count it; run again, it starts at the class it measured."""
+    from wukong_tpu.obs.trace import QueryTrace
+
+    triples, (T, P, Q) = _hub_graph()
+    dist = DistEngine(build_all_partitions(triples, 4), None,
+                      make_mesh(4, eight_cpu_devices[:4]))
+    want_q = _hub_query(T, P, Q)
+    CPUEngine(build_partition(triples, 0, 1), None).execute(
+        want_q, from_proxy=False)
+    want = sorted(map(tuple, want_q.result.table.tolist()))
+    assert len(want) == 1960 * 3000 + 40  # the hub's rows and the rest
+
+    before = _counter_total("wukong_dist_capacity_retries_total")
+    # each shard sends the hub's owner its 490 rows: a class of 256 a
+    # destination cannot hold them
+    dist.force_cap_override = {("exch", 2): 256}
+    q = _hub_query(T, P, Q)
+    q.trace = QueryTrace(kind="query")
+    dist.execute(q, from_proxy=False)
+    assert q.result.status_code == 0
+    assert sorted(map(tuple, q.result.table.tolist())) == want
+    retries = dist.last_chain_stats["retries"]
+    assert retries >= 1
+    assert _counter_total("wukong_dist_capacity_retries_total") - before \
+        == retries
+    assert q.trace.event_names().count("capacity.retry") >= retries
+    stats = dist.last_chain_stats
+    assert stats["rows_max_shard"] == 1960 * 3000
+    assert stats["rows_mean_shard"] == len(want) / 4
+
+    q2 = _hub_query(T, P, Q)
+    dist.execute(q2, from_proxy=False)
+    assert dist.last_chain_stats["retries"] == 0  # the learned classes
+    assert sorted(map(tuple, q2.result.table.tolist())) == want
+
+
+def test_exchange_counters_read_a_hand_checked_query(eight_cpu_devices):
+    """Four rows, one a shard, each exchanged to the next shard: the chain
+    counts four live rows off their chip, ``(D - 1)`` slots a shard of the
+    destination class, 4 x 2 ids of bytes, and its span says so."""
+    from wukong_tpu.obs.trace import QueryTrace
+    from wukong_tpu.sparql.ir import Pattern, SPARQLQuery
+    from wukong_tpu.types import IN, NORMAL_ID_START, OUT, TYPE_ID
+
+    T, P, Q = 20, 21, 22
+    base = NORMAL_ID_START  # a multiple of 4: vertex base + k is on shard k
+    xs = base + np.arange(4)
+    ys = base + 100 + (np.arange(4) + 1) % 4  # the next shard's vertex
+    zs = base + 200 + np.arange(4)
+    triples = np.concatenate([
+        np.stack([xs, np.full(4, TYPE_ID), np.full(4, T)], 1),
+        np.stack([xs, np.full(4, P), ys], 1),
+        np.stack([ys, np.full(4, Q), zs], 1)]).astype(np.int64)
+    dist = DistEngine(build_all_partitions(triples, 4), None,
+                      make_mesh(4, eight_cpu_devices[:4]))
+    q = SPARQLQuery()
+    q.pattern_group.patterns = [Pattern(T, TYPE_ID, IN, -1),
+                                Pattern(-1, P, OUT, -2),
+                                Pattern(-2, Q, OUT, -3)]
+    q.result.nvars = 3
+    q.result.required_vars = [-1, -2, -3]
+    q.trace = QueryTrace(kind="query")
+    names = ("wukong_dist_exchange_rows_total",
+             "wukong_dist_exchange_slots_total",
+             "wukong_dist_exchange_bytes_total")
+    before = [_counter_total(n) for n in names]
+    dist.execute(q, from_proxy=False)
+    assert q.result.status_code == 0 and q.result.nrows == 4
+    exch = [s for s in dist.last_chain_stats["steps"] if "exch_cap" in s]
+    assert len(exch) == 1  # the third step anchors on y: y's owner
+    slots = 4 * 3 * exch[0]["exch_cap"]
+    got = [_counter_total(n) - b for n, b in zip(names, before)]
+    assert got == [4, slots, 4 * 2 * 4]
+    chain = next(sp for sp in q.trace.spans if sp.name == "dist.chain")
+    assert chain.attrs["exchange_rows"] == 4
+    assert chain.attrs["exchange_slots"] == slots
+    assert chain.attrs["rows_max_shard"] == 1
+    assert chain.attrs["rows_mean_shard"] == 1.0
+
+
+def test_shard_tables_hash_the_bits_above_the_shard(sharded_world):
+    """A shard's vertex-keyed table holds only its own residue mod 4, so it
+    homes a key by the bits above (``key_shift`` 2) and probes one round
+    where the same keys hashed whole need two or three; the type index,
+    keyed by type ids on every shard, is hashed whole. A constant probed on
+    a shard that does not own it is found nowhere there."""
+    from wukong_tpu.engine.device_store import _next_pow2
+    from wukong_tpu.parallel.sharded_store import _shard_tables
+    from wukong_tpu.types import IN, OUT, TYPE_ID
+
+    world, _triples, _cpu = sharded_world
+    sstore = world.proxy.dist.sstore
+    typed = sstore.segment(TYPE_ID, OUT)
+    assert typed.key_shift == 2 and typed.max_probe == 1
+    assert sstore.segment(TYPE_ID, IN).key_shift == 0
+    shards = [(st.segments[(TYPE_ID, OUT)].keys,
+               st.segments[(TYPE_ID, OUT)].offsets,
+               st.segments[(TYPE_ID, OUT)].edges) for st in sstore.stores]
+    nb = max(_next_pow2((max(len(k) for k, _o, _e in shards) + 3) // 4), 2)
+    ep = _next_pow2(max(len(e) for _k, _o, e in shards))
+    assert _shard_tables(shards, nb, ep, 0)["max_probe"] >= 2
+
+    from wukong_tpu.sparql.ir import Pattern, SPARQLQuery
+
+    dist = world.proxy.dist
+    for vid in (int(shards[1][0][0]), int(shards[2][0][-1])):
+        q = SPARQLQuery()  # a const start: every shard probes the constant
+        q.pattern_group.patterns = [Pattern(vid, TYPE_ID, OUT, -1)]
+        q.result.nvars = 1
+        q.result.required_vars = [-1]
+        dist.execute(q, from_proxy=False)
+        owner = dist.sstore.stores[vid % 4]
+        assert sorted(q.result.table[:, 0].tolist()) == sorted(
+            owner.get_triples(vid, TYPE_ID, OUT).tolist())

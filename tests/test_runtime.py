@@ -163,6 +163,48 @@ def test_dist_fallback_on_unsupported_shape():
     assert q.result.nrows > 0
 
 
+def test_sharded_proxy_routes_unpinned_requests_to_the_sharded_engine(
+        monkeypatch):
+    """A proxy that holds the sharded engine serves an unpinned request
+    through it (``proxy.route`` says ``dist``); a pinned ``device=`` is
+    honoured; a proxy without one routes as before."""
+    import jax
+
+    from wukong_tpu.parallel.dist_engine import DistEngine
+    from wukong_tpu.parallel.mesh import make_mesh
+    from wukong_tpu.store.gstore import build_all_partitions
+
+    triples, _ = generate_lubm(1, seed=42)
+    ss = VirtualLubmStrings(1, seed=42)
+    g = build_partition(triples, 0, 1)
+    dist = DistEngine(build_all_partitions(triples, 4), ss,
+                      make_mesh(4, jax.devices()[:4]))
+    sharded = Proxy(g, ss, CPUEngine(g, ss), None, dist)
+    single = Proxy(g, ss, CPUEngine(g, ss), None)
+    monkeypatch.setattr(Global, "enable_tracing", True)
+    text = open(f"{BASIC}/lubm_q2").read()
+
+    def served(p, device=None):
+        q = p.serve_query(text, blind=False, device=device)
+        assert q.result.status_code == 0
+        spans = {sp.name for sp in q.trace.spans}
+        routes = [a.get("route") for sp in q.trace.spans
+                  for (_t, n, a) in sp.events if n == "proxy.route"]
+        return spans, routes, q.result.nrows
+
+    spans, routes, n = served(sharded)
+    assert "dist.execute" in spans and "cpu.execute" not in spans
+    assert routes == ["dist"]
+    spans, routes, n_cpu = served(sharded, "cpu")
+    assert "cpu.execute" in spans and "dist.execute" not in spans
+    assert routes == ["walk"] and n_cpu == n
+    spans, _routes, _n = served(sharded, "dist")
+    assert "dist.execute" in spans
+    spans, routes, n_single = served(single)
+    assert "dist.execute" not in spans and "cpu.execute" in spans
+    assert routes == ["walk"] and n_single == n
+
+
 def test_sparql_batch_mode(proxy, tmp_path):
     c = Console(proxy)
     batch = tmp_path / "batch"

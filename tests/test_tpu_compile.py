@@ -276,3 +276,69 @@ def test_level_programs_compile_at_lsqb_sizes(one_chip, case):
         mem = c.memory_analysis()
         assert mem.temp_size_in_bytes < limit
         assert mem.generated_code_size_in_bytes < 22 << 20
+
+
+@pytest.fixture(scope="module")
+def four_chips(one_chip):
+    """A mesh over the four described chips of a v5e:2x2, as the sharded
+    engine lays its partitions (one axis, ``x``)."""
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh
+
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    return Mesh(np.array(topo.devices), ("x",))
+
+
+def test_sharded_chain_compiles_for_four_chips(four_chips):
+    """LUBM q1's sharded chain (``wk_dist_chain``) over four chips at the
+    per-shard sizes of LUBM-2560 (``lubm_headers(2560)`` over four shards,
+    the classes its warm-up learns): an index start, the expansion to the
+    students, three row exchanges over ICI and three membership probes by
+    fingerprint, each shard's keys homed by their bits above the shard's,
+    one program. The compiler keeps its temporaries under 4 GiB
+    a chip and puts the all-to-all in."""
+    import types
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from wukong_tpu.loader.lubm import P as PRED, lubm_headers
+    from wukong_tpu.parallel.dist_engine import DistEngine, _Plan, _Step
+    from wukong_tpu.types import IN, OUT, TYPE_ID
+
+    D = 4
+    segs = lubm_headers(2560)["segs"]
+
+    def table(pid, d):  # a shard's share, staged as sharded_store stages it
+        nk, ne, _md = segs[(pid, d)]
+        nb = max(_next_pow2((-(-nk // D) + 3) // 4), 2)
+        return [(D, nb * BUCKET)] * 3 + [(D, _next_pow2(-(-ne // D)))] \
+            + [(D, nb)] * 2  # the fingerprint words
+
+    steps = [_Step("init_index", pid=17, dir=IN, cap=1 << 16),
+             _Step("expand", pid=PRED["undergraduateDegreeFrom"], dir=IN,
+                   col=0, cap=1 << 21, new_col=True),
+             _Step("expand", pid=PRED["memberOf"], dir=OUT, col=1,
+                   cap=1 << 21, exch_cap=1 << 20, new_col=True),
+             _Step("member", pid=PRED["subOrganizationOf"], dir=OUT, col=2,
+                   vals_col=0, cap=1 << 21, exch_cap=1 << 19),
+             _Step("member", pid=TYPE_ID, dir=OUT, col=1, const=24,
+                   cap=1 << 16, exch_cap=1 << 16),
+             _Step("member", pid=TYPE_ID, dir=OUT, col=2, const=18,
+                   cap=1 << 16, exch_cap=1 << 16)]
+    shapes = [[(D, 1024), (D, 1)]] + [table(s.pid, s.dir) for s in steps[1:]]
+    bounds = types.SimpleNamespace(max_probe=1, max_deg_log2=12, fpw0=True,
+                                   max_fp_dup=2, key_shift=2)
+    eng = types.SimpleNamespace(
+        D=D, axis="x", mesh=four_chips,
+        sstore=types.SimpleNamespace(segment=lambda pid, d: bounds))
+    sharded = NamedSharding(four_chips, P("x", None))
+    args = [tuple(jax.ShapeDtypeStruct(s, jnp.int32, sharding=sharded)
+                  for s in group) for group in shapes]
+    fn = DistEngine._compile(eng, _Plan(steps=steps), args)
+    compiled = fn.lower(*DistEngine._flatten_args(args)).compile()
+    mem = compiled.memory_analysis()
+    print(f"\nsharded q1 chain: {mem}")
+    assert mem.temp_size_in_bytes < 4 << 30
+    assert "all-to-all" in compiled.as_text()

@@ -48,7 +48,18 @@ BUCKET = 8
 # ---------------------------------------------------------------------------
 
 
-def _hash_find(bkey, bstart, bdeg, cur, valid, max_probe: int):
+def _home(cur, bmask, key_shift: int):
+    """A key's home bucket: the multiplicative hash of its bits above
+    ``key_shift`` (a shard's table whose keys all share their low bits,
+    ``ShardedDeviceStore``, hashes the bits that differ)."""
+    k = cur.astype(jnp.uint32)
+    if key_shift:
+        k = k >> np.uint32(key_shift)
+    return (k * _HASH_MULT) & bmask
+
+
+def _hash_find(bkey, bstart, bdeg, cur, valid, max_probe: int,
+               key_shift: int = 0):
     """(found, start, degree) per cur[i]; bkey/bstart/bdeg are flat [NB*8].
 
     Per probe round: three flat gathers of [C*8] (groups of 8 consecutive
@@ -58,7 +69,7 @@ def _hash_find(bkey, bstart, bdeg, cur, valid, max_probe: int):
     NB = bkey.shape[0] // BUCKET
     bmask = np.uint32(NB - 1)
     C = cur.shape[0]
-    hb = (cur.astype(jnp.uint32) * _HASH_MULT) & bmask
+    hb = _home(cur, bmask, key_shift)
     found = jnp.zeros(C, bool)
     start = jnp.zeros_like(cur)
     deg = jnp.zeros_like(cur)
@@ -96,7 +107,7 @@ def _fp_of(cur):
 
 
 def _hash_find_fp(bkey, bstart, bdeg, fpw0, fpw1, cur, valid,
-                  max_probe: int, fp_dup: int):
+                  max_probe: int, fp_dup: int, key_shift: int = 0):
     """Fingerprint-packed probe: same contract as _hash_find with ~5 [C]
     gathers per round instead of 24.
 
@@ -112,7 +123,7 @@ def _hash_find_fp(bkey, bstart, bdeg, fpw0, fpw1, cur, valid,
     bmask = np.uint32(NB - 1)
     C = cur.shape[0]
     curfp = _fp_of(cur)
-    hb = (cur.astype(jnp.uint32) * _HASH_MULT) & bmask
+    hb = _home(cur, bmask, key_shift)
     found = jnp.zeros(C, bool)
     start = jnp.zeros_like(cur)
     deg = jnp.zeros_like(cur)
@@ -184,22 +195,23 @@ def _saturate_total(cum):
 
 
 def _probe(bkey, bstart, bdeg, cur, n, max_probe: int,
-           fpw0=None, fpw1=None, fp_dup: int = 0):
+           fpw0=None, fpw1=None, fp_dup: int = 0, key_shift: int = 0):
     """Probe dispatch. `fp_dup` is the caller's STATIC decision (see
     DeviceSegment.max_fp_dup); row validity is derived from `n` on every
     path so the two probes can never diverge on masking. fp_dup > 0 selects
-    the fingerprint-packed probe."""
+    the fingerprint-packed probe. `key_shift` (static) is the table's: the
+    low key bits its home buckets ignore (0 but for a shard's table)."""
     valid = jnp.arange(cur.shape[0], dtype=jnp.int32) < n
     if fp_dup > 0 and fpw0 is not None:
         return _hash_find_fp(bkey, bstart, bdeg, fpw0, fpw1, cur, valid,
-                             max_probe, fp_dup)
-    return _hash_find(bkey, bstart, bdeg, cur, valid, max_probe)
+                             max_probe, fp_dup, key_shift)
+    return _hash_find(bkey, bstart, bdeg, cur, valid, max_probe, key_shift)
 
 
 @partial(jax.jit,
-         static_argnames=("col", "cap_out", "max_probe", "fp_dup"))
+         static_argnames=("col", "cap_out", "max_probe", "fp_dup", "key_shift"))
 def wk_walk_expand(table, n, bkey, bstart, bdeg, edges, col, cap_out,
-                   max_probe, fpw0=None, fpw1=None, fp_dup=0):
+                   max_probe, fpw0=None, fpw1=None, fp_dup=0, key_shift=0):
     """known_to_unknown: expand each live row by its neighbor list.
 
     table: [W, C]. Returns (out [W+1, cap_out], out_n, total) — total may
@@ -211,7 +223,7 @@ def wk_walk_expand(table, n, bkey, bstart, bdeg, edges, col, cap_out,
     valid = rows < n
     cur = table[col]
     found, start, deg = _probe(bkey, bstart, bdeg, cur, n, max_probe,
-                               fpw0, fpw1, fp_dup)
+                               fpw0, fpw1, fp_dup, key_shift)
     cum = jnp.cumsum(deg)
     total = _saturate_total(cum)
     starts_excl = cum - deg
@@ -232,9 +244,10 @@ def wk_walk_expand(table, n, bkey, bstart, bdeg, edges, col, cap_out,
 
 
 @partial(jax.jit,
-         static_argnames=("col", "cap_out", "max_probe", "fp_dup"))
+         static_argnames=("col", "cap_out", "max_probe", "fp_dup", "key_shift"))
 def wk_walk_expand2(table, n, bkey, bstart, bdeg, edges_pid, edges_val, col,
-                    cap_out, max_probe, fpw0=None, fpw1=None, fp_dup=0):
+                    cap_out, max_probe, fpw0=None, fpw1=None, fp_dup=0,
+                    key_shift=0):
     """VERSATILE known_unknown_unknown (?x ?p ?y with x bound — the
     reference's sparql.hpp:601-650 kernel; its GPU engine refuses the
     shape): expand each live row by its COMBINED adjacency — every
@@ -246,7 +259,7 @@ def wk_walk_expand2(table, n, bkey, bstart, bdeg, edges_pid, edges_val, col,
     rows = jnp.arange(C, dtype=jnp.int32)
     cur = table[col]
     found, start, deg = _probe(bkey, bstart, bdeg, cur, n, max_probe,
-                               fpw0, fpw1, fp_dup)
+                               fpw0, fpw1, fp_dup, key_shift)
     cum = jnp.cumsum(deg)
     total = _saturate_total(cum)
     starts_excl = cum - deg
@@ -268,10 +281,10 @@ def wk_walk_expand2(table, n, bkey, bstart, bdeg, edges_pid, edges_val, col,
 
 
 @partial(jax.jit,
-         static_argnames=("col", "max_probe", "depth", "fp_dup"))
+         static_argnames=("col", "max_probe", "depth", "fp_dup", "key_shift"))
 def wk_walk_member_mask_known(table, n, vals, bkey, bstart, bdeg, edges, col,
                               max_probe, depth, fpw0=None, fpw1=None,
-                              fp_dup=0):
+                              fp_dup=0, key_shift=0):
     """known_to_known / known_to_const: per-row membership of vals[i] in
     adj(cur[i]). table: [W, C]; vals: [C]."""
     W, C = table.shape
@@ -279,7 +292,7 @@ def wk_walk_member_mask_known(table, n, vals, bkey, bstart, bdeg, edges, col,
     valid = rows < n
     cur = table[col]
     found, start, deg = _probe(bkey, bstart, bdeg, cur, n, max_probe,
-                               fpw0, fpw1, fp_dup)
+                               fpw0, fpw1, fp_dup, key_shift)
     ok = _range_member(edges, start, start + deg, vals, depth)
     return valid & found & ok
 

@@ -27,6 +27,7 @@ import numpy as np
 
 from wukong_tpu.config import Global
 from wukong_tpu.engine import tpu_kernels as K
+from wukong_tpu.obs.metrics import get_registry
 from wukong_tpu.parallel.sharded_store import ShardedDeviceStore
 from wukong_tpu.sparql.ir import NO_RESULT, PGType, SPARQLQuery
 from wukong_tpu.types import IN, OUT, PREDICATE_ID, TYPE_ID, AttrType
@@ -38,6 +39,26 @@ from wukong_tpu.utils.errors import (
     WukongError,
     assert_ec,
 )
+
+# what a chain's collectives carried, counted once its attempt came out
+# sound: live rows that left their chip, and the slots shipped to other
+# chips, padding included (``all_to_all`` ships ``exch_cap`` slots to each
+# destination; ``all_gather`` the whole table to every other chip)
+_M_XROWS = get_registry().counter(
+    "wukong_dist_exchange_rows_total",
+    "Live binding-table rows the sharded chain sent to another chip",
+    labels=("collective",))
+_M_XSLOTS = get_registry().counter(
+    "wukong_dist_exchange_slots_total",
+    "Row slots the sharded chain shipped to other chips, padding included",
+    labels=("collective",))
+_M_XBYTES = get_registry().counter(
+    "wukong_dist_exchange_bytes_total",
+    "Bytes of the live rows the sharded chain sent to another chip",
+    labels=("collective",))
+_M_RETRIES = get_registry().counter(
+    "wukong_dist_capacity_retries_total",
+    "Whole-chain reruns of the sharded chain at larger capacity classes")
 
 
 @dataclass
@@ -253,7 +274,9 @@ class DistEngine:
                     st = getattr(self, "last_chain_stats", None) or {}
                     tr.end_span(sp, rows_out=q.result.nrows,
                                 **{k: st[k] for k in
-                                   ("mode", "retries", "exchanges")
+                                   ("mode", "retries", "exchanges",
+                                    "exchange_rows", "exchange_slots",
+                                    "rows_max_shard", "rows_mean_shard")
                                    if k in st})
         while not q.done_patterns():  # attr tail (or attr-only query)
             self._attr_host()._execute_one_pattern(q)
@@ -463,6 +486,7 @@ class DistEngine:
         if self.force_cap_override:
             cap_override.update(self.force_cap_override)
         self.force_cap_override = None
+        from wukong_tpu.obs.trace import trace_event
         from wukong_tpu.runtime import faults
         from wukong_tpu.runtime.resilience import (
             charge_query,
@@ -496,7 +520,8 @@ class DistEngine:
             else:
                 tables, ns, totals = _gather_host(
                     (out["table"], out["n"], out["totals"]))
-            totals = np.asarray(totals)  # [D, 2 * nsteps]
+            # [D, 4 * nsteps]: rows, exchange loads, rows sent, slots sent
+            totals = np.asarray(totals)
             S = len(plan.steps)
             over = False
             for i, s in enumerate(plan.steps):
@@ -508,6 +533,9 @@ class DistEngine:
                             f"table_capacity_max ({self.cap_max:,})")
                     cap_override[("cap", i)] = K.next_capacity(
                         t, self.cap_min, self.cap_max)
+                    trace_event("capacity.retry", site="dist.chain", step=i,
+                                cap_from=s.cap,
+                                cap_to=cap_override[("cap", i)])
                     over = True
                 if s.exch_cap:
                     em = int(totals[:, S + i].max())
@@ -518,6 +546,9 @@ class DistEngine:
                                 f"exceeds table_capacity_max ({self.cap_max:,})")
                         cap_override[("exch", i)] = K.next_capacity(
                             em, self.cap_min, self.cap_max)
+                        trace_event("capacity.retry", site="dist.exchange",
+                                    step=i, cap_from=s.exch_cap,
+                                    cap_to=cap_override[("exch", i)])
                         over = True
             if not over:
                 break
@@ -531,18 +562,38 @@ class DistEngine:
         # per-destination exchange load against their capacity classes
         S = len(plan.steps)
         step_stats = []
-        for i, s in enumerate(plan.steps):
+        # collective -> [live rows, slots, live bytes] sent to other chips
+        moved = {"all_to_all": [0, 0, 0], "all_gather": [0, 0, 0]}
+        for i, (s, w_in) in enumerate(zip(plan.steps, _widths_in(plan))):
             st = {"kind": s.kind, "cap": s.cap,
                   "rows_peak_shard": int(totals[:, i].max()),
                   "rows_all_shards": int(totals[:, i].sum())}
             if s.exch_cap:
                 st["exch_cap"] = s.exch_cap
                 st["exch_peak_dest"] = int(totals[:, S + i].max())
+            if s.exch_cap or s.kind == "expand_type_all":
+                got = moved["all_to_all" if s.exch_cap else "all_gather"]
+                rows = int(totals[:, 2 * S + i].sum())
+                got[0] += rows
+                got[1] += int(totals[:, 3 * S + i].sum())
+                got[2] += rows * w_in * 4  # int32 ids
             step_stats.append(st)
-        self.last_chain_stats = {"retries": int(_attempt),
-                                 "exchanges": sum(1 for s in plan.steps
-                                                  if s.exch_cap),
-                                 "steps": step_stats}
+        for coll, (rows, slots, nbytes) in moved.items():
+            if slots:
+                _M_XROWS.labels(collective=coll).inc(rows)
+                _M_XSLOTS.labels(collective=coll).inc(slots)
+                _M_XBYTES.labels(collective=coll).inc(nbytes)
+        if _attempt:
+            _M_RETRIES.inc(_attempt)
+        ns = np.asarray(ns).reshape(-1)
+        self.last_chain_stats = {
+            "retries": int(_attempt),
+            "exchanges": sum(1 for s in plan.steps if s.exch_cap),
+            "exchange_rows": sum(m[0] for m in moved.values()),
+            "exchange_slots": sum(m[1] for m in moved.values()),
+            "rows_max_shard": int(ns.max()),
+            "rows_mean_shard": float(ns.mean()),
+            "steps": step_stats}
         self._last_plan = plan
         # learn EXACT classes for the next run of this chain (tighter
         # where the estimate over-padded, already-exact where it retried)
@@ -597,26 +648,23 @@ class DistEngine:
         W = 4  # int32 device arrays
         D = self.D
         seg_b = tab_b = exch_b = 0
-        width = 0
         cap_prev = 0
-        for s in plan.steps:
-            w_in = width
+        widths = _widths_in(plan)
+        for i, s in enumerate(plan.steps):
+            w_in, width = widths[i], widths[i + 1]
             if s.kind == "init_rows":
-                width = s.width
                 cap_prev = s.cap
                 tab_b += W * D * width * s.cap
                 continue
             if s.kind == "init_index":
                 idx = self.sstore.index_list(s.pid, s.dir)
                 seg_b += int(idx.edges.size) * W
-                width = 1
                 cap_prev = s.cap
                 tab_b += W * D * s.cap
                 continue
             if s.kind == "init_const":
                 seg = self.sstore.segment(s.pid, s.dir)
                 seg_b += int(seg.nbytes) if seg is not None else 0
-                width = 1
                 cap_prev = s.cap
                 tab_b += W * D * s.cap
                 continue
@@ -634,8 +682,6 @@ class DistEngine:
             else:
                 seg = self.sstore.segment(s.pid, s.dir)
                 seg_b += int(seg.nbytes) if seg is not None else 0
-            if s.new_col:
-                width += 2 if s.kind == "expand_versatile" else 1
             tab_b += W * D * (w_in * cap_prev + width * s.cap)
             cap_prev = s.cap
         return {"segment_bytes": int(seg_b), "table_bytes": int(tab_b),
@@ -664,19 +710,24 @@ class DistEngine:
         col_mult: dict[int, int] = {}
         MULT_CAP = 1 << 31
 
+        # ``est_rows``: the rows a shard is expected to hold at a step, from
+        # average degrees; each class is sized at twice it (one class of
+        # headroom, as the single-chip engine's EST_SAFETY), the headroom
+        # taken once a step and never compounded along the chain
         def cap_for(i, est):
             return cap_override.get(("cap", i)) or K.next_capacity(
-                max(int(est), self.cap_min), self.cap_min, self.cap_max)
+                max(int(est) * 2, self.cap_min), self.cap_min, self.cap_max)
 
         def exch_cap_for(i, col):
             got = cap_override.get(("exch", i))
             if got:
                 return got
-            base = max(est_rows // self.D * 4, self.cap_min)
+            padded = est_rows * 2
+            base = max(padded // self.D * 4, self.cap_min)
             hot = col_mult.get(col)
             if hot is not None:
-                base = max(base, min(int(hot), int(est_rows))
-                           + est_rows // self.D * 2)
+                base = max(base, min(int(hot), int(padded))
+                           + padded // self.D * 2)
             return K.next_capacity(min(base, self.cap_max),
                                    self.cap_min, self.cap_max)
 
@@ -697,7 +748,7 @@ class DistEngine:
                       ErrorCode.UNSUPPORTED_SHAPE,
                       "seeded distributed chains must start from a pattern "
                       "anchored on a seeded column")
-            est_rows = max(len(seed_table) // self.D, 1) * 2
+            est_rows = max(len(seed_table) // self.D, 1)
             plan.steps.append(_Step(
                 kind="init_rows", col=anchor, width=width,
                 cap=self._seed_cap(seed_table, anchor)))
@@ -727,7 +778,7 @@ class DistEngine:
                     exch_cap = exch_cap_for(i, col)
                 vseg = self.sstore.versatile_segment(d)
                 avg = vseg.avg_deg if vseg else 0.0
-                est_rows = int(max(est_rows * max(avg, 0.1) * 2, 1))
+                est_rows = int(max(est_rows * max(avg, 0.1), 1))
                 kind = "expand_versatile" if o < 0 else "expand_versatile_const"
                 plan.steps.append(_Step(
                     kind=kind, pid=0, dir=d, col=col,
@@ -749,7 +800,7 @@ class DistEngine:
             if i == 0 and seed is None and q.pattern_step == 0 \
                     and pat is patterns[0] and q.start_from_index():
                 idx = self.sstore.index_list(s, d)
-                est_rows = max(idx.total // self.D, 1) * 2
+                est_rows = max(idx.total // self.D, 1)
                 step = _Step(kind="init_index", pid=s, dir=d,
                              cap=cap_for(i, est_rows))
                 v2c[o] = 0
@@ -777,7 +828,7 @@ class DistEngine:
             if width == 0:
                 assert_ec(s > 0, ErrorCode.FIRST_PATTERN_ERROR)
                 seg = self.sstore.segment(p, d)
-                est_rows = int((seg.avg_deg if seg else 1) * 2)
+                est_rows = int(max(seg.avg_deg if seg else 1, 1))
                 step = _Step(kind="init_const", pid=p, dir=d, const=s,
                              cap=cap_for(i, est_rows))
                 v2c[o] = 0
@@ -819,7 +870,7 @@ class DistEngine:
             seg = self.sstore.segment(p, d)
             avg = seg.avg_deg if seg else 0.0
             if o < 0 and not o_known:  # expansion
-                est_rows = int(max(est_rows * max(avg, 0.1) * 2, 1))
+                est_rows = int(max(est_rows * max(avg, 0.1), 1))
                 kind = "expand_type_all" if type_all else "expand"
                 step = _Step(kind=kind, pid=p, dir=d, col=col,
                              cap=min(cap_for(i, est_rows), self.cap_max),
@@ -883,16 +934,19 @@ class DistEngine:
                     bounds.append((0, 0))
                 else:
                     args.append((vseg.bkey, vseg.bstart, vseg.bdeg,
-                                 vseg.edges, vseg.edges2))
-                    bounds.append((vseg.max_probe, vseg.max_deg_log2))
+                                 vseg.edges, vseg.edges2) + _fp_args(vseg))
+                    bounds.append((vseg.max_probe, vseg.max_deg_log2,
+                                   _fp_dup(vseg), vseg.key_shift))
             else:
                 seg = self.sstore.segment(s.pid, s.dir)
                 if seg is None:
                     args.append(None)
                     bounds.append((0, 0))
                 else:
-                    args.append((seg.bkey, seg.bstart, seg.bdeg, seg.edges))
-                    bounds.append((seg.max_probe, seg.max_deg_log2))
+                    args.append((seg.bkey, seg.bstart, seg.bdeg, seg.edges)
+                                + _fp_args(seg))
+                    bounds.append((seg.max_probe, seg.max_deg_log2,
+                                   _fp_dup(seg), seg.key_shift))
         sig = (plan.signature(), tuple(bounds))
         if sig in self._fn_cache:
             return self._fn_cache[sig], self._flatten_args(args)
@@ -968,6 +1022,8 @@ class DistEngine:
 
         probes = {}
         depths = {}
+        fps = {}  # the fingerprint probe's candidates a bucket; 0: plain
+        shifts = {}  # the low key bits a table's home buckets ignore
         for i, s in enumerate(steps):
             if s.kind in ("expand_versatile", "expand_versatile_const"):
                 # the combined segment's OWN probe bound — segment(pid=0)
@@ -976,10 +1032,22 @@ class DistEngine:
                 vseg = self.sstore.versatile_segment(s.dir)
                 probes[i] = vseg.max_probe if vseg else 1
                 depths[i] = vseg.max_deg_log2 if vseg else 1
+                fps[i] = _fp_dup(vseg)
+                shifts[i] = vseg.key_shift if vseg else 0
             elif s.kind not in ("init_index", "init_rows", "member_index"):
                 seg = self.sstore.segment(s.pid, s.dir)
                 probes[i] = seg.max_probe if seg else 1
                 depths[i] = seg.max_deg_log2 if seg else 1
+                fps[i] = _fp_dup(seg)
+                shifts[i] = seg.key_shift if seg else 0
+
+        def probe_kw(i, fp):
+            """How step ``i`` probes its table: its bounds, its key shift
+            and, where staged, its fingerprint words."""
+            kw = {"max_probe": probes[i], "key_shift": shifts[i]}
+            if fp:
+                kw.update(fpw0=fp[0], fpw1=fp[1], fp_dup=fps[i])
+            return kw
 
         def wk_dist_chain(*flat):
             # unflatten per-step args (squeeze the leading shard axis)
@@ -995,6 +1063,9 @@ class DistEngine:
             n = jnp.int32(0)
             totals = [jnp.int32(0)] * len(steps)
             exch_totals = [jnp.int32(0)] * len(steps)
+            # per step: live rows sent to other chips, slots shipped there
+            sent = [jnp.int32(0)] * len(steps)
+            slots = [jnp.int32(0)] * len(steps)
 
             for i, s in enumerate(steps):
                 if s.kind == "init_rows":
@@ -1015,19 +1086,20 @@ class DistEngine:
                         table = jnp.zeros((1, s.cap), jnp.int32)
                         n = jnp.int32(0)
                         continue
-                    bkey, bstart, bdeg, edges = arrs
+                    bkey, bstart, bdeg, edges, *fp = arrs
                     table, n, tot = K.wk_walk_expand.__wrapped__(
                         const_tab, jnp.int32(1), bkey, bstart, bdeg, edges,
-                        col=0, cap_out=s.cap, max_probe=probes[i])
+                        col=0, cap_out=s.cap, **probe_kw(i, fp))
                     table = table[1:, :]  # drop the const row ([W, C] layout)
                     totals[i] = tot
                     continue
 
                 if s.exch_cap:
-                    table, n, em, tot_recv = _exchange(
+                    table, n, em, tot_recv, sent[i] = _exchange(
                         table, n, s.col, s.exch_cap, s.cap, D, axis)
                     exch_totals[i] = em
                     totals[i] = jnp.maximum(totals[i], tot_recv)
+                    slots[i] = jnp.int32((D - 1) * s.exch_cap)
 
                 if s.kind == "member_index":
                     edges_i, lens = per_step[i]
@@ -1047,10 +1119,10 @@ class DistEngine:
                             axis=0)
                         n = jnp.int32(0)
                         continue
-                    bkey, bstart, bdeg, edges, edges2 = arrs
+                    bkey, bstart, bdeg, edges, edges2, *fp = arrs
                     table, n, tot = K.wk_walk_expand2.__wrapped__(
                         table, n, bkey, bstart, bdeg, edges2, edges,
-                        col=s.col, cap_out=s.cap, max_probe=probes[i])
+                        col=s.col, cap_out=s.cap, **probe_kw(i, fp))
                     totals[i] = jnp.maximum(totals[i], tot)
                     if fold:
                         # known_unknown_const: keep value == const rows,
@@ -1062,6 +1134,8 @@ class DistEngine:
                         table = table[:-1]
                 elif s.kind in ("expand", "expand_type_all"):
                     if s.kind == "expand_type_all":
+                        sent[i] = n * (D - 1)
+                        slots[i] = jnp.int32((D - 1) * table.shape[1])
                         table, n = _allgather_rows(table, n, D, axis)
                     if arrs is None:
                         table = jnp.concatenate(
@@ -1069,35 +1143,67 @@ class DistEngine:
                             axis=0)
                         n = jnp.int32(0)
                         continue
-                    bkey, bstart, bdeg, edges = arrs
+                    bkey, bstart, bdeg, edges, *fp = arrs
                     table, n, tot = K.wk_walk_expand.__wrapped__(
                         table, n, bkey, bstart, bdeg, edges, col=s.col,
-                        cap_out=s.cap, max_probe=probes[i])
+                        cap_out=s.cap, **probe_kw(i, fp))
                     totals[i] = jnp.maximum(totals[i], tot)
                 elif s.kind == "member":
                     if arrs is None:
                         keep = jnp.zeros(table.shape[1], bool)
                     else:
-                        bkey, bstart, bdeg, edges = arrs
+                        bkey, bstart, bdeg, edges, *fp = arrs
                         if s.vals_col >= 0:
                             vals = table[s.vals_col]
                         else:
                             vals = jnp.full(table.shape[1], np.int32(s.const))
                         keep = K.wk_walk_member_mask_known.__wrapped__(
                             table, n, vals, bkey, bstart, bdeg, edges,
-                            col=s.col, max_probe=probes[i], depth=depths[i])
+                            col=s.col, depth=depths[i], **probe_kw(i, fp))
                     table, n = K.wk_walk_compact.__wrapped__(table, keep)
 
             return {
                 "table": table[None],
                 "n": n[None],
-                "totals": jnp.stack(totals + exch_totals)[None],
+                "totals": jnp.stack(totals + exch_totals + sent
+                                    + slots)[None],
             }
 
         out_specs = {"table": P(axis), "n": P(axis), "totals": P(axis)}
         return jax.jit(shard_map(wk_dist_chain, mesh=self.mesh,
                                  in_specs=tuple(arg_specs),
                                  out_specs=out_specs, check_vma=False))
+
+
+def _fp_dup(seg) -> int:
+    """How many candidate lanes the fingerprint probe verifies a bucket for
+    ``seg`` (``tpu_kernels._hash_find_fp``), or 0 for the plain probe: where
+    no fingerprints are staged or ``enable_fp_probe`` is off. The plain
+    probe gathers three lanes-wide windows a round, some five times the
+    fingerprint probe's elements."""
+    if seg is None or seg.fpw0 is None \
+            or not Global.enable_fp_probe:
+        return 0
+    return int(seg.max_fp_dup)
+
+
+def _fp_args(seg) -> tuple:
+    return (seg.fpw0, seg.fpw1) if _fp_dup(seg) else ()
+
+
+def _widths_in(plan: _Plan) -> list[int]:
+    """The binding table's width as each step of ``plan`` receives it, and
+    last as the chain leaves it."""
+    out, width = [], 0
+    for s in plan.steps:
+        out.append(width)
+        if s.kind == "init_rows":
+            width = s.width
+        elif s.kind in ("init_index", "init_const"):
+            width = 1
+        elif s.new_col:
+            width += 2 if s.kind == "expand_versatile" else 1
+    return out + [width]
 
 
 def _gather_host(tree):
@@ -1150,26 +1256,25 @@ def _exchange(table, n, col, exch_cap: int, cap_new: int, D: int, axis: str):
 
     table: [W, C]. Per-destination capacity-padded all_to_all: send buffer
     [D, W, exch_cap]; per-dest row counts ride along so receivers compact
-    exactly. Returns (table [W, cap_new], n, max_dest_count, total_received).
+    exactly. A row's slot in its destination's block is its rank among the
+    live rows bound there, a running count a destination: no sort. Returns
+    (table [W, cap_new], n, max_dest_count, total_received, live rows sent
+    to other chips).
     """
     import jax
     import jax.numpy as jnp
 
     W, C = table.shape
-    rows = jnp.arange(C, dtype=jnp.int32)
-    live = rows < n
+    live = jnp.arange(C, dtype=jnp.int32) < n
     dest = jnp.where(live, table[col] % D, D)
-    order = jnp.argsort(dest, stable=True)
-    st = table[:, order]
-    sd = dest[order]
-    counts = jnp.bincount(dest, length=D + 1)[:D].astype(jnp.int32)
-    cumx = jnp.concatenate([jnp.zeros(1, jnp.int32),
-                            jnp.cumsum(counts)[:-1].astype(jnp.int32)])
-    within = rows - cumx[jnp.clip(sd, 0, D - 1)]
-    slot = jnp.where((sd < D) & (within < exch_cap),
-                     sd * exch_cap + within, D * exch_cap)
+    to = dest[None, :] == jnp.arange(D, dtype=jnp.int32)[:, None]  # [D, C]
+    rank = jnp.cumsum(to, axis=1, dtype=jnp.int32)
+    counts = rank[:, -1]
+    within = jnp.sum(jnp.where(to, rank, 0), axis=0) - 1
+    slot = jnp.where(live & (within < exch_cap), dest * exch_cap + within,
+                     D * exch_cap)
     send = jnp.zeros((W, D * exch_cap), jnp.int32).at[:, slot].set(
-        st, mode="drop")
+        table, mode="drop")
     send = send.reshape(W, D, exch_cap).transpose(1, 0, 2)  # [D, W, exch_cap]
     recv = jax.lax.all_to_all(send, axis, split_axis=0, concat_axis=0,
                               tiled=False)
@@ -1185,7 +1290,8 @@ def _exchange(table, n, col, exch_cap: int, cap_new: int, D: int, axis: str):
     out = jnp.zeros((W, cap_new), jnp.int32).at[:, pos].set(flat, mode="drop")
     tot_recv = rcounts.sum().astype(jnp.int32)
     new_n = jnp.minimum(tot_recv, cap_new)
-    return out, new_n, counts.max(), tot_recv
+    sent = counts.sum() - counts[jax.lax.axis_index(axis)]
+    return out, new_n, counts.max(), tot_recv, sent
 
 
 def _allgather_rows(table, n, D: int, axis: str):

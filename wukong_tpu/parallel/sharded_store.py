@@ -15,7 +15,8 @@ from dataclasses import dataclass
 import numpy as np
 
 from wukong_tpu.analysis.lockdep import declare_leaf, make_lock
-from wukong_tpu.engine.device_store import _next_pow2, build_hash_table
+from wukong_tpu.engine.device_store import (_next_pow2, build_hash_table,
+                                           fp_words)
 from wukong_tpu.runtime.transport import make_transport, run_op
 
 INT32_MAX = np.iinfo(np.int32).max
@@ -38,6 +39,14 @@ class StackedSegment:
     max_deg: int = 1  # global max degree (skew-aware exchange capacities)
     # VERSATILE combined segments: aligned per-edge predicate ids [D, E_pad]
     edges2: object = None
+    # each bucket's 8 key fingerprints packed in two words, [D, NB] each,
+    # and the most equal fingerprints of any bucket of any shard: the
+    # single-chip store's fingerprint probe (``tpu_kernels._hash_find_fp``)
+    fpw0: object = None
+    fpw1: object = None
+    max_fp_dup: int = 1
+    # the low key bits every shard's home buckets ignore (``_key_shift``)
+    key_shift: int = 0
 
     @property
     def nbytes(self) -> int:
@@ -45,6 +54,8 @@ class StackedSegment:
              + self.edges.size) * 4
         if self.edges2 is not None:
             n += self.edges2.size * 4
+        if self.fpw0 is not None:
+            n += (self.fpw0.size + self.fpw1.size) * 4
         return n
 
 
@@ -53,6 +64,55 @@ class StackedIndex:
     edges: object  # [D, L_pad] sharded on axis 0; pad INT32_MAX
     real_lens: np.ndarray  # [D] host-side true lengths
     total: int
+
+
+def _key_shift(keys_by_shard: list) -> int:
+    """``log2(D)`` where every key of shard ``i`` of ``D`` (a power of two)
+    is ``i`` mod ``D`` — a vertex-keyed segment, each vertex on its owner —
+    else 0. Such keys share their low bits, which a multiplicative hash
+    mod a power of two keeps: hashed whole they would reach a quarter of a
+    shard's home buckets at ``D`` 4, so the table hashes the bits above."""
+    D = len(keys_by_shard)
+    if D < 2 or D & (D - 1):
+        return 0
+    for i, keys in enumerate(keys_by_shard):
+        if len(keys) and not np.all((np.asarray(keys) & (D - 1)) == i):
+            return 0
+    return D.bit_length() - 1
+
+
+def _shard_tables(shards: list, NB: int, Ep: int, key_shift: int = 0):
+    """Each shard's (keys, offsets, edges) as a hashed table of ``NB``
+    buckets, its keys homed by their bits above ``key_shift``, and an edge
+    array padded to ``Ep``, the shards built side by side (the native table
+    build runs outside the GIL). -> a dict of
+    lists over the shards (``bkey``, ``bstart``, ``bdeg``: flat buckets;
+    ``edges``: padded; ``fpw0``, ``fpw1``: packed fingerprints) and
+    ``max_probe``, ``max_deg``, ``max_fp_dup``: the largest of any shard."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    def one(i):
+        k, o, e = shards[i]
+        k = np.asarray(k)
+        bk, bs, bd, mp = build_hash_table(k >> key_shift, np.asarray(o),
+                                          num_buckets=NB)
+        if key_shift:  # the slots hold whole keys, each shard's low bits i
+            bk = np.where(bk >= 0, (bk.astype(np.int64) << key_shift) | i,
+                          -1).astype(np.int32)
+        w0, w1, dup = fp_words(bk)
+        ee = np.full(Ep, INT32_MAX, dtype=np.int32)
+        ee[: len(e)] = e
+        deg = int((o[1:] - o[:-1]).max()) if len(k) else 1
+        # flat [NB*8] per shard (see tpu_kernels LAYOUT RULE)
+        return {"bkey": bk.reshape(-1), "bstart": bs.reshape(-1),
+                "bdeg": bd.reshape(-1), "edges": ee, "fpw0": w0, "fpw1": w1,
+                "max_probe": mp, "max_deg": deg, "max_fp_dup": dup}
+
+    with ThreadPoolExecutor(max_workers=len(shards)) as ex:
+        built = list(ex.map(one, range(len(shards))))
+    return {name: ([b[name] for b in built] if not name.startswith("max_")
+                   else max([1] + [b[name] for b in built]))
+            for name in built[0]}
 
 
 def _exec_local(fn, g):
@@ -511,6 +571,25 @@ class ShardedDeviceStore:
         spec = P(self.axis, *([None] * (arr.ndim - 1)))
         return jax.device_put(arr, NamedSharding(self.mesh, spec))
 
+    def _stacked(self, t: dict, avg_deg: float, key_shift: int,
+                 edges2=None) -> StackedSegment:
+        """The shards' tables (``_shard_tables``) stacked over the mesh."""
+        return StackedSegment(
+            bkey=self._put(np.stack(t["bkey"])),
+            bstart=self._put(np.stack(t["bstart"])),
+            bdeg=self._put(np.stack(t["bdeg"])),
+            edges=self._put(np.stack(t["edges"])),
+            edges2=edges2,
+            fpw0=self._put(np.stack(t["fpw0"])),
+            fpw1=self._put(np.stack(t["fpw1"])),
+            max_fp_dup=t["max_fp_dup"],
+            key_shift=key_shift,
+            max_probe=t["max_probe"],
+            max_deg_log2=max(int(t["max_deg"]).bit_length(), 1),
+            avg_deg=avg_deg,
+            max_deg=int(t["max_deg"]),
+        )
+
     # ------------------------------------------------------------------
     def segment(self, pid: int, d: int) -> StackedSegment | None:
         self.check_version()
@@ -535,35 +614,11 @@ class ShardedDeviceStore:
         NB = max(_next_pow2((max_k + 3) // 4), 2)
         max_e = max(len(e) for (_, _, e) in shards)
         Ep = _next_pow2(max(max_e, 1))
-        bkeys, bstarts, bdegs, edges_l = [], [], [], []
-        max_probe = 1
-        max_deg = 1
-        tot_e = tot_k = 0
-        for (k, o, e) in shards:
-            bk, bs, bd, mp = build_hash_table(np.asarray(k), np.asarray(o),
-                                              num_buckets=NB)
-            # flat [NB*8] per shard (see tpu_kernels LAYOUT RULE)
-            bkeys.append(bk.reshape(-1))
-            bstarts.append(bs.reshape(-1))
-            bdegs.append(bd.reshape(-1))
-            max_probe = max(max_probe, mp)
-            if len(k):
-                max_deg = max(max_deg, int((o[1:] - o[:-1]).max()))
-            tot_e += len(e)
-            tot_k += len(k)
-            ee = np.full(Ep, INT32_MAX, dtype=np.int32)
-            ee[: len(e)] = e
-            edges_l.append(ee)
-        seg = StackedSegment(
-            bkey=self._put(np.stack(bkeys)),
-            bstart=self._put(np.stack(bstarts)),
-            bdeg=self._put(np.stack(bdegs)),
-            edges=self._put(np.stack(edges_l)),
-            max_probe=max_probe,
-            max_deg_log2=max(int(max_deg).bit_length(), 1),
-            avg_deg=tot_e / max(tot_k, 1),
-            max_deg=int(max_deg),
-        )
+        shift = _key_shift([k for (k, _, _) in shards])
+        t = _shard_tables(shards, NB, Ep, shift)
+        tot_e = sum(len(e) for (_, _, e) in shards)
+        tot_k = sum(len(k) for (k, _, _) in shards)
+        seg = self._stacked(t, tot_e / max(tot_k, 1), shift)
         if healthy:
             # degraded stagings are never cached: the next query re-stages,
             # so a recovered shard's data reappears without a version bump
@@ -599,38 +654,18 @@ class ShardedDeviceStore:
         max_k = max(len(k) for (k, _, _, _) in shards)
         NB = max(_next_pow2((max_k + 3) // 4), 2)
         Ep = _next_pow2(max(max(len(e) for (_, _, e, _) in shards), 1))
-        bkeys, bstarts, bdegs, edges_l, pids_l = [], [], [], [], []
-        max_probe = 1
-        max_deg = 1
-        tot_e = tot_k = 0
-        for (k, o, e, p) in shards:
-            bk, bs, bd, mp = build_hash_table(np.asarray(k), np.asarray(o),
-                                              num_buckets=NB)
-            bkeys.append(bk.reshape(-1))
-            bstarts.append(bs.reshape(-1))
-            bdegs.append(bd.reshape(-1))
-            max_probe = max(max_probe, mp)
-            if len(k):
-                max_deg = max(max_deg, int((o[1:] - o[:-1]).max()))
-            tot_e += len(e)
-            tot_k += len(k)
-            ee = np.full(Ep, INT32_MAX, dtype=np.int32)
-            ee[: len(e)] = e
-            edges_l.append(ee)
+        shift = _key_shift([k for (k, _, _, _) in shards])
+        t = _shard_tables([(k, o, e) for (k, o, e, _) in shards], NB, Ep,
+                          shift)
+        pids_l = []
+        for (_, _, _, p) in shards:
             pp = np.full(Ep, INT32_MAX, dtype=np.int32)
             pp[: len(p)] = p
             pids_l.append(pp)
-        seg = StackedSegment(
-            bkey=self._put(np.stack(bkeys)),
-            bstart=self._put(np.stack(bstarts)),
-            bdeg=self._put(np.stack(bdegs)),
-            edges=self._put(np.stack(edges_l)),
-            edges2=self._put(np.stack(pids_l)),
-            max_probe=max_probe,
-            max_deg_log2=max(int(max_deg).bit_length(), 1),
-            avg_deg=tot_e / max(tot_k, 1),
-            max_deg=int(max_deg),
-        )
+        tot_e = sum(len(e) for (_, _, e, _) in shards)
+        tot_k = sum(len(k) for (k, _, _, _) in shards)
+        seg = self._stacked(t, tot_e / max(tot_k, 1), shift,
+                            edges2=self._put(np.stack(pids_l)))
         if healthy:
             self._cache[key] = seg
             self.bytes_used += seg.nbytes

@@ -64,6 +64,20 @@ class Booted:
     phases: dict = field(default_factory=dict)  # phase -> (seconds, bytes)
 
 
+@dataclass
+class BootedShards:
+    """A store hash-partitioned over ``len(stores)`` workers: shard ``k``
+    holds what ``build_partition(triples, k, n)`` gives, from the bundle
+    file ``bundle_paths[k]``."""
+
+    stores: list
+    str_server: object
+    planner: object
+    from_bundle: bool
+    bundle_paths: list
+    phases: dict = field(default_factory=dict)  # phase -> (seconds, bytes)
+
+
 def dataset_source(dataset_dir: str) -> TripleSource:
     """The id-format dataset directory the console is started on. A
     generated dataset is named by its generator's parameters
@@ -169,40 +183,83 @@ def _build(source: TripleSource):
     g = build_partition(narrow, 0, 1, attrs)
     del narrow, attrs
     _widen(g)
-    return g, stats
+    return [g], stats
 
 
-def _save(g, stats, key: dict, path: str, statfile: str) -> int:
-    """Both files under temporary names first: a start that is killed while
-    it writes leaves no bundle, not half of one."""
-    tmp, stat_tmp = path + ".tmp.npz", statfile + ".tmp"
+def _build_shards(source: TripleSource, n: int):
+    """The ``n`` partitions from one assignment of the triples to their
+    owners (``build_all_partitions``), while the planner's statistics, which
+    need the triples and no store, are gathered on a thread beside them."""
+    import threading
+
+    from wukong_tpu.planner.stats import Stats
+    from wukong_tpu.store.gstore import build_all_partitions
+
+    triples, attrs = source.load()
+    box: dict = {}
+
+    def gather():
+        try:
+            box["stats"] = Stats.generate(triples)
+        except BaseException as e:  # re-raised on the calling thread
+            box["error"] = e
+
+    th = threading.Thread(target=gather, name="boot-stats")
+    th.start()
     try:
-        persist.save_gstore(g, tmp, key=key)
+        stores = build_all_partitions(_narrowed(triples), n, attrs)
+    finally:
+        th.join()
+    if "error" in box:
+        raise box["error"]
+    for g in stores:
+        _widen(g)
+    return stores, box["stats"]
+
+
+def _save(stores: list, stats, keys: list, paths: list,
+          statfile: str) -> int:
+    """Every file under a temporary name first, the stores side by side: a
+    start that is killed while it writes leaves no bundle, not part of
+    one."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    tmps, stat_tmp = [p + ".tmp.npz" for p in paths], statfile + ".tmp"
+    try:
+        with ThreadPoolExecutor(max_workers=len(stores)) as ex:
+            list(ex.map(lambda a: persist.save_gstore(a[0], a[1], key=a[2]),
+                        zip(stores, tmps, keys)))
         stats.save(stat_tmp)
         os.replace(stat_tmp + ".npz", statfile + ".npz")
-        os.replace(tmp, path)  # the bundle last: a start looks for it
+        for tmp, path in zip(tmps, paths):  # the bundle last: a start
+            os.replace(tmp, path)           # looks for it
     finally:
-        for p in (tmp, stat_tmp + ".npz"):  # what a full disk left behind
+        for p in tmps + [stat_tmp + ".npz"]:  # what a full disk left behind
             if os.path.exists(p):
                 os.remove(p)
-    return os.path.getsize(path) + os.path.getsize(statfile + ".npz")
+    return sum(os.path.getsize(p) for p in paths) \
+        + os.path.getsize(statfile + ".npz")
 
 
-def _load(key: dict, path: str, statfile: str, phases: dict):
-    """-> (store, planner) from the bundle, or None where there is none
-    for this key. A bundle that cannot be trusted is removed."""
+def _load(keys: list, paths: list, statfile: str, phases: dict):
+    """-> (stores, planner) from the bundle, or None where there is none
+    for these keys. A bundle that cannot be trusted is removed."""
+    from concurrent.futures import ThreadPoolExecutor
+
     from wukong_tpu.planner.optimizer import make_planner
 
-    if not (os.path.exists(path) and os.path.exists(statfile + ".npz")):
+    if not all(os.path.exists(p) for p in paths + [statfile + ".npz"]):
         return None
     try:
-        if persist.bundle_key(path) != key:
-            raise CheckpointCorrupt("the key in its _meta is not the key "
-                                    "in its name", path=path)
+        for key, path in zip(keys, paths):
+            if persist.bundle_key(path) != key:
+                raise CheckpointCorrupt("the key in its _meta is not the "
+                                        "key in its name", path=path)
         t0 = time.perf_counter()
-        g = persist.load_gstore(path)
-        phases["boot.bundle_load"] = (time.perf_counter() - t0,
-                                      os.path.getsize(path))
+        with ThreadPoolExecutor(max_workers=len(paths)) as ex:
+            stores = list(ex.map(persist.load_gstore, paths))
+        phases["boot.bundle_load"] = (time.perf_counter() - t0, sum(
+            os.path.getsize(p) for p in paths))
         t0 = time.perf_counter()
         try:
             planner = make_planner(None, statfile)
@@ -215,36 +272,83 @@ def _load(key: dict, path: str, statfile: str, phases: dict):
         log_error(f"boot: {e}: the bundle is NOT served; rebuilding "
                   "from the triples")
         phases.clear()
-        for p in (path, statfile + ".npz"):
+        for p in paths + [statfile + ".npz"]:
             if os.path.exists(p):
                 os.remove(p)
         return None
-    return g, planner
+    return stores, planner
 
 
 def boot_store(source: TripleSource, bundle_dir: str) -> Booted:
     """The store, the string server and the planner of ``source``'s data:
     loaded from the bundle under ``bundle_dir`` whose key matches, else
     built as every start used to and saved there for the next one."""
+    key = bundle_key(source.key)
+    stem = os.path.join(bundle_dir, bundle_stem(key))
+    b = _boot(source, bundle_dir, [key], [stem + ".npz"], stem + ".stat",
+              lambda: _build(source))
+    return Booted(b.stores[0], b.str_server, b.planner, b.from_bundle,
+                  b.bundle_paths[0], b.phases)
+
+
+def boot_shards(source: TripleSource, bundle_dir: str,
+                partitions: int) -> BootedShards:
+    """``source``'s data hash-partitioned over ``partitions`` workers, as
+    ``boot_store`` boots one: loaded from the bundle's shard files, else
+    built from one assignment of the triples to their owners and saved. No
+    whole store is built beside the shards: the planner's statistics are
+    gathered from the triples."""
+    key = {**bundle_key(source.key), "partitions": int(partitions)}
+    stem = os.path.join(bundle_dir, bundle_stem(key))
+    keys = [{**key, "shard": k} for k in range(partitions)]
+    paths = [f"{stem}-shard{k}.npz" for k in range(partitions)]
+    return _boot(source, bundle_dir, keys, paths, stem + ".stat",
+                 lambda: _build_shards(source, partitions))
+
+
+def sharded_proxy(booted: BootedShards, devices=None):
+    """The proxy of a sharded deployment: the sharded engine over the
+    booted shards, one device a shard (``devices``, else the first of
+    ``jax.devices()``), serves every request that pins no engine. No whole
+    store stands beside the shards: the host engine walks them in place,
+    each lookup on its vertex's owner (``InplaceEngine``), and answers what
+    the sharded engine refuses; the device engine holds no segment of its
+    own (a federated view of the shards, which it cannot stage) and answers
+    only where it is pinned; shard 0 is the proxy's host partition."""
+    from wukong_tpu.engine.tpu import TPUEngine
+    from wukong_tpu.parallel.dist_engine import DistEngine
+    from wukong_tpu.parallel.inplace import FederatedGraph, InplaceEngine
+    from wukong_tpu.parallel.mesh import make_mesh
+    from wukong_tpu.runtime.proxy import Proxy
+
+    stores, ss = booted.stores, booted.str_server
+    dist = DistEngine(stores, ss, make_mesh(len(stores), devices))
+    proxy = Proxy(stores[0], ss, InplaceEngine(stores, ss),
+                  TPUEngine(FederatedGraph(stores), ss), dist,
+                  planner=booted.planner)
+    proxy.tpu.stats = booted.planner.stats
+    return proxy
+
+
+def _boot(source: TripleSource, bundle_dir: str, keys: list, paths: list,
+          statfile: str, build) -> BootedShards:
     from wukong_tpu.planner.optimizer import Planner
     from wukong_tpu.store.string_server import StringServer
 
-    key = bundle_key(source.key)
-    stem = os.path.join(bundle_dir, bundle_stem(key))
-    path, statfile = stem + ".npz", stem + ".stat"
     phases: dict = {}
-    loaded = _load(key, path, statfile, phases)
+    loaded = _load(keys, paths, statfile, phases)
     if loaded is not None:
-        g, planner = loaded
+        stores, planner = loaded
     else:
         t0 = time.perf_counter()
-        g, stats = _build(source)
-        phases["boot.build"] = (time.perf_counter() - t0, g.memory_bytes())
+        stores, stats = build()
+        phases["boot.build"] = (time.perf_counter() - t0,
+                                sum(g.memory_bytes() for g in stores))
         planner = Planner(stats)
         t0 = time.perf_counter()
         os.makedirs(bundle_dir, exist_ok=True)
         try:
-            nbytes = _save(g, stats, key, path, statfile)
+            nbytes = _save(stores, stats, keys, paths, statfile)
         except OSError as e:  # a start must not fail for want of a cache
             log_error(f"boot: bundle not saved ({e}); the next start "
                       "builds again")
@@ -254,5 +358,5 @@ def boot_store(source: TripleSource, bundle_dir: str) -> Booted:
         _M_SECONDS.labels(phase=name).set(secs)
         _M_BYTES.labels(phase=name).set(nbytes)
         log_info(f"{name}: {secs:.2f} s, {nbytes:,} bytes")
-    return Booted(g, StringServer(source.strings_dir), planner,
-                  loaded is not None, path, phases)
+    return BootedShards(stores, StringServer(source.strings_dir), planner,
+                        loaded is not None, paths, phases)

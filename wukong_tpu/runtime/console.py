@@ -619,11 +619,11 @@ def main(argv=None):
 
         from wukong_tpu.parallel.dist_engine import DistEngine
         from wukong_tpu.parallel.mesh import make_mesh
-        from wukong_tpu.store.gstore import build_partition
+        from wukong_tpu.store.gstore import build_all_partitions
 
         n = args.workers or len(jax.devices())
         triples, attrs = source.load()
-        stores = [build_partition(triples, i, n, attrs) for i in range(n)]
+        stores = build_all_partitions(triples, n, attrs)
         del triples, attrs
         dist = DistEngine(stores, ss, make_mesh(n))
         proxy = Proxy(g, ss, CPUEngine(g, ss),

@@ -212,11 +212,10 @@ class Proxy:
         adaptive snooze — wukong.cpp:202-225 spawns these at boot; here the
         first concurrent workload starts them)."""
         if self._pool is None:
-            from wukong_tpu.engine.cpu import CPUEngine
             from wukong_tpu.runtime.scheduler import EnginePool
 
             self._pool = EnginePool(
-                make_engine=lambda tid: CPUEngine(self.g, self.str_server))
+                make_engine=lambda tid: self._new_host_engine())
             self._pool.start()
         return self._pool
 
@@ -300,11 +299,39 @@ class Proxy:
             self._plan_cache.record(parsed, q, fam, version)
 
     def _engine_for(self, q: SPARQLQuery, device: str | None):
-        if device == "tpu" or (device is None and Global.enable_tpu and self.tpu):
+        """The engine a request is served by: the pinned ``device``, else
+        the sharded engine where the proxy holds one (the deployment is
+        sharded), else the single-partition engines as configured."""
+        if device == "dist" or (device is None and self.dist is not None):
+            return self.dist or self.cpu
+        return self._host_engine(device)
+
+    def _host_engine(self, device: str | None = None):
+        """The engine that answers without the sharded one: the device
+        engine where ``tpu`` is pinned, or unpinned on a single-partition
+        proxy while it is on; else the CPU engine, which on a sharded
+        deployment walks every shard in place."""
+        if device == "tpu" or (device is None and self.dist is None
+                               and Global.enable_tpu and self.tpu):
             return self.tpu or self.cpu
-        if device == "dist" and self.dist is not None:
-            return self.dist
         return self.cpu
+
+    def _whole_graph(self):
+        """The graph a host-side read sees whole: the proxy's partition,
+        or where that is one shard of a sharded deployment, the host
+        engine's view of every shard."""
+        if self.dist is not None and self.cpu is not None:
+            return self.cpu.g
+        return self.g
+
+    def _new_host_engine(self):
+        """A host engine over the whole graph, for the engine pool."""
+        from wukong_tpu.engine.cpu import CPUEngine
+        from wukong_tpu.parallel.inplace import InplaceEngine
+
+        if isinstance(self.cpu, InplaceEngine):  # the shards, in place
+            return InplaceEngine(self.cpu.g.stores, self.str_server)
+        return CPUEngine(self.g, self.str_server)
 
     # ------------------------------------------------------------------
     def run_single_query(self, text: str, repeats: int = 1,
@@ -439,8 +466,8 @@ class Proxy:
                 # materialize the oversized table on one host).
                 log_info("distributed engine rejected the plan shape; "
                          "falling back to the host engine")
-                host = self._engine_for(q, None) or self.cpu
-                if host is None or host is self.dist:
+                host = self._host_engine()
+                if host is None:
                     break  # no host engine: keep the error status
                 if trace is not None:
                     trace.event("proxy.fallback", reason="shape",
@@ -567,9 +594,10 @@ class Proxy:
         if qq.join_strategy == "wcoj":
             qq.join_route = self.classify_join_route(qq)
             self._m_join_route.labels(route=qq.join_route).inc()
-        elif getattr(qq, "knn", None) is None:
+        elif getattr(qq, "knn", None) is None and self.dist is None:
             # walk-strategy shapes may compile the WHOLE plan into one
-            # fused device program (engine/template_compile.py)
+            # fused device program (engine/template_compile.py); a sharded
+            # deployment's chain is the sharded engine's own program
             qq.template_route = self.classify_template_route(qq)
             self._m_template_route.labels(route=qq.template_route).inc()
 
@@ -701,9 +729,12 @@ class Proxy:
         pg = q.pattern_group
         if (pg.unions or pg.optional or q.planner_empty
                 or not pg.patterns
-                or getattr(q, "knn", None) is not None):
+                or getattr(q, "knn", None) is not None
+                or self.dist is not None):
             # knn composition lives in the walk engine's pre/post hooks;
-            # the tensor-join executors have no vector seam
+            # the tensor-join executors have no vector seam; and a sharded
+            # deployment serves through its sharded chain, which closes a
+            # cycle with a membership step on the owner's shard
             return "walk"
         knob = str(Global.join_strategy).strip().lower()
         if knob == "walk":
@@ -1091,7 +1122,8 @@ class Proxy:
     @staticmethod
     def _note_route(q: SPARQLQuery, route: str) -> None:
         """Traced: one ``proxy.route`` event naming the route that answered
-        (``wcoj``, ``template`` or ``walk``), what the plan-time choices
+        (``wcoj``, ``template``, ``walk`` or ``dist``: the sharded engine's
+        chain), what the plan-time choices
         were and, for a template program, ``why``: which half of the rule
         sent the reply to it (``small_classes`` or ``estimate``; ``knob``
         where ``template_device`` forced it). A demotion decided on this
@@ -1217,7 +1249,7 @@ class Proxy:
             if getattr(q, "knn", None) is not None:
                 self._maybe_presolve_knn(q)
             eng.execute(q)  # batcher bypass: direct dispatch
-            self._note_route(q, "walk")
+            self._note_route(q, "dist" if eng is self.dist else "walk")
             self._record_knn_feedback(q)
             return q
         finally:
@@ -1396,6 +1428,7 @@ class Proxy:
     def fill_template(self, tmpl: SPARQLTemplate) -> None:
         """Collect candidate constants per %placeholder by running the
         type/predicate index (proxy.hpp:69-129)."""
+        g = self._whole_graph()
         tmpl.candidates = []
         for tid, (pi, fld) in zip(tmpl.ptypes, tmpl.pos):
             if tid == "fromPredicate":
@@ -1404,7 +1437,7 @@ class Proxy:
                 # subjects (IN side), object slots its objects (OUT side)
                 pat = tmpl.query.pattern_group.patterns[pi]
                 d = IN if fld == "subject" else OUT
-                cands = np.asarray(self.g.get_index(pat.predicate, d))
+                cands = np.asarray(g.get_index(pat.predicate, d))
                 if len(cands) == 0:
                     raise WukongError(
                         ErrorCode.UNKNOWN_SUB,
@@ -1414,7 +1447,7 @@ class Proxy:
             if not is_tpid(tid):
                 raise WukongError(ErrorCode.SYNTAX_ERROR,
                                   f"placeholder type {tid} is not an index id")
-            cands = np.asarray(self.g.get_index(tid, IN))
+            cands = np.asarray(g.get_index(tid, IN))
             if len(cands) == 0:
                 raise WukongError(ErrorCode.UNKNOWN_SUB,
                                   f"no instances for placeholder type {tid}")

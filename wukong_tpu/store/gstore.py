@@ -211,10 +211,75 @@ def build_partition(triples: np.ndarray, sid: int, num_workers: int,
     insert (base_loader.hpp:165-219, static_gstore.hpp:383-454); here partition
     selection + CSR building are vectorized numpy over the shared array.
     """
-    g = GStore(sid=sid, num_workers=num_workers)
     if check_ids:
         check_vid_range(triples)
     s, p, o = triples[:, 0], triples[:, 1], triples[:, 2]
+
+    def out_side():  # the subject owner's copy
+        mine = hash_mod(s, num_workers) == sid
+        return s[mine], p[mine], o[mine]
+
+    def in_side():
+        # the object side never stores type triples as normal edges (the
+        # NORMAL_ID_START test folds into the owner mask: one copy, not two)
+        mine = (hash_mod(o, num_workers) == sid) & (o >= NORMAL_ID_START)
+        return s[mine], p[mine], o[mine]
+
+    return _assemble(GStore(sid=sid, num_workers=num_workers), out_side,
+                     in_side, attr_triples, versatile)
+
+
+def _owner_runs(owner: np.ndarray, n: int) -> list[np.ndarray]:
+    """Row numbers of each owner ``0..n-1``, in row order: one stable sort
+    of the owner column (a radix sort on its narrow copy). Rows whose owner
+    is ``n`` or more belong to none."""
+    narrow = owner.astype(np.uint8 if n < 255 else np.int64)
+    order = np.argsort(narrow, kind="stable")
+    bounds = np.searchsorted(narrow[order], np.arange(n + 1))
+    return [order[bounds[k]:bounds[k + 1]] for k in range(n)]
+
+
+def build_all_partitions(triples: np.ndarray, num_workers: int,
+                         attr_triples=None, versatile: bool = True,
+                         threads: int | None = None) -> list[GStore]:
+    """Every worker's GStore from one assignment of the triples to their
+    owners: each triple goes to its subject's owner as an OUT edge and to
+    its object's owner as an IN edge, as ``build_partition`` selects them,
+    and the partitions are then built side by side on ``threads`` threads
+    (default one a partition; the sorts run outside the GIL). The stores
+    are the bytes ``build_partition`` gives, shard by shard: each side's
+    rows keep their order."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    from wukong_tpu.native import get_lib
+
+    check_vid_range(triples)
+    get_lib()  # a first use builds the library: once, not on every thread
+    n = num_workers
+    s, p, o = triples[:, 0], triples[:, 1], triples[:, 2]
+    out_rows = _owner_runs(hash_mod(s, n), n)
+    in_rows = _owner_runs(np.where(o >= NORMAL_ID_START, hash_mod(o, n), n),
+                          n)
+
+    def build(k: int) -> GStore:
+        def side(rows):
+            return lambda: (s[rows[k]], p[rows[k]], o[rows[k]])
+
+        g = _assemble(GStore(sid=k, num_workers=n), side(out_rows),
+                      side(in_rows), attr_triples, versatile)
+        out_rows[k] = in_rows[k] = None  # this shard's row lists are spent
+        return g
+
+    with ThreadPoolExecutor(max_workers=threads or n,
+                            thread_name_prefix="build-shard") as ex:
+        return list(ex.map(build, range(n)))
+
+
+def _assemble(g: GStore, out_side, in_side, attr_triples,
+              versatile: bool) -> GStore:
+    """Fill ``g`` from its two sides: ``out_side()`` and ``in_side()`` give
+    the (s, p, o) columns of the triples it keeps as OUT and as IN edges."""
+    sid, num_workers = g.sid, g.num_workers
 
     # ---- normal segments + predicate indexes (one sort per side) ---------
     # One direction END-TO-END at a time (slice -> sort -> segments ->
@@ -222,9 +287,7 @@ def build_partition(triples: np.ndarray, sid: int, num_workers: int,
     # at LUBM-10240 (1.27B triples, int32) the old both-sides-up-front
     # layout peaked past this host's 125 GB and the build OOM-killed.
     # pso order: (p, s, o) — each predicate run becomes one OUT segment
-    mine_out = hash_mod(s, num_workers) == sid  # pso copy (subject owner)
-    so, po, oo = s[mine_out], p[mine_out], o[mine_out]
-    del mine_out
+    so, po, oo = out_side()
     order = _triple_argsort(po, so, oo)
     so, po, oo = so[order], po[order], oo[order]
     del order
@@ -238,12 +301,8 @@ def build_partition(triples: np.ndarray, sid: int, num_workers: int,
         p_out = np.unique(po[po != TYPE_ID])
     del so, po, oo
 
-    # pos order: (p, o, s) — each predicate run becomes one IN segment;
-    # the object side never stores type triples as normal edges (the
-    # NORMAL_ID_START test folds into the owner mask: one copy, not two)
-    mine_in = (hash_mod(o, num_workers) == sid) & (o >= NORMAL_ID_START)
-    si, pi, oi = s[mine_in], p[mine_in], o[mine_in]
-    del mine_in
+    # pos order: (p, o, s) — each predicate run becomes one IN segment
+    si, pi, oi = in_side()
     order = _triple_argsort(pi, oi, si)
     si, pi, oi = si[order], pi[order], oi[order]
     del order
@@ -300,11 +359,3 @@ def build_partition(triples: np.ndarray, sid: int, num_workers: int,
             g.attrs[aid] = AttrSegment(keys=keys, values=vals, type=at)
 
     return g
-
-
-def build_all_partitions(triples: np.ndarray, num_workers: int,
-                         attr_triples=None, versatile: bool = True) -> list[GStore]:
-    check_vid_range(triples)  # once, not per partition
-    return [build_partition(triples, i, num_workers, attr_triples, versatile,
-                            check_ids=False)
-            for i in range(num_workers)]
